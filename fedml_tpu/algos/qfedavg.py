@@ -34,8 +34,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from fedml_tpu.parallel.compat import shard_map
 
 from fedml_tpu.algos.fedavg import FedAvgAPI
 from fedml_tpu.parallel.shard import (client_axis, client_rngs,

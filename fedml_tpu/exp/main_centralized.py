@@ -20,6 +20,8 @@ import json
 import logging
 import sys
 
+from fedml_tpu.utils import use_compile_cache
+
 
 def run_centralized(args):
     from functools import partial
@@ -109,6 +111,7 @@ def main(argv=None):
     logging.basicConfig(level=logging.INFO,
                         format="[Centralized %(asctime)s] %(message)s")
     args = parse_args(sys.argv[1:] if argv is None else argv)
+    use_compile_cache()
     return run_centralized(args)
 
 

@@ -42,6 +42,7 @@ from fedml_tpu.trainer.local import (
     model_fns,
     softmax_ce,
 )
+from fedml_tpu.utils import device_placement, use_compile_cache
 
 DEFAULT_PORT_BASE = 50100
 
@@ -147,6 +148,12 @@ def main(argv=None):
     logging.basicConfig(
         level=logging.INFO,
         format=f"[cross-silo rank {args.rank}] %(asctime)s %(message)s")
+    # One OS process per rank, and a chip belongs to one process: a rank
+    # started on a TPU host takes its chips here or fails; ranks sharing
+    # one machine are placed on the CPU by their launcher
+    # (scripts/run_cross_silo.sh exports JAX_PLATFORMS=cpu).
+    use_compile_cache()
+    logging.info("placement: %s", device_placement())
 
     from fedml_tpu.exp.setup import setup_standard
 

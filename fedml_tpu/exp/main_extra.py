@@ -29,6 +29,7 @@ from fedml_tpu.exp.args import (add_args, config_from_args,
                                 reject_serve_flags)
 from fedml_tpu.exp.setup import global_test_batches, load_data
 from fedml_tpu.data.loaders import to_federated_arrays
+from fedml_tpu.utils import use_compile_cache
 
 
 def _setup(args):
@@ -337,6 +338,7 @@ def main(argv=None):
                         help="Decentralized only: dsgd | pushsum")
     add_args(parser)
     args = parser.parse_args(argv)
+    use_compile_cache()
     # FedBuff composes with the robust aggregator + corruption drill
     # (buffered ingest reduces through core/robust_agg); every other
     # specialty algorithm must refuse those flags, not no-op. The

@@ -15,6 +15,7 @@ import sys
 
 from fedml_tpu.exp.args import parse_args
 from fedml_tpu.exp.setup import setup_standard
+from fedml_tpu.utils import use_compile_cache
 
 
 def round_lr(base_lr: float, schedule: str, round_idx: int, total_rounds: int,
@@ -269,6 +270,7 @@ def run(args, algorithm: str = "FedAvg"):
 
 
 def main(argv=None, algorithm: str = "FedAvg"):
+    use_compile_cache()
     args = parse_args(argv)
     _, history = run(args, algorithm)
     # Empty history = resumed a run that had already completed.
@@ -286,5 +288,6 @@ if __name__ == "__main__":
     parser.add_argument("--algorithm", type=str, default="FedAvg")
     add_args(parser)
     ns = parser.parse_args()
+    use_compile_cache()
     _, hist = run(ns, ns.algorithm)
     print(json.dumps(hist[-1] if hist else {"status": "already_complete"}))

@@ -1,14 +1,18 @@
 """Native (C++) runtime components, built on demand with the system
 toolchain and loaded via ctypes — no pybind11 dependency.
 
-``load_msgnet()`` compiles ``msgnet.cpp`` once (cached as
-``_build/libmsgnet.so``, keyed on source mtime) and returns the ctypes
-library with argtypes configured.
+``load_msgnet()`` compiles ``msgnet.cpp`` once and returns the ctypes
+library with argtypes configured. Build artefacts live in the ignored
+``_build/`` under a name keyed on a hash of their sources and compiler
+flags, so a binary built from other sources or flags — or one that came
+along when the checkout was copied from another machine — has another
+name and can never load.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -19,48 +23,50 @@ _LOCK = threading.Lock()
 _LIB = None
 
 
-def _compile(src: str, out: str):
+def _artefact(srcs, flags, stem: str, suffix: str = "") -> str:
+    """Path of the artefact for (``srcs``, ``flags``), compiling it if it
+    is not there yet. The compiler writes a private temporary that is
+    renamed into place, so ranks starting together never load a
+    half-written file."""
+    digest = hashlib.sha256(" ".join(flags).encode())
+    for src in srcs:
+        with open(src, "rb") as f:
+            digest.update(f.read())
+    out = os.path.join(_BUILD, f"{stem}-{digest.hexdigest()[:16]}{suffix}")
+    if os.path.isfile(out):
+        return out
     os.makedirs(_BUILD, exist_ok=True)
-    cmd = [
-        "g++", "-O2", "-fPIC", "-shared", "-pthread", "-std=c++17",
-        src, "-o", out,
-    ]
+    tmp = f"{out}.{os.getpid()}.tmp"
+    cmd = ["g++", *flags, *srcs, "-o", tmp]
     proc = subprocess.run(cmd, capture_output=True, text=True)
     if proc.returncode != 0:
         raise RuntimeError(
             f"native build failed: {' '.join(cmd)}\n{proc.stderr[-4000:]}"
         )
+    os.replace(tmp, out)
+    return out
 
 
 def build_stress(sanitize: str = "thread") -> str:
     """Build the msgnet stress binary (linked with the transport sources)
     with a sanitizer — the race-detection harness. Returns the binary path."""
-    os.makedirs(_BUILD, exist_ok=True)
-    out = os.path.join(_BUILD, f"msgnet_stress_{sanitize}")
-    srcs = [os.path.join(_HERE, "msgnet.cpp"), os.path.join(_HERE, "msgnet_stress.cpp")]
-    newest = max(os.path.getmtime(s) for s in srcs)
-    if os.path.isfile(out) and os.path.getmtime(out) >= newest:
-        return out
-    cmd = ["g++", "-O1", "-g", "-pthread", "-std=c++17",
-           f"-fsanitize={sanitize}", *srcs, "-o", out]
-    proc = subprocess.run(cmd, capture_output=True, text=True)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"stress build failed: {' '.join(cmd)}\n{proc.stderr[-4000:]}")
-    return out
+    srcs = [os.path.join(_HERE, "msgnet.cpp"),
+            os.path.join(_HERE, "msgnet_stress.cpp")]
+    flags = ["-O1", "-g", "-pthread", "-std=c++17", f"-fsanitize={sanitize}"]
+    return _artefact(srcs, flags, f"msgnet_stress_{sanitize}")
 
 
 def load_msgnet() -> ctypes.CDLL:
-    """Build (if stale) + load the message-transport library."""
+    """Build (if not built from these sources yet) + load the
+    message-transport library."""
     global _LIB
     with _LOCK:
         if _LIB is not None:
             return _LIB
-        src = os.path.join(_HERE, "msgnet.cpp")
-        out = os.path.join(_BUILD, "libmsgnet.so")
-        if not os.path.isfile(out) or os.path.getmtime(out) < os.path.getmtime(src):
-            _compile(src, out)
-        lib = ctypes.CDLL(out)
+        lib = ctypes.CDLL(_artefact(
+            [os.path.join(_HERE, "msgnet.cpp")],
+            ["-O2", "-fPIC", "-shared", "-pthread", "-std=c++17"],
+            "libmsgnet", ".so"))
         lib.mn_server_create.argtypes = [ctypes.c_int, ctypes.c_int]
         lib.mn_server_create.restype = ctypes.c_int
         lib.mn_server_port.argtypes = [ctypes.c_int]

@@ -34,8 +34,6 @@ def model_cost(model, sample_x, train: bool = False) -> Dict[str, float]:
 
     compiled = jax.jit(fwd).lower(net, sample_x).compile()
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     return {
         "flops": float(ca.get("flops", float("nan"))),
         "bytes_accessed": float(ca.get("bytes accessed", float("nan"))),

@@ -37,8 +37,8 @@ single kernel producing dx and accumulating dscale/dbias across the
 sequential grid in VMEM scratch (written on the last step) — the TPU
 idiom for cross-block reductions.
 
-On non-TPU backends the kernels run in interpreter mode (CPU-mesh
-testable); equivalence vs ``nn.GroupNorm`` is pinned in
+On the CPU backend the kernels run in interpreter mode (CPU-mesh
+testable; ops/platform.py); equivalence vs ``nn.GroupNorm`` is pinned in
 tests/test_group_norm.py.
 """
 
@@ -51,13 +51,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from fedml_tpu.parallel.compat import pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from fedml_tpu.ops.platform import pallas_interpret
 
 
 def _block_n(n: int, s: int, c: int, budget_bytes: int = 1 << 19) -> int:
@@ -162,14 +156,14 @@ def _fwd(x3, gamma, beta, groups, eps):
         ],
         out_specs=pl.BlockSpec((bn, s, c), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, s, c), x3.dtype),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(x3, gamma.reshape(1, c), beta.reshape(1, c))
 
 
 def _bwd(x3, dy3, gamma, groups, eps):
     n, s, c = x3.shape
     bn = _block_n(n, s, c)
-    dims = _CompilerParams(dimension_semantics=("arbitrary",))
+    dims = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
     return pl.pallas_call(
         functools.partial(_bwd_kernel, groups=groups, eps=eps),
         grid=(n // bn,),
@@ -193,7 +187,7 @@ def _bwd(x3, dy3, gamma, groups, eps):
             pltpu.VMEM((1, c), jnp.float32),
         ],
         compiler_params=dims,
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(x3, dy3, gamma.reshape(1, c))
 
 

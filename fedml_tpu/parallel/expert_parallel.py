@@ -20,8 +20,8 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from fedml_tpu.parallel.compat import shard_map
 
 
 class MoEParams(NamedTuple):

@@ -22,8 +22,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from fedml_tpu.parallel.compat import shard_map
 
 
 def _block_attn(q, k, v, scale, mask):

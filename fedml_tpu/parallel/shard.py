@@ -17,8 +17,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from fedml_tpu.parallel.compat import shard_map
 
 from fedml_tpu.core.tree import tree_weighted_mean
 

@@ -23,8 +23,8 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from fedml_tpu.parallel.compat import shard_map
 
 
 def _split(arr, n, axis):

@@ -12,17 +12,19 @@ lifts the shared-base matmuls to batched matmuls against one weight
 while the per-row LoRA pairs contract per row
 (:func:`~fedml_tpu.models.transformer.lora_delta_batched`).
 
-Bitwise contracts (test-pinned, the PR 15 identity invariant moved onto
-the read path):
+Identity contracts (test-pinned, the PR 15 identity invariant moved onto
+the read path; byte-exact unless noted):
 
 - the batched forward at ``B=1`` equals the per-request jitted forward
   bit-for-bit;
 - a row whose adapter vector is all-zero (rank-0 / never-personalized
-  under a zero global) reproduces the DENSE model byte-identically;
+  under a zero global) reproduces the DENSE model (to the last ulp since
+  jaxlib 0.9.0: another program, other fusions);
 - right-padding the token row and zero-padding the batch change no real
-  row's logits (causal attention + row-independent vmap), so the plane
-  can pad every micro-batch to one compiled ``[max_batch, seq_len]``
-  shape.
+  row's logits (causal attention + row-independent vmap; the token
+  padding to the last ulp since jaxlib 0.9.0, whose CPU dots pick their
+  accumulation order from the operand shape), so the plane can pad every
+  micro-batch to one compiled ``[max_batch, seq_len]`` shape.
 
 For tokens/s the module also carries :class:`AdapterDecoder`, a
 KV-cached prefill + per-step decode over the SAME merged params —

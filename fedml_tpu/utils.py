@@ -7,7 +7,11 @@
 - ``logging_config``: per-rank logging format (utils/logger.py:7,
   main_fedavg.py:411-415);
 - ``post_complete_message_to_sweep_process``: fifo signal used by sweep
-  drivers (fedavg/utils.py:19-27).
+  drivers (fedavg/utils.py:19-27);
+- ``use_compile_cache``: where an entry point keeps JAX's persistent
+  compile cache;
+- ``device_placement``: which backend this process runs on, refusing
+  JAX's quiet TPU-to-CPU fallback.
 """
 
 from __future__ import annotations
@@ -46,6 +50,52 @@ def logging_config(process_id: int = 0, level=logging.INFO):
         ),
         force=True,
     )
+
+
+#: ``<checkout>/.jax_cache`` — the compile cache when nothing places it.
+DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache for an entry point and
+    return its directory. Placed from outside: when
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and nothing
+    is set here. Otherwise the cache is ``<checkout>/.jax_cache`` — one
+    fixed path (never ``/tmp``, a pid or a timestamp), because a cache a
+    later process cannot find again is no cache. Call it before the
+    first compilation: JAX opens the cache once per process."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE)
+    return DEFAULT_COMPILE_CACHE
+
+
+def device_placement() -> str:
+    """``"<platform> x<count> (<device_kind>)"`` for this process's
+    default JAX backend — initializes it, so on a TPU host this takes the
+    chips. With ``JAX_PLATFORMS`` unset JAX survives a TPU it cannot
+    initialize (held by another process, broken libtpu) by logging at
+    INFO and running on the CPU; that is raised here instead, so a
+    process started on a TPU host either gets the chip or fails. Setting
+    ``JAX_PLATFORMS=cpu`` is how a caller asks for the CPU."""
+    import jax
+    # What JAX itself consults before its "a TPU may be present" warning.
+    from jax._src import hardware_utils
+
+    backend = jax.default_backend()
+    chips = hardware_utils.num_available_tpu_chips_and_device_id()[0]
+    if backend == "cpu" and chips and not os.environ.get("JAX_PLATFORMS"):
+        raise RuntimeError(
+            f"this host has {chips} TPU chip(s) but JAX fell back to the "
+            "CPU: another process holds the chip or libtpu failed to "
+            "start. One process per chip; set JAX_PLATFORMS=cpu to run "
+            "on the CPU on purpose.")
+    devices = jax.devices()
+    return f"{backend} x{len(devices)} ({devices[0].device_kind})"
 
 
 def rss_mb() -> float:

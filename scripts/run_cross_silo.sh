@@ -8,6 +8,14 @@
 # one-command launch, no MPI required — each rank is a plain python
 # process and the rank table is ports, not a hostfile.
 #
+# Placement: this launcher is the one-machine demo of the MESSAGE plane,
+# and every rank it starts runs on the CPU (JAX_PLATFORMS=cpu, printed
+# below). A chip belongs to one process at a time: W+1 processes on the
+# default backend of a one-chip host would hand the chip to the first
+# rank and fail or hang the rest. To train a silo on a chip, start
+# `python -m fedml_tpu.exp.main_cross_silo --rank R ...` by hand on that
+# silo's own host — it takes the host's chips or fails.
+#
 # Usage:
 #   scripts/run_cross_silo.sh <num_silos> [extra main_cross_silo args...]
 # Example:
@@ -19,6 +27,9 @@ W=${1:?usage: run_cross_silo.sh <num_silos> [args...]}
 shift
 SIZE=$((W + 1))
 PORT_BASE=${PORT_BASE:-50100}
+
+export JAX_PLATFORMS=cpu
+echo "run_cross_silo.sh: $SIZE ranks on this machine, all JAX_PLATFORMS=cpu" >&2
 
 pids=()
 for rank in $(seq 1 "$W"); do

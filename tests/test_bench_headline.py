@@ -1,7 +1,7 @@
 """The driver-artifact contract (r4 VERDICT #1): bench.py's FINAL stdout
 line must be a compact headline that survives any bounded tail capture.
 
-BENCH_r03/r04.json lost the primary metric because the full JSON line
+The r03/r04 driver records lost the primary metric because the full JSON line
 outgrew the driver's tail window (parsed: null). ``build_headline`` is
 the fix; these tests pin its contract against the REAL round-4 blob
 (docs/bench_r4_local.json) so output growth can never silently break the

@@ -69,14 +69,21 @@ def test_feddyn_server_state_invariant():
 def test_feddyn_beats_fedavg_under_drift():
     """Many local epochs on strongly shifted clients: dynamic
     regularization should reach a lower global train loss than FedAvg at
-    the same budget (the paper's core claim)."""
+    the same budget (the paper's core claim).
+
+    lr re-pinned 0.3 -> 0.03 in PR 21. At 0.3 the local steps on inputs
+    shifted by 4 sigma are unstable and which algorithm ends lower is a
+    seed lottery (3 of 6 seeds each way, under the old and the new
+    threefry stream alike); seed 0 happened to win until JAX 0.5 made
+    jax_threefry_partitionable the default and changed the draw. At 0.03
+    FedDyn ends 3-5x lower on every seed tried (0.02-0.03 vs 0.10-0.12)."""
     fed, test = _shifted_clients(shift=4.0)
     rounds, epochs = 20, 5
 
     fa = FedAvgAPI(LogisticRegression(num_classes=2), fed, test,
-                   _cfg(rounds, epochs))
+                   _cfg(rounds, epochs, lr=0.03))
     fd = FedDynAPI(LogisticRegression(num_classes=2), fed, test,
-                   _cfg(rounds, epochs), alpha=0.1)
+                   _cfg(rounds, epochs, lr=0.03), alpha=0.1)
     for r in range(rounds):
         fa.train_one_round(r)
         fd.train_one_round(r)

@@ -116,13 +116,21 @@ def test_batched_b8_matches_per_row(stack):
     np.testing.assert_allclose(batched, seq, atol=1e-5, rtol=1e-5)
 
 
-def test_rank0_rows_bitwise_equal_dense_model(stack):
-    """A zero adapter vector through the serve forward is byte-identical
-    to the DENSE transformer (same frozen base, no injection) run
-    through the same batched harness: the adapter machinery adds exactly
-    nothing for never-personalized rows. (Same-shape programs — a
-    vmapped dense forward — because XLA tiling is batch-shape-dependent;
-    the B=1 pin above covers the per-request path.)"""
+def test_rank0_rows_equal_dense_model(stack):
+    """A zero adapter vector through the serve forward reproduces the
+    DENSE transformer (same frozen base, no injection) run through the
+    same batched harness: the adapter machinery adds exactly nothing for
+    never-personalized rows. (Same-shape programs — a vmapped dense
+    forward — because XLA tiling is batch-shape-dependent; the B=1 pin
+    above covers the per-request path.)
+
+    To the last ulp, not byte-identical, since jaxlib 0.9.0 (triaged in
+    PR 21): XLA:CPU's fusion emitters (``xla_cpu_use_fusion_emitters``,
+    now on by default) fuse the dense projections with the exact-zero
+    LoRA terms' adds into other loops than the dense model's, and the
+    float32 sums come out reassociated. With ``XLA_FLAGS=
+    --xla_cpu_use_fusion_emitters=false`` the two programs are
+    byte-identical again."""
     from fedml_tpu.trainer.local import NetState, model_fns
 
     toks = _toks(2)
@@ -139,19 +147,29 @@ def test_rank0_rows_bitwise_equal_dense_model(stack):
         return logits[0]
 
     dense = np.asarray(jax.jit(jax.vmap(dense_row))(jnp.asarray(toks)))
-    assert np.array_equal(served, dense)
+    np.testing.assert_allclose(served, dense, rtol=2e-6, atol=2e-6)
 
 
-def test_padding_is_bitwise_inert(stack):
+def test_padding_is_inert(stack):
     """Right-padded token tail and zero-padded batch rows change nothing
     for the real prefix/rows (causal attention + vmap row independence)
-    — what lets the plane pad every micro-batch to ONE compiled shape."""
+    — what lets the plane pad every micro-batch to ONE compiled shape.
+
+    Batch-row padding is byte-exact. Sequence padding is exact to the
+    last ulp only, since jaxlib 0.9.0 (triaged in PR 21): XLA:CPU now
+    lowers these dots through YNNPACK fusions
+    (``xla_cpu_experimental_ynn_fusion_type``), whose kernels pick their
+    accumulation order from the operand shape, so a row of a ``[2*10,
+    d]`` matmul is no longer the bits of the same row in a ``[2*6, d]``
+    one. With ``XLA_FLAGS=--xla_cpu_experimental_ynn_fusion_type=`` the
+    padded prefix is byte-identical again."""
     vecs, toks = _vecs(stack, 2), _toks(2, t=6)
     full = stack["fwd"].prefill(vecs, toks)
     padded_toks = np.zeros((2, T), np.int32)
     padded_toks[:, :6] = toks
     padded = stack["fwd"].prefill(vecs, padded_toks)
-    assert np.array_equal(full, padded[:, :6])
+    np.testing.assert_allclose(np.asarray(full), np.asarray(padded[:, :6]),
+                               rtol=2e-6, atol=2e-6)
     # batch zero-pad: rows beyond the real traffic don't touch row 0/1
     wide_vecs = np.zeros((4, stack["fwd"].dim), np.float32)
     wide_vecs[:2] = vecs
@@ -481,7 +499,11 @@ def test_rollout_regression_gate_blocks_worse_candidate(stack):
     relative-tolerance gate. Arms chosen by measured CE on this traffic:
     large-noise adapters land near the uniform distribution (~log V)
     while the module's mild-noise globals sit visibly above it."""
-    live = _randomized(stack["glob"], seed=99, scale=5.0)  # lower CE
+    # Arms re-picked in PR 21 by measured CE on this traffic: JAX 0.5
+    # made jax_threefry_partitionable the default, which changed every
+    # seeded draw (model init and these adapters). live 3.25 vs the
+    # module global's 4.38 (was 4.14 vs 4.65 under the old stream).
+    live = _randomized(stack["glob"], seed=13, scale=5.0)  # lower CE
     mgr = ServeManager(stack["fwd"], None, live, seq_len=T, max_batch=4)
     co = RolloutCoordinator(mgr, min_shadow_tokens=8, regression_tol=0.02)
     co.publish(stack["glob"], epoch=1)  # higher-CE candidate
@@ -500,7 +522,11 @@ def test_rollout_restart_resumes_mid_promotion(stack, tmp_path):
     is deterministic."""
     from fedml_tpu.sim.clock import VirtualClock
 
-    cand = _randomized(stack["glob"], seed=11, scale=0.04)
+    # Candidate re-picked in PR 21 (the partitionable threefry stream,
+    # see the regression-gate test): CE 4.27 on this traffic against the
+    # live global's 4.38, inside the 2 % gate; the old seed now lands at
+    # 4.51 and is — correctly — blocked.
+    cand = _randomized(stack["glob"], seed=2, scale=0.04)
     mgr = ServeManager(stack["fwd"], None, stack["glob"], seq_len=T,
                        max_batch=4, clock=VirtualClock())
     co = RolloutCoordinator(mgr, directory=str(tmp_path),

@@ -138,7 +138,15 @@ def test_windowed_mesh_bit_equal():
     """The windowed scan over the shard_map round (clients sharded over
     the mesh axis, superbatch laid out [W, C-sharded, ...]) must equal
     the per-round sharded host loop exactly — including a SUBSAMPLED
-    cohort, which the on-device scan tier refuses on a mesh."""
+    cohort, which the on-device scan tier refuses on a mesh.
+
+    The trajectory (params) is bit-exact. The reported loss scalars are
+    telemetry and agree to the last ulp only, since jaxlib 0.9.0 (triaged
+    in PR 21: round 5's differs by 1.5e-8): XLA:CPU vectorizes the loss's
+    float32 reduction at its preferred vector width, and inside the scan
+    body the partial sums associate differently than in the standalone
+    round. With ``XLA_FLAGS=--xla_cpu_prefer_vector_width=128`` the six
+    scalars are bit-equal again."""
     from fedml_tpu.parallel.mesh import client_mesh
 
     x, y, parts = _power_law(seed=2, n_clients=16)
@@ -152,7 +160,7 @@ def test_windowed_mesh_bit_equal():
     la = [host.train_one_round(r)["train_loss"] for r in range(6)]
     lb = win.train_rounds_windowed(6, window=3)
     assert win._window_stats["scanned_rounds"] == 6
-    np.testing.assert_array_equal(la, lb)
+    np.testing.assert_allclose(la, lb, rtol=1e-6, atol=0)
     _assert_nets_bit_equal(host, win)
 
 
