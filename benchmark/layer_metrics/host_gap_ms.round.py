@@ -1,0 +1,17 @@
+"""Host time a round during which the device ran nothing: the traced window
+less the union of the first device's XLA Ops intervals, over the traced
+rounds.
+"""
+
+META = {"layer": "round loop", "unit": "ms", "moves": "rounds_per_s"}
+
+
+def applies(cell: dict) -> bool:
+    return True
+
+
+def read(summary: dict):
+    t = summary.get("trace")
+    if not t:
+        return None
+    return 1e3 * (t["window_s"] - t["busy_s_first"]) / t["rounds"]
