@@ -23,6 +23,7 @@ from fedml_tpu.algos.loop import FederatedLoop, eval_segments
 from fedml_tpu.core.robust_agg import make_aggregator
 from fedml_tpu.data.batching import FederatedArrays
 from fedml_tpu.obs.sanitizer import planned_transfer
+from fedml_tpu.obs.trace import span
 from fedml_tpu.parallel.shard import (
     client_axes,
     client_axis,
@@ -975,7 +976,8 @@ class FedAvgAPI(FederatedLoop):
                 from fedml_tpu.data.batching import gather_clients
 
                 def gather_step(net, extra, fed, idx, wmask, key):
-                    sub = gather_clients(fed, idx)
+                    with jax.named_scope("fed.gather"):
+                        sub = gather_clients(fed, idx)
                     w = sub.counts.astype(jnp.float32) * wmask
                     return step(net, extra, sub.x, sub.y, sub.mask, w, key)
 
@@ -990,37 +992,48 @@ class FedAvgAPI(FederatedLoop):
         checkpoints and remainder/eval host work read the new state.
         Returns the round's (device) loss."""
         pre, gather = self._fused_round_step()
-        self.rng, rnd_rng = jax.random.split(self.rng)
-        self._last_round_key = rnd_rng
-        idx, wmask = self.sample_round(round_idx)
-        aux = self._fused_round_extras(round_idx, idx, wmask)
-        extra = self._window_carry_init()
-        if self._streaming:
-            sub = self._stream_cohort(round_idx, idx)
-            weights = sub.counts.astype(jnp.float32) * jnp.asarray(wmask)
-            (self.net, extra), loss = pre(
-                self.net, extra, sub.x, sub.y, sub.mask, weights, rnd_rng,
-                *aux)
-        elif gather is not None and not aux:
-            (self.net, extra), loss = gather(
-                self.net, extra, self.train_fed, jnp.asarray(idx),
-                jnp.asarray(wmask), rnd_rng)
+        with span("fed.round.sample", round=round_idx):
+            self.rng, rnd_rng = jax.random.split(self.rng)
+            self._last_round_key = rnd_rng
+            idx, wmask = self.sample_round(round_idx)
+            aux = self._fused_round_extras(round_idx, idx, wmask)
+            extra = self._window_carry_init()
+        if gather is not None and not aux:
+            operands = (self.train_fed, jnp.asarray(idx), jnp.asarray(wmask),
+                        rnd_rng)
+            step = gather
         else:
-            from fedml_tpu.data.batching import gather_clients
+            if self._streaming:
+                sub = self._stream_cohort(round_idx, idx)
+            else:
+                from fedml_tpu.data.batching import gather_clients
 
-            sub = gather_clients(self.train_fed, idx)
+                # (eager ops take no named_scope: known by their programs)
+                with span("fed.round.gather", round=round_idx):
+                    sub = gather_clients(self.train_fed, idx)
             weights = sub.counts.astype(jnp.float32) * jnp.asarray(wmask)
-            (self.net, extra), loss = pre(
-                self.net, extra, sub.x, sub.y, sub.mask, weights, rnd_rng,
-                *aux)
+            operands = (sub.x, sub.y, sub.mask, weights, rnd_rng, *aux)
+            step = pre
+        with span("fed.round.dispatch", round=round_idx):
+            (self.net, extra), loss = step(self.net, extra, *operands)
         self._window_carry_commit(extra)
         self._emit_reduce_obs()
         return loss
 
     def train_one_round(self, round_idx: int) -> Dict[str, float]:
-        if self._fused_round_step() is not None:
-            loss = self._train_round_fused(round_idx)
-            return {"round": round_idx, "train_loss": float(loss)}
+        with span("fed.round", round=round_idx):
+            if self._fused_round_step() is not None:
+                loss = self._train_round_fused(round_idx)
+            else:
+                loss = self._train_round_unfused(round_idx)
+            with span("fed.round.loss_fetch", round=round_idx):
+                loss = float(loss)  # the round's one host sync
+        return {"round": round_idx, "train_loss": loss}
+
+    def _train_round_unfused(self, round_idx: int):
+        """One round as ``run_round`` + ``_server_update``, two
+        dispatches, for the configurations with no fused step. Returns
+        the round's (device) loss."""
         if self.window_protocol == "custom":
             # A custom-protocol class without its fused step must not
             # silently fall through to plain run_round rounds — that is
@@ -1036,7 +1049,7 @@ class FedAvgAPI(FederatedLoop):
             # Memoized — returns the cohort this round actually trained.
             idx, wmask = self.sample_round(round_idx)
             self._update_oort_state(round_idx, idx, wmask)
-        return {"round": round_idx, "train_loss": float(loss)}
+        return loss
 
     def train_rounds_pipelined(self, n_rounds: int, start_round: int = 0):
         """Run ``n_rounds`` host-loop rounds back-to-back WITHOUT the

@@ -48,6 +48,7 @@ import numpy as np
 
 from fedml_tpu.data.batching import FederatedArrays, WindowBatch
 from fedml_tpu.obs.sanitizer import planned_transfer
+from fedml_tpu.obs.trace import span
 
 
 def _bucket_steps(steps: int) -> int:
@@ -216,13 +217,15 @@ class FederatedStore:
         steps = self._resolve_steps(ccounts, steps)
         cap = steps * self.batch_size
 
-        xs = np.empty((k, cap) + self._sample_shape, self._sample_dtype)
-        ys = np.empty((k, cap) + self._label_shape, self._label_dtype)
-        empty = self._fill_rows(idx, cap, xs, ys)
-        mask = (np.arange(cap) < ccounts[:, None]).astype(np.float32)
-        if empty.any():
-            xs[empty] = 0
-            ys[empty] = 0
+        with span("fed.store.gather", clients=k, steps=steps):
+            xs = np.empty((k, cap) + self._sample_shape, self._sample_dtype)
+            ys = np.empty((k, cap) + self._label_shape, self._label_dtype)
+            empty = self._fill_rows(idx, cap, xs, ys)
+            mask = (np.arange(cap) < ccounts[:, None]).astype(np.float32)
+            if empty.any():
+                xs[empty] = 0
+                ys[empty] = 0
+            counts = ccounts.astype(np.int32)
 
         def split(a):
             return a.reshape((k, steps, self.batch_size) + a.shape[2:])
@@ -230,12 +233,13 @@ class FederatedStore:
         # planned_transfer: the cohort H2D is the streaming tier's ONE
         # deliberate staging copy per round — mark it so the whole round
         # loop can run under obs.sanitizer.sanitized()'s transfer guard.
-        with planned_transfer():
+        nbytes = xs.nbytes + ys.nbytes + mask.nbytes + counts.nbytes
+        with span("fed.store.put", bytes=nbytes), planned_transfer():
             return FederatedArrays(
                 x=jnp.asarray(split(xs)),
                 y=jnp.asarray(split(ys)),
                 mask=jnp.asarray(split(mask)),
-                counts=jnp.asarray(ccounts, jnp.int32),
+                counts=jnp.asarray(counts),
             )
 
     def _gather_cohort_loop(self, indices,
@@ -409,7 +413,8 @@ class CohortPrefetcher:
 
         def work():
             try:
-                cohort = self.store.gather_cohort(indices)
+                with span("fed.cohort.prefetch", round=round_idx):
+                    cohort = self.store.gather_cohort(indices)
                 with self._lock:
                     self._ready[round_idx] = (indices, cohort)
             finally:
@@ -420,7 +425,8 @@ class CohortPrefetcher:
                 with self._lock:
                     self._pending.pop(round_idx, None)
 
-        t = threading.Thread(target=work, daemon=True)
+        t = threading.Thread(target=work, name="fed-cohort-prefetch",
+                             daemon=True)
         with self._lock:
             # Membership check and registration under ONE acquisition:
             # check-then-act across two lock scopes would let concurrent
@@ -431,21 +437,25 @@ class CohortPrefetcher:
         t.start()
 
     def get(self, round_idx: int, indices) -> FederatedArrays:
-        with self._lock:
-            t = self._pending.get(round_idx)
-        if t is not None:
-            t.join()
-        with self._lock:
-            hit = self._ready.pop(round_idx, None)
-            # Drop stale buffers (a user skipping rounds must not leak).
-            for r in [r for r in self._ready if r < round_idx]:
-                self._ready.pop(r)
-        # The prefetched cohort is only valid for the EXACT index list the
-        # caller now wants — sampling inputs may have changed between the
-        # prefetch and the round (cfg mutation, subclass overrides).
-        if hit is not None and np.array_equal(hit[0], np.asarray(indices)):
-            return hit[1]
-        return self.store.gather_cohort(indices)
+        # On a miss the synchronous gather's fed.store.* spans lie inside
+        # this one, on the caller's thread: that nesting is the miss signal.
+        with span("fed.cohort.wait", round=round_idx):
+            with self._lock:
+                t = self._pending.get(round_idx)
+            if t is not None:
+                t.join()
+            with self._lock:
+                hit = self._ready.pop(round_idx, None)
+                # Drop stale buffers (a user skipping rounds must not leak).
+                for r in [r for r in self._ready if r < round_idx]:
+                    self._ready.pop(r)
+            # The prefetched cohort is only valid for the EXACT index list
+            # the caller now wants — sampling inputs may have changed between
+            # the prefetch and the round (cfg mutation, subclass overrides).
+            if hit is not None and np.array_equal(hit[0],
+                                                  np.asarray(indices)):
+                return hit[1]
+            return self.store.gather_cohort(indices)
 
 
 class WindowPrefetcher:

@@ -196,11 +196,11 @@ def run(args, algorithm: str = "FedAvg"):
     ckpt_mgr = None
     start_round = 0
     history = []
-    # --trace on the simulator tier: per-round train/eval spans (the
-    # message-passing tiers trace the full upload lifecycle; here the
-    # round IS the unit of work) dumped to run_dir as Chrome trace JSON.
+    # --trace on the simulator tier: the round's own fed.* spans
+    # (obs.trace.span: fed.round and what lies inside it) dumped to
+    # run_dir as Chrome trace JSON.
     tracing = contextlib.ExitStack()
-    tracer = tracing.enter_context(obs_trace.tracing_to(trace_dir_from(args)))
+    tracing.enter_context(obs_trace.tracing_to(trace_dir_from(args)))
     try:
         if args.run_dir and (args.checkpoint_frequency or args.resume):
             import os
@@ -220,8 +220,7 @@ def run(args, algorithm: str = "FedAvg"):
                              cfg.lr_decay_rate)
                 )
             timer.mark()
-            with timer.phase("round"), tracer.span(
-                    "round", cat="round", corr=obs_trace.corr(round=r)):
+            with timer.phase("round"):
                 metrics = api.train_one_round(r)
                 timer.fence(api.net)
             # Reference cadence: every frequency_of_the_test rounds + final

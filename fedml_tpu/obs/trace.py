@@ -17,6 +17,14 @@ turns those into ARTIFACTS:
   a shared null context manager, so instrumented hot paths cost one
   attribute lookup + an empty ``with`` when tracing is off (pinned
   within 2% of uninstrumented in tests/test_trace.py).
+- :func:`span` — the simulator round's spans (``fed.*``, docs/
+  OBSERVABILITY.md "Simulator round"). It always enters a
+  ``jax.profiler.TraceAnnotation``, so the span lies in the XLA
+  profiler's trace, on the device's clock, whenever ANYONE holds a
+  profiler session (``obs.timing.trace``, the benchmark's ``--trace 1``,
+  xprof) and costs well under a microsecond otherwise; and it records the
+  same name and args into the installed :class:`SpanTracer` when there
+  is one. No switch of its own: "on" means "somebody is tracing".
 - :class:`FlightRecorder` — a bounded ring buffer of recent control-plane
   events (beats, evictions, re-admissions, codec refusals, epoch drops;
   on the sharded aggregation plane also ``shard_eviction`` /
@@ -29,7 +37,7 @@ the message-passing tiers run one federation per process (or one drill
 per test, via the ``using`` context manager), and a global hook is what
 lets ``comm/codec.py`` and the sim fabric trace without threading a
 tracer handle through every constructor. Deliberately stdlib-only at
-import time.
+import time (:func:`span` imports ``jax.profiler`` on first use).
 """
 
 from __future__ import annotations
@@ -237,6 +245,46 @@ class SpanTracer:
             for ev in self.events():
                 f.write(json.dumps(ev) + "\n")
         return path
+
+
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, imported on first use
+
+
+class _RecordedSpan:
+    """A profiler annotation and a :class:`SpanTracer` span as one."""
+
+    __slots__ = ("_annotation", "_recorded")
+
+    def __init__(self, annotation, recorded):
+        self._annotation = annotation
+        self._recorded = recorded
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        self._recorded.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._recorded.__exit__(*exc)
+        self._annotation.__exit__(*exc)
+        return False
+
+
+def span(name: str, **args):
+    """Context manager: one span of the simulator round, in the XLA
+    profiler's trace (whoever holds the session) and in the installed
+    :class:`SpanTracer` (if any). ``args`` come back as the event's stats
+    in ``jax.profiler.ProfileData`` and as the Chrome event's ``args``."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    annotation = _ANNOTATION(name, **args)
+    tracer = _ACTIVE
+    if not tracer:
+        return annotation
+    return _RecordedSpan(annotation, tracer.span(name, cat="fed", **args))
 
 
 @contextlib.contextmanager

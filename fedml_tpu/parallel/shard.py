@@ -126,9 +126,10 @@ def run_clients_guarded(local_train, client_transform, nan_guard,
     order: the server's defenses see the already-corrupted updates. Its
     per-client streams are forked with their own reserved constant
     (0xC0), disjoint from training's and the transform's (0x7F)."""
-    client_nets, losses = jax.vmap(
-        local_train, in_axes=(None, 0, 0, 0, 0)
-    )(net, x, y, mask, rngs)
+    with jax.named_scope("fed.local_train"):
+        client_nets, losses = jax.vmap(
+            local_train, in_axes=(None, 0, 0, 0, 0)
+        )(net, x, y, mask, rngs)
     if corruptor is not None:
         crngs = jax.vmap(lambda r: jax.random.fold_in(r, 0xC0))(rngs)
         client_nets = corruptor(net, client_nets, adv, crngs)
@@ -211,21 +212,22 @@ def make_vmap_round(local_train, client_transform=None, nan_guard: bool = False,
         client_params, losses, finite = run_clients_guarded(
             local_train, client_transform, nan_guard,
             params, x, y, mask, rngs, corruptor=corruptor, adv=adv)
-        weights = weights * finite
-        loss_weights = loss_weights * finite
-        if aggregator is None:
-            avg = tree_weighted_mean(client_params, weights)
-            if nan_guard:
-                # Every sampled client diverged → keep the previous global
-                # model (a zero-total weighted mean would silently zero the
-                # params).
-                any_ok = jnp.sum(weights) > 0
-                avg = jax.tree.map(
-                    lambda a, p: jnp.where(any_ok, a, p), avg, params)
-        else:
-            avg = _robust_avg(aggregator, client_params, weights, params)
-        lw = loss_weights / jnp.maximum(jnp.sum(loss_weights), 1e-12)
-        mean_loss = jnp.sum(losses * lw)
+        with jax.named_scope("fed.aggregate"):
+            weights = weights * finite
+            loss_weights = loss_weights * finite
+            if aggregator is None:
+                avg = tree_weighted_mean(client_params, weights)
+                if nan_guard:
+                    # Every sampled client diverged → keep the previous
+                    # global model (a zero-total weighted mean would
+                    # silently zero the params).
+                    any_ok = jnp.sum(weights) > 0
+                    avg = jax.tree.map(
+                        lambda a, p: jnp.where(any_ok, a, p), avg, params)
+            else:
+                avg = _robust_avg(aggregator, client_params, weights, params)
+            lw = loss_weights / jnp.maximum(jnp.sum(loss_weights), 1e-12)
+            mean_loss = jnp.sum(losses * lw)
         if with_client_losses:
             return avg, mean_loss, losses
         return avg, mean_loss
@@ -311,18 +313,8 @@ def make_sharded_round(local_train, mesh, axis: str = "clients",
     dcn = axes[0] if len(axes) > 1 else None
     gather_ax = axes if dcn else axis  # collective name(s) spanning C
 
-    def body(params, x, y, mask, weights, loss_weights, rng, adv):
-        # Same global-slot-keyed streams as the vmap path. On a DCN×ICI
-        # mesh the flattened (hosts-major) axis index IS the global
-        # shard slot — exactly the order P(("hosts", axis)) lays the
-        # client dimension out in.
-        shard_idx = jax.lax.axis_index(gather_ax)
-        rngs = client_rngs(rng, x.shape[0], shard_idx * x.shape[0])
-        client_params, losses, finite = run_clients_guarded(
-            local_train, client_transform, nan_guard,
-            params, x, y, mask, rngs, corruptor=corruptor, adv=adv)
-        weights = weights * finite
-        loss_weights = loss_weights * finite
+    @jax.named_scope("fed.aggregate")
+    def aggregate(params, client_params, losses, weights, loss_weights):
         w = weights.astype(jnp.float32)
         if aggregator is None:
             total = _psum_hier(jnp.sum(w), axes)
@@ -375,6 +367,20 @@ def make_sharded_round(local_train, mesh, axis: str = "clients",
         lw = loss_weights.astype(jnp.float32)
         lw = lw / jnp.maximum(_psum_hier(jnp.sum(lw), axes), 1e-12)
         loss = _psum_hier(jnp.sum(losses * lw), axes)
+        return avg, loss
+
+    def body(params, x, y, mask, weights, loss_weights, rng, adv):
+        # Same global-slot-keyed streams as the vmap path. On a DCN×ICI
+        # mesh the flattened (hosts-major) axis index IS the global
+        # shard slot — exactly the order P(("hosts", axis)) lays the
+        # client dimension out in.
+        shard_idx = jax.lax.axis_index(gather_ax)
+        rngs = client_rngs(rng, x.shape[0], shard_idx * x.shape[0])
+        client_params, losses, finite = run_clients_guarded(
+            local_train, client_transform, nan_guard,
+            params, x, y, mask, rngs, corruptor=corruptor, adv=adv)
+        avg, loss = aggregate(params, client_params, losses,
+                              weights * finite, loss_weights * finite)
         if with_client_losses:
             return avg, loss, losses
         return avg, loss
@@ -424,7 +430,8 @@ def make_fused_round_step(round_fn, server_update=None):
         avg, loss = round_fn(net, x, y, mask, weights, weights, key, *aux)
         if server_update is None:
             return (avg, extra), loss
-        new_net, new_extra = server_update(net, avg, extra, key)
+        with jax.named_scope("fed.aggregate"):
+            new_net, new_extra = server_update(net, avg, extra, key)
         return (new_net, new_extra), loss
 
     return step_fn

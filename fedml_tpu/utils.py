@@ -19,6 +19,7 @@ from __future__ import annotations
 import contextlib
 import logging
 import os
+import re
 import traceback
 
 
@@ -52,24 +53,36 @@ def logging_config(process_id: int = 0, level=logging.INFO):
     )
 
 
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 #: ``<checkout>/.jax_cache`` — the compile cache when nothing places it.
-DEFAULT_COMPILE_CACHE = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+DEFAULT_COMPILE_CACHE = os.path.join(_CHECKOUT, ".jax_cache")
 
 
 def use_compile_cache() -> str:
     """Turn on JAX's persistent compile cache for an entry point and
     return its directory. Placed from outside: when
-    ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and nothing
-    is set here. Otherwise the cache is ``<checkout>/.jax_cache`` — one
-    fixed path (never ``/tmp``, a pid or a timestamp), because a cache a
-    later process cannot find again is no cache. Call it before the
-    first compilation: JAX opens the cache once per process."""
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and no
+    directory is set here. Otherwise the cache is ``<checkout>/.jax_cache``
+    — one fixed path (never ``/tmp``, a pid or a timestamp), because a
+    cache a later process cannot find again is no cache. Call it before
+    the first compilation: JAX opens the cache once per process.
+
+    The cache's key holds the program's metadata. JAX leaves it out by
+    default, and a cache that outlives a source change then hands back an
+    executable compiled from the OLD source, whose operation names show
+    up in every profile as if they were this program's: on the chip the
+    round's ops carried frames of a file deleted two PRs before and none
+    of the ``jax.named_scope`` phases just added (PERF.md, PR 25). Source
+    paths are keyed relative to the checkout, so a checkout that moves
+    still hits."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(_CHECKOUT + os.sep))
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE)
     return DEFAULT_COMPILE_CACHE
 

@@ -6,6 +6,7 @@ named CPU dry run says what it is."""
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -16,12 +17,19 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 
+CACHE_OPTIONS = ("jax_compilation_cache_dir",
+                 "jax_compilation_cache_include_metadata_in_key",
+                 "jax_hlo_source_file_canonicalization_regex")
+
+
 @pytest.fixture
 def cache_dir_config():
-    """``jax_compilation_cache_dir`` as the test found it, put back after."""
-    before = jax.config.jax_compilation_cache_dir
+    """What ``use_compile_cache`` sets, as the test found it, put back
+    after."""
+    before = {name: getattr(jax.config, name) for name in CACHE_OPTIONS}
     yield
-    jax.config.update("jax_compilation_cache_dir", before)
+    for name, value in before.items():
+        jax.config.update(name, value)
 
 
 def test_compile_cache_placed_from_outside_sets_nothing(
@@ -41,6 +49,11 @@ def test_compile_cache_defaults_to_checkout(monkeypatch, cache_dir_config):
     want = os.path.join(REPO, ".jax_cache")
     assert use_compile_cache() == want
     assert jax.config.jax_compilation_cache_dir == want
+    # stale metadata from the cache would mislabel every profile
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
+    here = os.path.join(REPO, "fedml_tpu", "utils.py")
+    assert re.sub(jax.config.jax_hlo_source_file_canonicalization_regex, "",
+                  here) == os.path.join("fedml_tpu", "utils.py")
 
 
 def test_chip_smoke_without_a_chip_runs_nothing():
