@@ -266,7 +266,9 @@ def two_rounds(ctx: Ctx, api, out: dict) -> None:
 
 
 def lower_round(api):
-    """The jitted round ``api`` runs, lowered on the cohort of round 0."""
+    """The jitted round of ``api``, lowered on the shapes of round 0's
+    cohort (shapes, not arrays: the layout of the operands is then the
+    round's own choice, whatever the cohort gathered here lies on)."""
     import jax
     import jax.numpy as jnp
 
@@ -275,7 +277,9 @@ def lower_round(api):
     idx, wmask = api.sample_round(0)
     sub = gather_clients(api.train_fed, idx)
     w = sub.counts.astype(jnp.float32) * jnp.asarray(wmask)
-    return api.round_fn.lower(api.net, sub.x, sub.y, sub.mask, w, w,
+    x, y, mask, ws = (jax.ShapeDtypeStruct(a.shape, a.dtype)
+                      for a in (sub.x, sub.y, sub.mask, w))
+    return api.round_fn.lower(api.net, x, y, mask, ws, ws,
                               jax.random.PRNGKey(0)), sub, w
 
 
@@ -597,6 +601,7 @@ def phase_timing_facts(ctx: Ctx, out: dict) -> None:
 
 def phase_multi_device(ctx: Ctx, out: dict) -> None:
     import jax
+    import jax.numpy as jnp
     import numpy as np
 
     from fedml_tpu.exp.args import parse_args
@@ -626,8 +631,25 @@ def phase_multi_device(ctx: Ctx, out: dict) -> None:
         check(len(held) == n, f"a parameter lives on {len(held)} of {n} "
               "devices after a sharded round")
 
-    # The compiled round: an all-reduce inside, and client-stacked operands
-    # laid out as it wants them land one shard on each device.
+    # The federation lies on every device, and the step that ran above
+    # takes its cohort there: no collective but the aggregation's.
+    for leaf in jax.tree.leaves(api.train_fed):
+        check(len(leaf.sharding.device_set) == n and leaf.is_fully_replicated,
+              f"the resident federation is not replicated over {n} devices")
+    idx, wmask = api.sample_round(0)
+    step = api._fused_round_step()[1].lower(
+        api.net, api._window_carry_init(), api.train_fed, jnp.asarray(idx),
+        jnp.asarray(wmask), jax.random.PRNGKey(0)).compile().as_text()
+    check("all-reduce" in step, "no all-reduce in the compiled gather step")
+    moved = [op for op in ("all-gather", "all-to-all", "collective-permute")
+             if op in step]
+    check(not moved, f"the compiled gather step moves data between devices: "
+          f"{moved}")
+    del step
+
+    # The compiled round on a pre-gathered cohort (the path of a streamed or
+    # eagerly gathered one): an all-reduce inside, and client-stacked
+    # operands laid out as it wants them land one shard on each device.
     lowered, sub, w = lower_round(api)
     compiled = lowered.compile()
     check("all-reduce" in compiled.as_text(),

@@ -17,6 +17,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from fedml_tpu.algos.config import FedConfig
 from fedml_tpu.algos.loop import FederatedLoop, eval_segments
@@ -25,9 +26,9 @@ from fedml_tpu.data.batching import FederatedArrays
 from fedml_tpu.obs.sanitizer import planned_transfer
 from fedml_tpu.obs.trace import span
 from fedml_tpu.parallel.shard import (
-    client_axes,
     client_axis,
     client_shards,
+    make_cohort_gather,
     make_sharded_round,
     make_vmap_round,
     mesh_dcn_axis,
@@ -69,7 +70,13 @@ def plan_window_spans(buckets, window: int):
 
 class FedAvgAPI(FederatedLoop):
     """Federated trainer. ``mesh=None`` → single-device vmap simulator;
-    with a mesh, clients are sharded over ``mesh.axis_names[0]``.
+    with a mesh, clients are sharded over ``mesh.axis_names[0]`` and a
+    device-resident ``train_fed`` is held REPLICATED over the mesh (a
+    copy of the caller's; as ``self.net`` is), so each shard takes its
+    sampled clients from its own copy inside the round's program. A mesh
+    this process cannot fully address (multi-host; read off
+    ``mesh.devices``) keeps ``train_fed`` where the caller put it and
+    gathers eagerly before the sharded round.
 
     ``train_fed`` may be a device-resident ``FederatedArrays`` (small
     client counts) or a host-resident ``data.store.FederatedStore``
@@ -131,6 +138,16 @@ class FedAvgAPI(FederatedLoop):
                 "would be silently inert")
         self.fns = self._model_fns(model)
         self._streaming = isinstance(train_fed, FederatedStore)
+        # A resident federation is gathered inside the round's program
+        # (None: a streaming store, or a mesh beyond this process).
+        self._cohort_gather = None
+        if not self._streaming and (mesh is None or all(
+                d.process_index == jax.process_index()
+                for d in mesh.devices.flat)):
+            self._cohort_gather = make_cohort_gather(mesh)
+            if mesh is not None:
+                self.train_fed = jax.device_put(
+                    train_fed, NamedSharding(mesh, PartitionSpec()))
         if self._streaming and not type(self).supports_streaming:
             raise NotImplementedError(
                 f"{type(self).__name__} keeps per-client state device-"
@@ -326,8 +343,6 @@ class FedAvgAPI(FederatedLoop):
             # mesh. Start it there: a round 0 fed from one device and a
             # round 1 fed the replicated result are two input shardings,
             # and the round compiled twice (chip_smoke.py multi_device).
-            from jax.sharding import NamedSharding, PartitionSpec
-
             self.net = jax.device_put(
                 self.net, NamedSharding(mesh, PartitionSpec()))
 
@@ -375,36 +390,31 @@ class FedAvgAPI(FederatedLoop):
             round_fn = self._make_vmap_round(
                 self.local_train, transform, guard
             )
-
-            if not self._streaming and self._corruptor() is None:
-                # (The corruption drill's rounds take a trailing per-
-                # round adversary-mask operand run_round computes host-
-                # side; the fused gather-inside-jit path has no slot for
-                # it, so drilled rounds use the plain round_fn path.)
-                # Single-device: fuse the client gather + weight
-                # computation into the jitted round. Dispatching the takes
-                # eagerly costs ~40% of the round wall-clock on a real chip
-                # (4 un-jitted device ops + host sync per round).
-                # FederatedArrays is a struct.dataclass pytree, so it
-                # traces straight through jit. (The streaming store
-                # gathers on HOST — its cohort arrives pre-gathered, so
-                # the plain round_fn path below is the fast path.)
-                from fedml_tpu.data.batching import gather_clients
-
-                def fused(net, fed, idx, wmask, rng):
-                    sub = gather_clients(fed, idx)
-                    w = sub.counts.astype(jnp.float32) * wmask
-                    return round_fn(net, sub.x, sub.y, sub.mask, w, w, rng)
-
-                self.round_fn_fused = jax.jit(fused)
         else:
-            # Pad the sampled set to the CLIENT axis size only (a 2-D mesh's
-            # model axis does not multiply the client shards). Gather stays
-            # outside the jit: arbitrary sampled indices cross client
-            # shards, so the resharding take must run before shard_map.
+            # The sampled set is padded to the CLIENT axis size only (a
+            # 2-D mesh's model axis does not multiply the client shards).
             round_fn = self._make_sharded_round(
                 self.local_train, mesh, transform, guard
             )
+        gather = self._cohort_gather
+        if gather is not None and self._corruptor() is None:
+            # Resident federation: the client gather is traced into the
+            # round's program (FederatedArrays is a struct.dataclass
+            # pytree, so it passes straight through jit). Dispatching the
+            # takes eagerly costs ~40% of the round wall-clock on one chip
+            # (4 un-jitted device ops + host sync per round) and, on a
+            # mesh, half of it: one device gathers for all while the
+            # others wait. (The streaming store gathers on HOST — its
+            # cohort arrives pre-gathered. The corruption drill's rounds
+            # take a trailing per-round adversary-mask operand run_round
+            # computes host-side; the gather-inside-jit round has no slot
+            # for it, so drilled rounds use the plain round_fn path.)
+            def fused(net, fed, idx, wmask, rng):
+                sub = gather(fed, idx)
+                w = sub.counts.astype(jnp.float32) * wmask
+                return round_fn(net, sub.x, sub.y, sub.mask, w, w, rng)
+
+            self.round_fn_fused = jax.jit(fused)
         self.round_fn = jax.jit(round_fn)
 
     # --- hooks subclasses override (FedOpt/FedProx/...) -------------------
@@ -953,8 +963,9 @@ class FedAvgAPI(FederatedLoop):
         ``_server_update`` procedure (capability record says no fused
         step; oort's three-output round). Returns ``(pre, gather)``:
         ``pre`` takes pre-gathered cohort operands; ``gather`` (resident
-        single-device "round" protocol only) traces the client gather
-        inside the same dispatch."""
+        federation, "round" protocol; one chip or a mesh) traces the
+        client gather inside the same dispatch
+        (``parallel.shard.make_cohort_gather``)."""
         if not self.capability().fused:
             return None
         if self.cfg.client_selection == "oort":
@@ -971,13 +982,10 @@ class FedAvgAPI(FederatedLoop):
             # client-state STACK — one live copy instead of two.
             pre = jax.jit(step, donate_argnums=(0, 1))
             gather = None
-            if (self.mesh is None and not self._streaming
-                    and self.window_protocol == "round"):
-                from fedml_tpu.data.batching import gather_clients
-
+            take = self._cohort_gather
+            if take is not None and self.window_protocol == "round":
                 def gather_step(net, extra, fed, idx, wmask, key):
-                    with jax.named_scope("fed.gather"):
-                        sub = gather_clients(fed, idx)
+                    sub = take(fed, idx)
                     w = sub.counts.astype(jnp.float32) * wmask
                     return step(net, extra, sub.x, sub.y, sub.mask, w, key)
 
@@ -1460,9 +1468,9 @@ class FedAvgAPI(FederatedLoop):
         (FedNova's τ weights, the corruption drill's masks) refuse with
         the record-derived reason. On a client mesh the scan rides the
         shard_map round under full participation (the gather is the
-        identity there; client shards stay pinned to their devices
-        across all rounds); subsampled mesh rounds still need the host
-        loop's resharding gather.
+        identity there: each shard reads its own clients from its copy
+        of the replicated federation, the operand the host loop's rounds
+        take); subsampled mesh rounds use the host loop.
 
         The incoming ``self.net`` (and the algorithm's carry) is DONATED
         to the scan (``donate_argnums``): callers that want to compare
@@ -1489,10 +1497,9 @@ class FedAvgAPI(FederatedLoop):
         cpr = min(cfg.client_num_per_round, n_total)
         if self.mesh is not None and (cpr != n_total
                                       or n_total % self.n_shards):
-            # Subsampled mesh rounds need a resharding gather (arbitrary
-            # sampled indices cross client shards), which cannot run inside
-            # shard_map; with FULL participation the gather is the
-            # identity, so the sharded round rides the scan directly.
+            # With FULL participation the gather is the identity, so the
+            # sharded round rides the scan directly; the on-device sampler
+            # below draws unpadded cohorts for one chip only.
             raise NotImplementedError(
                 "the sharded scan requires full participation with the "
                 "client count divisible by the mesh "
@@ -1543,24 +1550,6 @@ class FedAvgAPI(FederatedLoop):
             scan_fn = jax.jit(scan_fn, donate_argnums=(0, 1))
             self._rounds_scan_fn = scan_fn
 
-        fed = self.train_fed
-        if self.mesh is not None:
-            # Pin client shards to their devices for the whole scan (the
-            # host loop re-lays them out every round via the eager gather).
-            # The resharded copy REPLACES self.train_fed so repeat calls
-            # don't pay a full-dataset reshard each time or transiently
-            # hold two device-resident copies.
-            cached = getattr(self, "_mesh_pinned_fed", None)
-            if cached is None or cached is not fed:
-                from jax.sharding import NamedSharding
-                from jax.sharding import PartitionSpec as P
-
-                shard = NamedSharding(self.mesh, P(client_axes(self.mesh)))
-                fed = jax.tree.map(lambda a: jax.device_put(a, shard), fed)
-                self.train_fed = self._mesh_pinned_fed = fed
-            else:
-                fed = cached
-
         # Reproduce the host loop's per-round rng chain exactly.
         keys = []
         for _ in range(n_rounds):
@@ -1571,7 +1560,8 @@ class FedAvgAPI(FederatedLoop):
         # BACK is what instance state rebinds to (fedlint R5 discipline
         # — the donated buffers are dead after the call).
         net0, extra0 = self.net, self._window_carry_init()
-        carry, losses = scan_fn(net0, extra0, fed, jnp.stack(keys))
+        carry, losses = scan_fn(net0, extra0, self.train_fed,
+                                jnp.stack(keys))
         self.net, extra = carry
         self._window_carry_commit(extra)
         return losses

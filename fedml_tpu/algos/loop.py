@@ -42,7 +42,7 @@ class FederatedLoop(ExcludedScanTiers):
 
     ``round_fn_fused`` is an optional extension point: a jitted
     ``(net, train_fed, idx, wmask, rng)`` round with the client gather
-    traced inside (single-device fast path built by FedAvgAPI).
+    traced inside (built by FedAvgAPI for a resident federation).
 
     The scan-tier entry points come from :class:`ExcludedScanTiers`
     (record-derived refusals keyed on the carry capability
@@ -104,7 +104,7 @@ class FederatedLoop(ExcludedScanTiers):
         sample-count weights (padded slots weight 0), fresh round rng.
         Returns ``(avg_net, mean_loss)`` without touching ``self.net``.
 
-        When the subclass built a fused single-device round
+        When the subclass built a fused round
         (``round_fn_fused``), the gather happens inside the jit — one
         dispatch per round instead of five. With a host-resident
         ``FederatedStore`` (``self._streaming``), the cohort was gathered

@@ -21,6 +21,7 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from fedml_tpu.core.tree import tree_weighted_mean
+from fedml_tpu.data.batching import gather_clients
 
 #: Mesh-axis naming convention for the pod-scale compute plane
 #: (parallel/multihost.py builds these meshes): a mesh whose FIRST axis
@@ -401,6 +402,26 @@ def make_sharded_round(local_train, mesh, axis: str = "clients",
             return body(params, x, y, mask, weights, loss_weights, rng, adv)
 
     return round_fn
+
+
+def make_cohort_gather(mesh):
+    """``gather(fed, idx) -> FederatedArrays``: the sampled clients of a
+    resident federation, taken INSIDE the round's program under the
+    device scope ``fed.gather``. On one chip it is ``gather_clients``;
+    on a mesh ``fed`` is replicated, ``idx`` client-sharded, and each
+    shard takes its own rows from its local copy — slot ``i`` lands on
+    shard ``i // n_local``, the placement the round's operands have, with
+    no collective: no chip waits for another's data."""
+
+    def gather(fed, idx):
+        with jax.named_scope("fed.gather"):
+            return gather_clients(fed, idx)
+
+    if mesh is None:
+        return gather
+    cs = P(client_axes(mesh))
+    return shard_map(gather, mesh=mesh, in_specs=(P(), cs), out_specs=cs,
+                     check_vma=False)
 
 
 def make_fused_round_step(round_fn, server_update=None):

@@ -33,7 +33,7 @@ STORE_SPANS = {"fed.store.gather", "fed.store.put"}
 #: placement -> (names on the main thread, names on a worker's line)
 EXPECTED = {
     "resident": (ROUND_SPANS, set()),
-    "mesh": (ROUND_SPANS | {"fed.round.gather"}, set()),
+    "mesh": (ROUND_SPANS, set()),   # gathered inside the sharded program
     "store": (ROUND_SPANS | {"fed.cohort.wait"},
               STORE_SPANS | {"fed.cohort.prefetch"}),
 }
