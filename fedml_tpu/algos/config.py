@@ -84,6 +84,16 @@ class FedConfig:
     # Rematerialize forward activations during backprop (jax.checkpoint):
     # trades ~1.3x FLOPs for depth-independent peak HBM.
     remat: bool = False
+    # Clients trained at a time inside one round (parallel/shard.
+    # fold_client_groups): 0 (default) is the whole cohort under one
+    # vmap, which keeps every client's trained model [C, ...] alive until
+    # the mean; k > 0 scans over C/k groups of k and folds each group
+    # into a running weighted sum, for models a cohort of whose copies
+    # does not fit the chip (k of each shard's clients on a mesh). Mean
+    # aggregation only: robust aggregators, client transforms
+    # (cfg.compress, norm clipping) and the corruption drill need the
+    # whole stack and refuse k < C loudly.
+    client_group_size: int = 0
     # Client selection strategy (new capability — the reference only has
     # uniform seeded sampling, FedAVGAggregator.py:90-99): "random", or
     # "pow_d" (Power-of-Choice, Cho et al. 2020 — sample pow_d_candidates

@@ -247,6 +247,10 @@ class FedAvgAPI(FederatedLoop):
                 "(per-round adversary masks); use FedAvgRobustAPI — on "
                 f"{type(self).__name__} the flag would be silently inert")
         self.n_shards = client_shards(mesh)
+        # Clients trained at a time (0: the whole cohort, today's round).
+        self._client_group = int(getattr(cfg, "client_group_size", 0) or 0)
+        if self._client_group:
+            self._check_client_group()
         # Pod-scale reduction observability (docs/OBSERVABILITY.md): on
         # a DCN×ICI mesh the O(G)-inter-host-traffic claim is an
         # OBSERVABLE — per-round ctrl/ gauges of how many model-sized
@@ -444,6 +448,35 @@ class FedAvgAPI(FederatedLoop):
         — forgetting this is how a subclass silently trains at a stale lr
         under --lr_schedule."""
 
+    def _check_client_group(self) -> None:
+        """``cfg.client_group_size`` rides the shared round builders and
+        the weighted mean only: refuse what would silently ignore it (a
+        class with a round, builders or step of its own) or needs every
+        trained client at once."""
+        from fedml_tpu.parallel.shard import whole_stack_needed
+
+        k, cfg = self._client_group, self.cfg
+        rec = self.capability()
+        if k < 0 or rec.custom_round or rec.custom_builders \
+                or rec.custom_step:
+            raise NotImplementedError(
+                f"cfg.client_group_size={k}: {type(self).__name__} "
+                "customizes the round, its builders or its step; grouped "
+                "client training rides the FedAvg family's shared round "
+                "builders only (the field would be silently inert)")
+        cohort = min(cfg.client_num_per_round, cfg.client_num_in_total)
+        per_shard = -(-cohort // self.n_shards)
+        if per_shard % k:
+            raise ValueError(
+                f"cfg.client_group_size={k} does not divide the {per_shard} "
+                f"clients a round trains on each of {self.n_shards} "
+                "shard(s)")
+        if k != per_shard:
+            whole_stack_needed(
+                k, aggregator=self._round_aggregator(),
+                client_transform=self._client_transform(),
+                corruptor=self._corruptor())
+
     def _make_vmap_round(self, local_train, transform, guard):
         """Single-device round construction; q-FedAvg swaps in a
         loss-reweighted aggregation here. Under oort selection the round
@@ -454,7 +487,7 @@ class FedAvgAPI(FederatedLoop):
             local_train, client_transform=transform, nan_guard=guard,
             with_client_losses=self.cfg.client_selection == "oort",
             aggregator=self._round_aggregator(),
-            corruptor=self._corruptor())
+            corruptor=self._corruptor(), group=self._client_group)
 
     def _make_sharded_round(self, local_train, mesh, transform, guard):
         return make_sharded_round(
@@ -463,7 +496,7 @@ class FedAvgAPI(FederatedLoop):
             with_client_losses=self.cfg.client_selection == "oort",
             aggregator=self._round_aggregator(),
             corruptor=self._corruptor(),
-            group_reduce=self._group_reduce)
+            group_reduce=self._group_reduce, group=self._client_group)
 
     def _round_aggregator(self):
         """The aggregator handed to the round builders: ``None`` for mean

@@ -1964,6 +1964,14 @@ def build_federation_setup(model, train_fed: FederatedArrays, test_global,
             "cfg.group_reduce shrinks the client-MESH collective "
             "(parallel/shard.py); the message-passing tiers aggregate "
             "on the server host — drop the flag")
+    if getattr(cfg, "client_group_size", 0):
+        # Each worker trains one client a round: there is no cohort on a
+        # chip to group.
+        raise NotImplementedError(
+            f"cfg.client_group_size={cfg.client_group_size} groups the "
+            "cohort a simulator round trains on one chip (parallel/"
+            "shard.py); a message-passing worker trains one client — "
+            "drop the flag")
     adapter_holder = None
     if int(getattr(cfg, "adapter_rank", 0) or 0):
         # Frozen-base adapter finetuning (PR 15, models/adapter.py): the

@@ -196,6 +196,13 @@ def add_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
                         "jitted client step; params, gradients, "
                         "optimizer, aggregation and server carry stay "
                         "fp32 (docs/EXECUTION.md MFU playbook)")
+    p.add_argument("--client_group_size", type=int, default=0,
+                   help="clients trained at a time inside one round: 0 "
+                        "(default) the whole cohort under one vmap | k > 0 "
+                        "a scan over groups of k, each folded into a "
+                        "running weighted sum, for models a cohort of whose "
+                        "copies does not fit the chip (mean aggregation "
+                        "only; docs/EXECUTION.md)")
     p.add_argument("--group_reduce", action="store_true",
                    help="hierarchical sparse reduction on a client mesh "
                         "(cfg.group_reduce): group-composable "
@@ -363,6 +370,8 @@ def reject_pod_plane_flags(args, algorithm: str) -> None:
         bad.append(f"--client_step_dtype {args.client_step_dtype}")
     if getattr(args, "group_reduce", False):
         bad.append("--group_reduce")
+    if getattr(args, "client_group_size", 0):
+        bad.append(f"--client_group_size {args.client_group_size}")
     if getattr(args, "dcn_hosts", 0):
         bad.append(f"--dcn_hosts {args.dcn_hosts}")
     if bad:
@@ -554,6 +563,7 @@ def config_from_args(args: argparse.Namespace) -> FedConfig:
         dp_noise_multiplier=args.dp_noise_multiplier,
         compute_layout=args.compute_layout,
         client_step_dtype=args.client_step_dtype,
+        client_group_size=int(getattr(args, "client_group_size", 0) or 0),
         adapter_rank=int(getattr(args, "adapter_rank", 0) or 0),
         adapter_scope=getattr(args, "adapter_scope", "attn"),
         group_reduce=bool(getattr(args, "group_reduce", False)),

@@ -164,6 +164,7 @@ def run(args, algorithm: str = "FedAvg"):
     # trio by the shared round builders under setup_standard.
     # fedlint: consumes(aggregator, corrupt_mode)
     # fedlint: consumes(client_step_dtype, group_reduce, dcn_hosts)
+    # fedlint: consumes(client_group_size)
     if algorithm != "FedAdapter":
         # Frozen-base adapter knobs configure FedAdapter only on this
         # tier — on any other algorithm they would silently train the

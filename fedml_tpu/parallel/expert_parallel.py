@@ -11,6 +11,13 @@ a second all_to_all returns outputs, which are unpacked and gate-weighted.
 Tokens over capacity are dropped (output 0 — standard Switch-style
 behavior); with ``capacity >= tokens_per_device`` no token can drop and the
 sharded result equals the dense oracle exactly (tested).
+
+``route_held`` / ``held_expert_products`` are the layer a model calls as ONE
+SHARD of an expert-parallel layer: it is told how many experts exist and
+which contiguous range it holds, routes top-k over all of them, keeps every
+token (no capacity drops), and computes the part of the result its own
+experts give. On one chip it runs without its exchange, and nothing stands
+in for the absent shards: a token none of whose experts is held gets 0 here.
 """
 
 from __future__ import annotations
@@ -119,3 +126,150 @@ def make_moe_ep(mesh, axis: str = "ep", capacity: int | None = None):
         return out * (gate * keep.astype(x.dtype))[:, None]
 
     return validated
+
+
+# ---------------------------------------------------------------------------
+# One shard's part of a top-k expert layer (no exchange, no drops)
+# ---------------------------------------------------------------------------
+
+class HeldRouting(NamedTuple):
+    """Top-k routing over ALL experts, laid out for the held range."""
+
+    idx: jax.Array       # [N, k] the chosen experts (global ids)
+    weight: jax.Array    # [N, k] float32 combine weights
+    token: jax.Array     # [rows] source token of each row of the layout
+    slot_weight: jax.Array  # [rows] float32; 0 on a padding row
+    tile_expert: jax.Array  # [rows / tile] the held expert a tile belongs to
+    counts: jax.Array    # [H] int32 tokens routed to each held expert
+    unrouted: jax.Array  # [] int32 tokens none of whose experts is held
+    overflow: jax.Array  # [] bool: the held assignments need more tiles
+
+
+#: rows of the grouped product over the mean number of held assignments. A
+#: router trained with no balancing loss sends its held experts 2-5 times
+#: their share of the tokens within a benchmark window (PERF.md, PR 28); the
+#: rows cost products whether filled or not, so not more than this.
+CAPACITY_FACTOR = 3.0
+
+
+def held_layout(n_tokens: int, top_k: int, n_experts: int,
+                n_held: int) -> tuple:
+    """``(tile, n_tiles)`` of the grouped product's rows: tiles of 128 rows
+    (the MXU's; 8 where an expert's mean load is under 64 tokens), each
+    belonging to one held expert, whose tokens fill as many tiles as they
+    need. Room for ``CAPACITY_FACTOR`` times the mean number of held
+    assignments plus one part-filled tile an expert. Not a drop threshold:
+    past it the layer takes its dense path (``held_expert_products``). What
+    overflows is the TOTAL, not one popular expert: an untrained router is
+    flat, a trained one is not."""
+    per_expert = n_tokens * top_k / n_experts
+    tile = 128 if per_expert >= 64 else 8
+    return tile, n_held + int(
+        -(-CAPACITY_FACTOR * per_expert * n_held // tile))
+
+
+def route_held(x, w_router, n_held: int, first: int, top_k: int, tile: int,
+               n_tiles: int, renormalise: bool = True) -> HeldRouting:
+    """``x [N, d]`` and ``w_router [d, E]`` in float32: softmax over all
+    ``E`` experts, the ``top_k`` largest, weights renormalised over the
+    chosen (``renormalise``). The assignments to held experts
+    ``first .. first + n_held - 1`` are SORTED by expert (one stable sort of
+    the ``N * top_k`` assignments, the others keyed past the last held
+    expert) and laid out in ``n_tiles`` tiles of ``tile`` rows, an expert's
+    tokens padded to whole tiles."""
+    n = x.shape[0]
+    probs = jax.nn.softmax(
+        jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST), axis=-1)
+    weight, idx = jax.lax.top_k(probs, top_k)
+    if renormalise:
+        weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+    local = idx - first
+    held = (local >= 0) & (local < n_held)
+    key = jnp.where(held, local, n_held).reshape(-1)        # [N * k]
+    order = jnp.argsort(key, stable=True)
+    counts = jnp.sum(
+        (key[:, None] == jnp.arange(n_held)[None, :]).astype(jnp.int32),
+        axis=0)
+    start = jnp.cumsum(counts) - counts         # in the sorted assignments
+    padded = -(-counts // tile) * tile
+    end = jnp.cumsum(padded)                    # in the layout's rows
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(end, jnp.arange(n_tiles) * tile, side="right"),
+        n_held - 1).astype(jnp.int32)
+    expert = jnp.repeat(tile_expert, tile)                  # [rows]
+    rank = jnp.arange(n_tiles * tile) - (end - padded)[expert]
+    valid = rank < counts[expert]       # false on padding and spare tiles
+    source = order[jnp.clip(start[expert] + rank, 0, n * top_k - 1)]
+    return HeldRouting(
+        idx=idx, weight=weight, token=source // top_k,
+        slot_weight=jnp.where(valid, weight.reshape(-1)[source], 0.0),
+        tile_expert=tile_expert, counts=counts,
+        unrouted=jnp.sum(1 - jnp.any(held, axis=-1).astype(jnp.int32)),
+        overflow=end[-1] > n_tiles * tile)
+
+
+def gated_mlp(x, w_gate_up, w_down, spec_in: str, spec_out: str):
+    """``W_down (SiLU(W_gate x) * W_up x)`` with gate and up side by side in
+    ``w_gate_up [..., d, 2f]``; float32 accumulation."""
+    f32 = jnp.float32
+    gate_up = jnp.einsum(spec_in, x, w_gate_up, preferred_element_type=f32)
+    gate, up = jnp.split(gate_up, 2, axis=-1)
+    hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
+    return jnp.einsum(spec_out, hidden, w_down, preferred_element_type=f32)
+
+
+def held_expert_products(x, routing: HeldRouting, w_gate_up, w_down,
+                         first: int):
+    """The held experts' part of the layer: ``sum_{e in top-k(n), e held}
+    w_ne E_e(x_n)`` as float32 ``[N, d]``. ``w_gate_up [H, d, 2f]``,
+    ``w_down [H, f, d]``, in ``x``'s dtype.
+
+    Grouped products: the layout's rows gathered tile by tile, each tile
+    multiplied by its own expert's matrices (padded-group einsum: one
+    batched product over ``[tiles, tile, d]``, standard MXU shapes),
+    weighted and scatter-added back. Not ``jax.lax.ragged_dot`` on the
+    sorted assignments (measured on the v5e, PERF.md section 6, PR 28): over
+    all ``N * k`` of them, which needs no capacity, it is 1.8 times slower a
+    layer, forward and backward, than these tiles; over the tiles' rows it
+    is 0.73 times, but keeps the capacity and the dense arm, leaves the rows
+    past the groups' total unwritten on the chip, and its gradient has no
+    batching rule (jax 0.9.0), so it cannot run under the round's ``vmap``
+    over clients. If the held
+    assignments need more tiles than the layout has, the whole layer is
+    computed the plain way instead (every held expert over every token,
+    masked by the routing; ``lax.cond``, so it costs nothing while unused):
+    slower, never lossy. Each arm is rematerialised, although the model's
+    layer already is: the backward pass of a ``cond`` hands over the
+    residuals of BOTH arms, zeros for the arm not taken, and the dense arm's
+    are 0.9 GB a layer; with the arms' operands as the only residuals a
+    round of the benchmark's cell is 1,544 ms, without 1,655 ms and 0.66 GB
+    more (PERF.md section 6, PR 28)."""
+    n, d = x.shape
+    n_held = w_gate_up.shape[0]
+    tile = routing.token.shape[0] // routing.tile_expert.shape[0]
+
+    @jax.checkpoint
+    def grouped(x, routing, w_gate_up, w_down):
+        rows = jnp.take(x, routing.token, axis=0).reshape(-1, tile, d)
+        out = gated_mlp(rows, jnp.take(w_gate_up, routing.tile_expert, axis=0),
+                        jnp.take(w_down, routing.tile_expert, axis=0),
+                        "tcd,tdf->tcf", "tcf,tfd->tcd")
+        out = out.reshape(-1, d) * routing.slot_weight[:, None]
+        return jnp.zeros((n, d), jnp.float32).at[routing.token].add(out)
+
+    @jax.checkpoint
+    def dense(x, routing, w_gate_up, w_down):
+        def one(acc, expert):
+            e, gate_up, down = expert
+            w_e = jnp.sum(jnp.where(routing.idx == first + e,
+                                    routing.weight, 0.0), axis=-1)
+            out = gated_mlp(x, gate_up, down, "nd,df->nf", "nf,fd->nd")
+            return acc + out * w_e[:, None], None
+
+        acc, _ = jax.lax.scan(one, jnp.zeros((n, d), jnp.float32),
+                              (jnp.arange(n_held), w_gate_up, w_down))
+        return acc
+
+    return jax.lax.cond(routing.overflow, dense, grouped, x, routing,
+                        w_gate_up, w_down)

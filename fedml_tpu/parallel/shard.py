@@ -99,7 +99,8 @@ def client_finite_mask(client_params) -> jnp.ndarray:
 
 
 def run_clients_guarded(local_train, client_transform, nan_guard,
-                        net, x, y, mask, rngs, corruptor=None, adv=None):
+                        net, x, y, mask, rngs, corruptor=None, adv=None,
+                        unbatched: bool = False):
     """Shared per-round client-training prelude: vmapped local training,
     optional ADVERSARIAL corruption, optional post-transform (robust
     clipping etc.), and the NaN-guard zeroing. Returns ``(client_nets,
@@ -126,11 +127,21 @@ def run_clients_guarded(local_train, client_transform, nan_guard,
     It runs BEFORE the transform and the guard — exactly the real threat
     order: the server's defenses see the already-corrupted updates. Its
     per-client streams are forked with their own reserved constant
-    (0xC0), disjoint from training's and the transform's (0x7F)."""
+    (0xC0), disjoint from training's and the transform's (0x7F).
+
+    ``unbatched`` (the grouped round at one client a group): a stack of one
+    needs no ``vmap``, and without it control flow inside a model
+    (``lax.cond``, ``while_loop``) stays control flow instead of becoming a
+    select that runs every arm."""
     with jax.named_scope("fed.local_train"):
-        client_nets, losses = jax.vmap(
-            local_train, in_axes=(None, 0, 0, 0, 0)
-        )(net, x, y, mask, rngs)
+        if unbatched:
+            one_net, one_loss = local_train(net, x[0], y[0], mask[0], rngs[0])
+            client_nets = jax.tree.map(lambda p: p[None], one_net)
+            losses = one_loss[None]
+        else:
+            client_nets, losses = jax.vmap(
+                local_train, in_axes=(None, 0, 0, 0, 0)
+            )(net, x, y, mask, rngs)
     if corruptor is not None:
         crngs = jax.vmap(lambda r: jax.random.fold_in(r, 0xC0))(rngs)
         client_nets = corruptor(net, client_nets, adv, crngs)
@@ -171,9 +182,79 @@ def _robust_avg(aggregator, client_params, weights, params):
     return jax.tree.map(lambda a, p: jnp.where(any_ok, a, p), avg, params)
 
 
+def whole_stack_needed(group: int, **users) -> None:
+    """Refuse ``group`` clients at a time (``cfg.client_group_size``) for
+    what reads the whole trained stack ``[C, ...]``: the grouped round
+    keeps only a running weighted sum of it."""
+    named = [name for name, user in users.items() if user is not None]
+    if named:
+        raise NotImplementedError(
+            f"client_group_size={group} trains the cohort {group} clients "
+            "at a time and folds each group into a running weighted sum; "
+            f"{', '.join(named)} need(s) every client's trained model at "
+            "once — use client_group_size=0 (the whole cohort)")
+
+
+def fold_client_groups(local_train, nan_guard, group, params, x, y, mask,
+                       weights, loss_weights, rngs):
+    """The cohort ``[C, ...]`` trained ``group`` clients at a time: a
+    ``lax.scan`` over ``C / group`` groups, ``vmap`` inside a group, each
+    group's trained models folded into a running ``sum w_i theta_i`` — one
+    model-sized accumulator instead of the stack ``[C, ...]``, for models a
+    cohort of whose copies does not fit. ``nan_guard`` works a group at a
+    time. Returns ``(sum w_i theta_i (float32), sum w_i, sum lw_i loss_i,
+    sum lw_i, losses [C])``."""
+    n = x.shape[0]
+    # fedlint: disable=R4(group is cfg.client_group_size, a Python int fixed when the round is built, and n a static shape)
+    if n % group:
+        raise ValueError(
+            f"client_group_size={group} does not divide the {n} clients a "
+            "round trains here (the cohort, padded to the mesh's shards, "
+            "over the shards)")
+
+    def grouped(a):
+        return a.reshape((n // group, group) + a.shape[1:])
+
+    def body(carry, operands):
+        acc, sum_w, sum_loss, sum_lw = carry
+        xg, yg, mg, wg, lwg, rg = operands
+        nets, losses, finite = run_clients_guarded(
+            local_train, None, nan_guard, params, xg, yg, mg, rg,
+            unbatched=group == 1)
+        with jax.named_scope("fed.aggregate"), \
+                jax.named_scope("fed.client_fold"):
+            wg = wg.astype(jnp.float32) * finite
+            lwg = lwg.astype(jnp.float32) * finite
+            acc = jax.tree.map(
+                lambda a, p: a + jnp.einsum(
+                    "c,c...->...", wg, p.astype(jnp.float32)), acc, nets)
+            carry = (acc, sum_w + jnp.sum(wg),
+                     sum_loss + jnp.sum(losses * lwg), sum_lw + jnp.sum(lwg))
+        return carry, losses
+
+    zero = jnp.zeros((), jnp.float32)
+    init = (jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params),
+            zero, zero, zero)
+    (acc, sum_w, sum_loss, sum_lw), losses = jax.lax.scan(
+        body, init, tuple(grouped(a) for a in (
+            x, y, mask, weights, loss_weights, rngs)))
+    return acc, sum_w, sum_loss, sum_lw, losses.reshape(n)
+
+
+def _grouped_mean(params, acc, sum_w, sum_loss, sum_lw):
+    """``(avg, mean_loss)`` from :func:`fold_client_groups`' sums (after
+    the ``psum`` on a mesh). No client carried weight (every one diverged
+    under ``nan_guard``, or an all-pad round): the previous global model."""
+    avg = jax.tree.map(
+        lambda a, p: jnp.where(
+            sum_w > 0, a / jnp.maximum(sum_w, 1e-12), p).astype(p.dtype),
+        acc, params)
+    return avg, sum_loss / jnp.maximum(sum_lw, 1e-12)
+
+
 def make_vmap_round(local_train, client_transform=None, nan_guard: bool = False,
                     with_client_losses: bool = False, aggregator=None,
-                    corruptor=None):
+                    corruptor=None, group: int = 0):
     """``round_fn(params, x, y, mask, weights, loss_weights, rng) ->
     (avg_params, mean_loss)`` with client-stacked inputs ``[C, S, B, ...]``.
 
@@ -204,12 +285,30 @@ def make_vmap_round(local_train, client_transform=None, nan_guard: bool = False,
     trailing ``adv [C]`` operand (adversary mask) and the corruptor runs
     on the trained stack before the transform/guard — see
     :func:`run_clients_guarded`. The mask-driven form means the drill
-    rides every tier, including the windowed ``lax.scan`` body."""
+    rides every tier, including the windowed ``lax.scan`` body.
+
+    ``group`` (``cfg.client_group_size``) trains the cohort that many
+    clients at a time (:func:`fold_client_groups`); 0, or the cohort's
+    size, is the one ``vmap`` over the whole cohort. Mean aggregation
+    only: what needs the stack refuses at the first trace, by name
+    (:func:`whole_stack_needed`; ``FedAvgAPI`` refuses at construction)."""
     if _is_mean(aggregator):
         aggregator = None
 
     def round_core(params, x, y, mask, weights, loss_weights, rng, adv):
         rngs = client_rngs(rng, x.shape[0], 0)
+        if group and group != x.shape[0]:
+            whole_stack_needed(
+                group, aggregator=aggregator,
+                client_transform=client_transform, corruptor=corruptor)
+            *sums, losses = fold_client_groups(
+                local_train, nan_guard, group, params, x, y, mask, weights,
+                loss_weights, rngs)
+            with jax.named_scope("fed.aggregate"):
+                avg, mean_loss = _grouped_mean(params, *sums)
+            if with_client_losses:
+                return avg, mean_loss, losses
+            return avg, mean_loss
         client_params, losses, finite = run_clients_guarded(
             local_train, client_transform, nan_guard,
             params, x, y, mask, rngs, corruptor=corruptor, adv=adv)
@@ -251,7 +350,8 @@ def client_rngs(rng, n_local, offset):
 def make_sharded_round(local_train, mesh, axis: str = "clients",
                        client_transform=None, nan_guard: bool = False,
                        with_client_losses: bool = False, aggregator=None,
-                       corruptor=None, group_reduce: bool = False):
+                       corruptor=None, group_reduce: bool = False,
+                       group: int = 0):
     """Sharded round: client axis split over ``mesh[axis]``; output replicated.
 
     Weighted average = psum of per-shard weighted partial sums / psum of
@@ -296,7 +396,11 @@ def make_sharded_round(local_train, mesh, axis: str = "clients",
     (``group_reduce=False``).
 
     ``corruptor`` as in :func:`make_vmap_round`: the round grows a
-    trailing client-sharded ``adv`` operand."""
+    trailing client-sharded ``adv`` operand.
+
+    ``group`` as in :func:`make_vmap_round`, of each shard's own clients:
+    every shard folds its groups into its partial sums, which meet in the
+    same ``psum`` as the whole-cohort mean's."""
     if _is_mean(aggregator):
         aggregator = None
     if group_reduce and aggregator is not None \
@@ -377,6 +481,21 @@ def make_sharded_round(local_train, mesh, axis: str = "clients",
         # client dimension out in.
         shard_idx = jax.lax.axis_index(gather_ax)
         rngs = client_rngs(rng, x.shape[0], shard_idx * x.shape[0])
+        if group and group != x.shape[0]:
+            whole_stack_needed(
+                group, aggregator=aggregator,
+                client_transform=client_transform, corruptor=corruptor)
+            *sums, losses = fold_client_groups(
+                local_train, nan_guard, group, params, x, y, mask, weights,
+                loss_weights, rngs)
+            with jax.named_scope("fed.aggregate"):
+                acc, *scalars = sums
+                acc = jax.tree.map(lambda a: _psum_hier(a, axes), acc)
+                avg, loss = _grouped_mean(
+                    params, acc, *(_psum_hier(v, axes) for v in scalars))
+            if with_client_losses:
+                return avg, loss, losses
+            return avg, loss
         client_params, losses, finite = run_clients_guarded(
             local_train, client_transform, nan_guard,
             params, x, y, mask, rngs, corruptor=corruptor, adv=adv)

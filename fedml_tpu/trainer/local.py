@@ -51,7 +51,12 @@ def model_fns(module) -> ModelFns:
     """Wrap a flax.linen module (taking a ``train`` kwarg) into ModelFns."""
 
     def init(rng, sample_x) -> NetState:
-        variables = module.init({"params": rng}, sample_x, train=False)
+        # One program, not an eager walk of the forward pass op by op (350 s
+        # of small compiles for a 424 M-parameter transformer on the chip,
+        # PR 28); the values are the eager init's bit for bit.
+        variables = jax.jit(
+            lambda r, x: module.init({"params": r}, x, train=False)
+        )(rng, sample_x)
         params = variables["params"]
         state = {k: v for k, v in variables.items() if k != "params"}
         return NetState(params=params, model_state=state)

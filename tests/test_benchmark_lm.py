@@ -1,0 +1,349 @@
+"""The benchmark files of the Qwen3-Next cell and of the LDA cell (PR 28):
+the configuration against the catalog entry, the model it builds and the
+work counts; the two generators; the scope reducer on a hand-made trace; the
+nine readers against the manifest. CPU; nothing here reads a device number.
+"""
+
+import importlib.util
+import json
+import os
+
+import jax
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCHMARK = os.path.join(ROOT, "benchmark")
+CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+CONFIG = "qwen3_next_80b_a3b"
+CELL = "qwen3next_c4_s4k"
+NEW_READERS = [
+    "device_ms.gdn.round", "device_ms.attn.round", "device_ms.moe.round",
+    "device_ms.head.round", "device_ms.client_fold.round",
+    "gdn_scan_roofline_pct", "attn_core_roofline_pct",
+    "moe_experts_roofline_pct", "moe_load_max_over_mean"]
+
+
+def _load(relative: str):
+    path = os.path.join(BENCHMARK, relative)
+    name = "bench_test_" + relative.replace("/", "_").replace(".", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def _json(*relative):
+    with open(os.path.join(ROOT, *relative)) as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def config():
+    return _json("benchmark", "configs", CONFIG + ".json")
+
+
+@pytest.fixture(scope="module")
+def mix():
+    return _json("benchmark", "traffic", "resident_silos_c4_s4k.json")
+
+
+# --- the configuration ------------------------------------------------------
+
+def test_every_published_number_is_in_the_file_or_in_reduced(config):
+    """The catalog entry's ``config`` key for key: equal, or listed in
+    ``reduced`` with the published value beside it; ``reduced`` names no
+    width."""
+    if not os.path.exists(CATALOG):
+        pytest.skip("the model-configs catalog is not on this machine")
+    with open(CATALOG) as f:
+        entry, = [row for row in map(json.loads, f)
+                  if row["source_url"] == config["source"]]
+    for key, value in entry["config"].items():
+        if key in config["reduced"]:
+            assert config["published"][key] == value, key
+            assert config[key] != value, key
+        else:
+            assert config[key] == value, key
+    assert sorted(config["reduced"]) == sorted(config["published"])
+    widths = ("hidden_size", "intermediate", "_dim", "_rank", "per_tok")
+    assert not [k for k in config["reduced"]
+                if any(w in k for w in widths)]
+    manifest = _json("BENCHMARK.json")
+    listed, = [c for c in manifest["configs"] if c["name"] == CONFIG]
+    assert listed["reduced"] == config["reduced"]
+    assert listed["source"] == config["source"]
+    assert len(config["reduced_detail"]) == len(config["reduced"])
+
+
+def test_the_factory_builds_what_the_file_states(config):
+    """``factory_kwargs`` is the file's own top level (the router keeps the
+    published width, ``num_experts_held`` is the file's ``num_experts``),
+    and the model it builds has the parameters the file counts."""
+    from fedml_tpu.models.qwen3_next import qwen3_next
+
+    kwargs = config["factory_kwargs"]
+    for key, value in kwargs.items():
+        if key == "num_experts":
+            assert value == config["published"]["num_experts"]
+        elif key in config:
+            assert config[key] == value, key
+    assert kwargs["num_experts_held"] == config["num_experts"]
+    assert config["classes"] == config["vocab_size"] == kwargs["vocab_size"]
+    model = qwen3_next(**kwargs)
+    ids = jax.ShapeDtypeStruct((1, 128), np.int32)
+    shapes = jax.eval_shape(
+        lambda i: model.init({"params": jax.random.PRNGKey(0)}, i), ids)
+    counted = sum(int(np.prod(leaf.shape))
+                  for leaf in jax.tree.leaves(shapes["params"]))
+    assert counted == config["parameters"]
+    counts = _load(config["counts"])
+    assert counts.parameters(kwargs) == config["parameters"]
+    from fedml_tpu.algos.config import FedConfig
+
+    assert all(hasattr(FedConfig(), k) for k in config["fed_config"])
+
+
+def test_the_frozen_flops_are_the_counts(config, mix):
+    counts = _load(config["counts"])
+    assert (counts.train_flops_per_sequence(config, mix)
+            == config["train_flops_per_sample"])
+    per_token = counts.forward_flops_per_token(config["factory_kwargs"],
+                                               mix["sequence_length"])
+    assert {k: round(v) for k, v in per_token.items()} \
+        == config["forward_flops_per_token"]
+    # by hand: the delta rule a value head a token, and the causal core
+    s = config["factory_kwargs"]
+    ops, moved = counts.gdn_scan_forward(s, 1)
+    assert ops == 32 * (7 * 128 * 128 + 3 * 128)
+    assert moved == (2 * 16 * 128 + 2 * 32 * 128) * 2 + 2 * 32 * 4
+    ops, _ = counts.attn_core_forward(s, 4)
+    assert ops == 16 * 4 * 256 * (4 * 5 // 2)
+    assert counts.held_assignments_per_token(s) == 10 * 16 / 512
+    assert counts.steps_per_round(mix) == 8
+    peaks = _json("benchmark", "peaks.json")["TPU v5 lite"]
+    for kernel in ("gdn_scan", "attn_core", "moe_experts"):
+        assert counts.roofline_ms_per_round(kernel, config, mix, peaks) > 0
+
+
+def test_the_dryrun_sizes_are_the_cpu_tests_sizes(config):
+    import test_qwen3_next
+
+    small = dict(test_qwen3_next.SMALL, attention="flash")
+    assert config["dryrun"]["factory_kwargs"] == small
+
+
+# --- the generators ---------------------------------------------------------
+
+def test_packed_documents_from_a_seed(mix):
+    gen = _load("generators/lm_zipf_docs.py")
+    small = {**mix, **mix["dryrun"]}
+    cfg = {"classes": 256}
+    a = gen.generate(small, cfg, 2 ** 31 + 11)
+    b = gen.generate(small, cfg, 2 ** 31 + 11)
+    c = gen.generate(small, cfg, 7)
+    x, y, parts, counts = a
+    assert x.dtype == np.int32 and x.shape == (8, 128) == y.shape
+    assert all(np.array_equal(u, v) for u, v in zip(a[:2], b[:2]))
+    assert not np.array_equal(x, c[0]) and np.array_equal(counts, c[3])
+    assert x.min() >= gen.SEPARATOR and x.max() < 256     # never pad_id
+    np.testing.assert_array_equal(y[:, :-1], x[:, 1:])    # the next token
+    assert (y[:, -1] == gen.PAD).all()
+    assert (x == gen.SEPARATOR).any()
+    assert sorted(np.concatenate(list(parts.values()))) == list(range(8))
+    # Zipf: a client's commonest word is far commoner than its median one
+    freq = np.sort(np.bincount(x[:2].ravel(), minlength=256))[::-1]
+    assert freq[0] > 8 * max(1, freq[64])
+
+
+def test_lda_silos_do_not_move_with_the_seed():
+    gen = _load("generators/class_templates_lda.py")
+    mix = _json("benchmark", "traffic", "resident_lda_c10.json")
+    small = {**mix, **mix["dryrun"]}
+    cfg = {"classes": 10, "input_shape": [8, 8, 3]}
+    a, b = gen.generate(small, cfg, 2 ** 31 + 5), gen.generate(small, cfg, 9)
+    assert np.array_equal(a[1], b[1]) and np.array_equal(a[3], b[3])
+    assert all(np.array_equal(a[2][c], b[2][c]) for c in a[2])
+    assert not np.array_equal(a[0], b[0])
+    assert a[3].sum() == len(a[0]) == 160 and len(set(a[3])) > 1
+    # the label skew of a Dirichlet split: some silo lacks some class
+    held = [set(a[1][a[2][c]]) for c in a[2]]
+    assert any(len(h) < 10 for h in held)
+    # the cell's own sizes: 10 silos of 835 to 1,720 samples, 27 steps
+    from fedml_tpu.data.partition import partition_dirichlet
+
+    own = np.random.RandomState(0)
+    y = own.permutation(np.repeat(np.arange(10, dtype=np.int32), 1280))
+    sizes = [len(p) for p in partition_dirichlet(y, 10, 0.5, seed=0).values()]
+    assert (min(sizes), max(sizes), -(-max(sizes) // 64)) == (835, 1720, 27)
+
+
+# --- the scope reducer ------------------------------------------------------
+
+def test_scope_of_takes_the_innermost_and_survives_transforms():
+    rsc = _load("reduce_scopes.py")
+    path = ("jit(gather_step)/fed.local_train/transpose(jvp(fed.model.gdn))/"
+            "fed.model.gdn.scan/while/body/dot_general")
+    assert rsc.scope_of([path]) == "fed.model.gdn.scan"
+    assert rsc.scope_of(["jit(f)/fed.aggregate/fed.client_fold/add"]) \
+        == "fed.client_fold"
+    assert rsc.scope_of(["x/fed.model.moe/fed.model.moe.route/sort"]) \
+        == "fed.model.moe.route"
+    assert rsc.scope_of(["jit(f)/fed.local_train/mul", ""]) == ""
+
+
+def test_reduce_books_self_time_by_scope():
+    """Two rounds; a ``while`` of 60 with a 40 body under the scan scope, an
+    attention op clipped by the window's end, an op with no model scope."""
+    rsc = _load("reduce_scopes.py")
+    events = [
+        ["host", "bench.round", 0, 100, ""], ["host", "fed.round", 5, 90, ""],
+        ["host", "bench.round", 100, 100, ""],
+        ["host", "fed.round", 105, 90, ""],
+        ["op", "while.1", 10, 60, "fed.model.gdn"],
+        ["op", "fusion.2", 20, 40, "fed.model.gdn.scan"],
+        ["op", "fusion.3", 80, 10, ""],
+        ["op", "custom-call.4", 190, 30, "fed.model.attn.core"]]
+    got = rsc.reduce(events)
+    assert got["rounds"] == 2
+    assert got["device_ns_by_scope"] == {
+        "": 10, "fed.model.attn.core": 10, "fed.model.gdn": 20,
+        "fed.model.gdn.scan": 40}
+    assert got["top_ops"][0] == ["fed.model.gdn.scan", "fusion.2", 40]
+    assert rsc.reduce(events[4:]) is None
+    assert "fed.model.gdn.scan" in rsc.table(got)
+
+
+def test_scopes_are_read_from_the_event_metadata(tmp_path):
+    """The wire reader's walk (reduce_spans' ``_fields``) on a hand-made
+    ``.xplane.pb``: one plane, two events' metadata, a string stat and an
+    interned one."""
+    import test_round_spans as spans
+
+    rsc = _load("reduce_scopes.py")
+    pb = spans._pb
+    path = "jit(f)/fed.local_train/%s"
+    plane = pb(
+        (1, 3), (2, b"/device:TPU:0"),
+        (5, pb((1, 300), (2, pb((1, 300), (2, (
+            path % "fed.model.moe/fed.model.moe.experts/dot").encode()))))),
+        (4, pb((1, 1), (2, pb(
+            (1, 1), (2, b"%fusion.1 = f32[8]"),
+            (5, pb((1, 7), (5, (path % "fed.model.head/take").encode()))))))),
+        (4, pb((1, 2), (2, pb(
+            (1, 2), (2, b"%fusion.2 = f32[8]"),
+            (5, pb((1, 7), (7, 300))))))),
+        (4, pb((1, 3), (2, pb((1, 3), (2, b"%copy.3 = f32[8]"))))))
+    file = tmp_path / "t.xplane.pb"
+    file.write_bytes(pb((1, plane)))
+    assert rsc.metadata_scopes(str(file), "/device:TPU:0") == {
+        "%fusion.1 = f32[8]": "fed.model.head",
+        "%fusion.2 = f32[8]": "fed.model.moe.experts"}
+    assert rsc.metadata_scopes(str(file), "/device:TPU:1") == {}
+
+
+# --- the readers ------------------------------------------------------------
+
+@pytest.mark.parametrize("name", NEW_READERS)
+def test_reader_agrees_with_the_manifest_and_reads_none_without_a_trace(
+        name, tmp_path, monkeypatch):
+    manifest = _json("BENCHMARK.json")
+    entry, = [m for m in manifest["per_layer"] if m["name"] == name]
+    reader = _load(f"layer_metrics/{name}.py")
+    assert {k: entry[k] for k in ("layer", "unit", "moves")} == reader.META
+    cells = [w["name"] for w in manifest["workloads"] if reader.applies(w)]
+    assert cells == entry["workloads"] == [CELL]
+    monkeypatch.setattr(reader.rsc.rs, "TRACE_DIR", str(tmp_path))
+    assert reader.read({"chips": 1, "rounds": 3,
+                        "device_kind": "TPU v5 lite"}) is None
+
+
+def test_readers_on_a_reduction(config, mix, monkeypatch):
+    """Scope times through the readers: milliseconds a round by prefix, a
+    roofline share from the counts, ``None`` for a program with no model
+    scope (the parent of this PR), and the counter."""
+    reduction = {"rounds": 4, "device_ns_by_scope": {
+        "": 5e6, "fed.model.gdn": 300e6, "fed.model.gdn.scan": 100e6,
+        "fed.model.attn.core": 80e6, "fed.client_fold": 8e6}}
+    summary = {"device_kind": "TPU v5 lite", "moe_load_max_over_mean": 1.5,
+               "counts": {"module": config["counts"], "config": config,
+                          "mix": mix}}
+    gdn = _load("layer_metrics/device_ms.gdn.round.py")
+    monkeypatch.setattr(gdn.rsc, "traced", lambda: reduction)
+    assert gdn.read(summary) == pytest.approx(100.0)
+    scan = _load("layer_metrics/gdn_scan_roofline_pct.py")
+    monkeypatch.setattr(scan.rsc, "traced", lambda: reduction)
+    counts = _load(config["counts"])
+    least = counts.roofline_ms_per_round(
+        "gdn_scan", config, mix, _json("benchmark", "peaks.json")["TPU v5 lite"])
+    assert scan.read(summary) == pytest.approx(100.0 * least / 25.0)
+    assert 0 < scan.read(summary) < 100
+    moe = _load("layer_metrics/device_ms.moe.round.py")
+    monkeypatch.setattr(moe.rsc, "traced", lambda: reduction)
+    assert moe.read(summary) == 0.0         # scopes named, none of them moe's
+    monkeypatch.setattr(moe.rsc, "traced", lambda: {
+        "rounds": 4, "device_ns_by_scope": {"": 5e6}})
+    assert moe.read(summary) is None        # a program that names none
+    with pytest.raises(KeyError):
+        monkeypatch.setattr(scan.rsc, "traced", lambda: reduction)
+        scan.read({**summary, "device_kind": "TPU v9"})
+    load = _load("layer_metrics/moe_load_max_over_mean.py")
+    assert load.read(summary) == 1.5 and load.read({}) is None
+
+
+# --- the comparison that decides ``correct``, through the harness ------------
+
+@pytest.mark.parametrize("stand_in, failing", [
+    (None, set()),
+    # the contract's planted faults and the lower-precision control, each in
+    # the program's place in the comparison: every one has to fail a limit
+    ("unchanged_state", {
+        "embedding", "norms", "gdn_gates", "conv", "delta_projections",
+        "held_experts", "router", "shared_expert", "attention_projections",
+        "head"}),
+    ("last_batch_left_out", None),
+    ("reference_bits:4", None),
+])
+def test_the_cell_is_correct_and_its_stand_ins_are_not(config, mix, stand_in,
+                                                       failing):
+    """``benchmark/run.py``'s own context and runner at the rehearsal's
+    sizes on the CPU: the round passes every limit of ``TOLERANCES``; a
+    state left unchanged reads 1 on every kind; a client's last batch left
+    out and the reference with float8's 4-bit products each fail at least
+    one limit, so ``correct`` comes out false."""
+    import argparse
+
+    run = _load("run.py")
+    manifest = run.load_manifest()
+    cell = run.by_name(manifest["workloads"], CELL, "workload")
+    args = argparse.Namespace(seed=2800000555, seconds=0.2, trace=0,
+                              dryrun_cpu=True)
+    if stand_in:
+        mix = {**mix, "stand_in": stand_in}
+    ctx = run.Ctx(manifest, cell, config, mix, args, "cpu")
+    runner = ctx.load_module(f"runners/{mix['runner']}.py")
+    result = runner.run(ctx)
+    errors = result["summary"]["reference_errors"]
+    over = {k for k, v in errors.items()
+            if v > runner.TOLERANCES[k]["limit"]}
+    assert result["summary"]["moe_dropped_tokens"] == 0
+    assert result["correct"] == (stand_in is None), errors
+    if failing is None:
+        assert over, errors
+    else:
+        assert over == failing, errors
+    if stand_in == "unchanged_state":
+        assert all(errors[k] == pytest.approx(1.0) for k in failing)
+
+
+def test_every_limit_lies_between_its_two_readings():
+    """``TOLERANCES`` carries both readings of every limit: the largest the
+    program read on the chip, and the control (the reference at the nearest
+    precision below; for the loss, the planted fault). At least twice the
+    first and at most half the second, so that fresh seeds have room and
+    the control has none."""
+    runner = _load("runners/fed_lm_round.py")
+    assert set(runner.TOLERANCES) == {
+        kind for kind, _ in runner._KINDS} | {"loss"}
+    for kind, t in runner.TOLERANCES.items():
+        assert 2 * t["program"] <= t["limit"] <= t["control"] / 2, kind
