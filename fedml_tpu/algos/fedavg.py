@@ -382,6 +382,7 @@ class FedAvgAPI(FederatedLoop):
         self._rounds_scan_fn = None  # round_fn changes → cached scan stale
         self._window_scan_fn = None  # windowed scan rides round_fn too
         self._fused_step_fn = None  # fused round step rides round_fn too
+        self._size_group_fns = None  # ... and the size-grouped round's steps
         self._on_client_lr_change()  # subclasses drop their own cached jits
         cfg, mesh = self.cfg, self.mesh
         optimizer = make_client_optimizer(
@@ -690,20 +691,23 @@ class FedAvgAPI(FederatedLoop):
         idx, wmask = pad_to_multiple(idx, self.n_shards)
         return idx, wmask
 
-    def _stream_cohort(self, round_idx: int, idx):
+    def _stream_cohort(self, round_idx: int, idx, group: int = 0):
         """Fetch the round's cohort from the host store (prefetched when
         possible) and kick off the NEXT round's gather + H2D transfer so
         it overlaps this round's compute. Only seeded-random selection can
-        prefetch — pow_d depends on the current net."""
+        prefetch — pow_d depends on the current net. ``group`` > 0 (the
+        size-grouped round) fetches and prefetches the cohort as its size
+        groups (``FederatedStore.gather_groups``)."""
         from fedml_tpu.data.store import CohortPrefetcher
 
         pf = getattr(self, "_cohort_prefetcher", None)
         if pf is None:
             pf = self._cohort_prefetcher = CohortPrefetcher(self.train_fed)
-        sub = pf.get(round_idx, idx)
-        # Post-round consumers (oort's utility eval) reuse this instead of
-        # paying a second synchronous host gather of the same cohort.
-        self._stream_last = (round_idx, np.asarray(idx), sub)
+        sub = pf.get(round_idx, idx, group)
+        if not group:
+            # Post-round consumers (oort's utility eval) reuse this instead
+            # of paying a second synchronous host gather of the same cohort.
+            self._stream_last = (round_idx, np.asarray(idx), sub)
         if (self.cfg.client_selection == "random"
                 and round_idx + 1 < self.cfg.comm_round):
             from fedml_tpu.core.sampling import pad_to_multiple, sample_clients
@@ -712,7 +716,7 @@ class FedAvgAPI(FederatedLoop):
                 sample_clients(round_idx + 1, self.cfg.client_num_in_total,
                                self.cfg.client_num_per_round),
                 self.n_shards)
-            pf.prefetch(round_idx + 1, nidx)
+            pf.prefetch(round_idx + 1, nidx, group)
         return sub
 
     # --- Oort utility-based selection (Lai et al., OSDI'21) --------------
@@ -940,6 +944,30 @@ class FedAvgAPI(FederatedLoop):
         reg = getattr(self, "_reduce_registry", None)
         return reg.snapshot() if reg is not None else {}
 
+    # --- what the streamed round dispatched ------------------------------
+    def _count_dispatch(self, groups: int, slots: int, idx, wmask) -> None:
+        reg = getattr(self, "_dispatch_registry", None)
+        if reg is None:
+            from fedml_tpu.obs.registry import MetricsRegistry
+
+            reg = self._dispatch_registry = MetricsRegistry()
+        reg.counter("rounds_streamed").inc()
+        reg.counter("groups_dispatched").inc(groups)
+        reg.counter("slots_dispatched").inc(slots)
+        reg.counter("samples_real").inc(int(
+            (self._host_counts()[np.asarray(idx)] * np.asarray(wmask)).sum()))
+
+    def dispatch_profile(self) -> Dict[str, int]:
+        """Running totals of what the streamed rounds put on the device
+        (empty before the first, and on a resident federation):
+        ``rounds_streamed``; ``groups_dispatched`` (1 a whole-cohort round);
+        ``slots_dispatched``, the sample slots trained an epoch (clients x
+        the step bucket they were trained at x batch, padding included);
+        ``samples_real``, the cohort's real samples (weight-masked).
+        ``samples_real / slots_dispatched`` is the dispatched fill."""
+        reg = getattr(self, "_dispatch_registry", None)
+        return reg.snapshot() if reg is not None else {}
+
     # --- capability record (algos/capability.py) ------------------------
     def capability(self):
         """This algorithm's :class:`~fedml_tpu.algos.capability.
@@ -1045,7 +1073,13 @@ class FedAvgAPI(FederatedLoop):
             step = gather
         else:
             if self._streaming:
+                group = self._size_group()
+                if group:
+                    return self._train_round_size_grouped(
+                        round_idx, idx, wmask, rnd_rng, extra, group)
                 sub = self._stream_cohort(round_idx, idx)
+                self._count_dispatch(
+                    1, len(idx) * sub.x.shape[1] * sub.x.shape[2], idx, wmask)
             else:
                 from fedml_tpu.data.batching import gather_clients
 
@@ -1059,6 +1093,117 @@ class FedAvgAPI(FederatedLoop):
             (self.net, extra), loss = step(self.net, extra, *operands)
         self._window_carry_commit(extra)
         self._emit_reduce_obs()
+        return loss
+
+    # --- the size-grouped streamed round (one dispatch a size group) ----
+    def _size_group(self) -> int:
+        """Clients a size group of the streamed round, or 0 for the
+        whole-cohort round. Decided ONCE, from what the code can observe: a
+        host store whose clients differ in step bucket (equal clients gain
+        nothing from sorting), one device, the shared round builders with
+        the weighted mean and no per-round operands (what reads the whole
+        trained stack keeps the whole cohort, as under
+        ``cfg.client_group_size``), and a cohort that ``size_group`` can
+        cut. Not round by round: a round whose groups happen to share a
+        bucket must not call for a whole-cohort program nobody compiled."""
+        g = getattr(self, "_size_group_clients", None)
+        if g is None:
+            g = self._size_group_clients = self._resolve_size_group()
+        return g
+
+    def _cohort_slots(self) -> int:
+        """Slots of a sampled cohort (one device: nothing pads it)."""
+        return min(self.cfg.client_num_per_round,
+                   self.cfg.client_num_in_total)
+
+    def _resolve_size_group(self) -> int:
+        from fedml_tpu.data.store import bucket_steps_for_counts, size_group
+
+        rec = self.capability()
+        if (not self._streaming or self.mesh is not None
+                or self._client_group or self.window_protocol != "round"
+                or not rec.fused or rec.custom_round or rec.custom_builders
+                or rec.custom_step or rec.round_aux
+                or self.cfg.client_selection == "oort"
+                or any(user is not None for user in (
+                    self._round_aggregator(), self._client_transform(),
+                    self._corruptor()))):
+            return 0
+        store = self.train_fed
+        if len(np.unique(bucket_steps_for_counts(
+                store.counts, store.batch_size))) < 2:
+            return 0
+        return size_group(self._cohort_slots(), store.batch_size)
+
+    def _size_group_steps(self):
+        """The cached jitted ``(init, group_step, finish)`` of the
+        size-grouped round (``parallel.shard.make_size_group_round``).
+        Built, they are COMPILED, every one: ``group_step`` for each step
+        bucket a group of this federation can have, on a zero-weight group
+        of its smallest clients, and ``finish`` on a copy of the model
+        (both donate), so that no later round compiles whatever it draws —
+        the caller's warm-up sees only a few cohorts."""
+        fns = self._size_group_fns
+        if fns is None:
+            from fedml_tpu.data.store import bucket_steps_for_counts
+            from fedml_tpu.parallel.shard import make_size_group_round
+
+            init, step, finish = make_size_group_round(
+                self.local_train, self._nan_guard,
+                self._window_server_update())
+            fns = (jax.jit(init), jax.jit(step, donate_argnums=(1,)),
+                   jax.jit(finish, donate_argnums=(0, 1)))
+            init, step, finish = fns
+            store, group = self.train_fed, self._size_group()
+            smallest = np.argsort(store.counts, kind="stable")[:group]
+            buckets = bucket_steps_for_counts(store.counts, store.batch_size)
+            with planned_transfer():
+                no_weight = jnp.zeros((self._cohort_slots(),), jnp.float32)
+                slots = jnp.arange(group, dtype=jnp.int32)
+
+                def copy(tree):
+                    return jax.tree.map(
+                        lambda a: jnp.array(a, copy=True), tree)
+
+                carry = init(self.net)
+                # a group's bucket is its largest member's: none is under
+                # the bucket of the federation's group-th smallest client
+                for steps in np.unique(buckets[buckets
+                                               >= buckets[smallest].max()]):
+                    fed = store.gather_cohort(smallest, steps=int(steps))
+                    carry = step(self.net, carry, fed.x, fed.y, fed.mask,
+                                 fed.counts, slots, no_weight, self.rng)
+                jax.block_until_ready(finish(
+                    copy(self.net), copy(self._window_carry_init()), carry,
+                    self.rng))
+            self._size_group_fns = fns
+        return fns
+
+    def _train_round_size_grouped(self, round_idx: int, idx, wmask, key,
+                                  extra, group: int):
+        """The streamed round with the cohort cut into size groups: each
+        group trained at ITS step bucket and folded into one running sum
+        (one donated dispatch a group), then the mean and the server update
+        (one more). Same clients, samples, steps and rng streams as the
+        whole-cohort round; the padding is what is left out."""
+        init, group_step, finish = self._size_group_steps()
+        groups = self._stream_cohort(round_idx, idx, group)
+        with span("fed.round.dispatch", round=round_idx):
+            on_device = jnp.asarray(wmask, jnp.float32)
+            carry = init(self.net)
+        for j, (fed, slots, steps) in enumerate(groups):
+            with span("fed.round.dispatch", round=round_idx, group=j,
+                      steps=steps):
+                carry = group_step(self.net, carry, fed.x, fed.y, fed.mask,
+                                   fed.counts, slots, on_device, key)
+        with span("fed.round.dispatch", round=round_idx):
+            (self.net, extra), loss = finish(self.net, extra, carry, key)
+        self._window_carry_commit(extra)
+        self._emit_reduce_obs()
+        self._count_dispatch(
+            len(groups),
+            sum(group * g.steps for g in groups) * self.cfg.batch_size,
+            idx, wmask)
         return loss
 
     def train_one_round(self, round_idx: int) -> Dict[str, float]:

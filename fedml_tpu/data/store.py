@@ -17,6 +17,11 @@ sampled cohort per round:
   - the cohort is padded to ITS OWN max count (bucketed to a power of two
     so XLA sees a handful of shapes, not one per round), so power-law
     tails no longer tax every round;
+  - ``gather_groups`` goes one step further for the per-round host loop:
+    the cohort sorted by step need and cut into ``size_group(k, B)``
+    clients a group, each group padded to the bucket of ITS largest
+    member, so one giant client pads its own group and no other (the
+    size-grouped streamed round, ``FedAvgAPI._train_round_size_grouped``);
   - ``gather_cohort`` returns a regular ``FederatedArrays``, so the
     existing jitted rounds (vmap and shard_map) consume it unchanged;
   - ``CohortPrefetcher`` overlaps the next round's host gather + H2D
@@ -40,7 +45,7 @@ bit-equal — see docs/EXECUTION.md "Scale tiers").
 from __future__ import annotations
 
 import threading
-from typing import Dict, Optional
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -72,6 +77,46 @@ def bucket_steps_for_counts(counts, batch_size: int) -> np.ndarray:
     for shift in (1, 2, 4, 8, 16, 32):
         v |= v >> np.uint64(shift)
     return (v + 1).astype(np.int64)
+
+
+#: The size-grouped streamed round (``size_group``): a group's step holds
+#: at least this many samples (clients x batch), and a round has between
+#: ``MIN_GROUPS`` and ``MAX_GROUPS`` groups. Settled on the chip (PERF.md
+#: §6, PR 29; FEMNIST CNN, 200 lognormal clients a round, batch 20, groups
+#: of 2 to 50): a sample slot costs less, not more, at 100 samples a step
+#: than at 4,000, so narrower groups only gain, until the worker that
+#: prepares them (4.5 ms a group: a gather, five puts) falls behind the
+#: device. At 20 groups a round it keeps a third of the round in hand; at
+#: 40 the round is faster still but waits for the host and spreads 4 % from
+#: run to run: raise ``MAX_GROUPS`` when the store's host path is cheaper
+#: (ROADMAP S4). Under 8 groups nothing was measured: the whole cohort.
+MIN_STEP_SAMPLES = 100
+MIN_GROUPS, MAX_GROUPS = 8, 20
+
+
+def size_group(cohort: int, batch_size: int) -> int:
+    """Clients a size group for a sampled cohort of ``cohort`` slots at
+    ``batch_size`` samples a step: the smallest divisor of the cohort that
+    keeps ``MIN_STEP_SAMPLES`` in a step and the round within ``MAX_GROUPS``
+    groups (200 at batch 20 -> 10, 64 -> 8, 1,000 -> 50), so every group has
+    the same client count and a round's programs differ in the step bucket
+    alone. 0 — the whole cohort as one group, the flat ``gather_cohort``
+    round — where no such divisor leaves ``MIN_GROUPS`` groups (a small
+    cohort, a small batch, a prime). A rule of the code, not a setting."""
+    least = max(-(-MIN_STEP_SAMPLES // int(batch_size)),
+                -(-int(cohort) // MAX_GROUPS))
+    for g in range(least, int(cohort) // MIN_GROUPS + 1):
+        if cohort % g == 0:
+            return g
+    return 0
+
+
+class CohortGroup(NamedTuple):
+    """One size group of a streamed cohort (``gather_groups``)."""
+
+    fed: FederatedArrays    # the members, [g, steps, B, ...] on the device
+    slots: jax.Array        # [g] int32: each member's slot in the cohort
+    steps: int              # the group's own power-of-two step bucket
 
 
 class FederatedStore:
@@ -140,10 +185,12 @@ class FederatedStore:
         return self._x.nbytes + self._y.nbytes
 
     def cohort_steps(self, indices) -> int:
-        """The power-of-two step bucket a cohort needs — the same number
-        ``gather_cohort`` computes internally, exposed so window planning
+        """The power-of-two step bucket of a cohort gathered WHOLE — the
+        bucket of its largest client, the number ``gather_cohort`` computes
+        internally — exposed so window planning
         (``FedAvgAPI.train_rounds_windowed``) can group upcoming rounds by
-        bucket WITHOUT gathering them."""
+        bucket WITHOUT gathering them. Not what the size-grouped round
+        dispatches: there each group has its own (``plan_groups``)."""
         ccounts = self.counts[np.asarray(indices)]
         return _bucket_steps(
             int(np.ceil(max(int(ccounts.max()), 1) / self.batch_size)))
@@ -197,9 +244,12 @@ class FederatedStore:
 
     def gather_cohort(self, indices,
                       steps: Optional[int] = None) -> FederatedArrays:
-        """Materialize the sampled clients as a device-resident
-        ``FederatedArrays`` padded to the COHORT max count (power-of-two
-        step bucket). Duplicate indices are fine (pad_to_multiple repeats
+        """Materialize the given clients as ONE device-resident
+        ``FederatedArrays``, every client padded to the largest of THESE
+        clients (power-of-two step bucket): the whole sampled cohort for
+        the whole-cohort round, ``pow_d``'s candidate pass and the
+        evaluation chunks; one size group at a time for ``gather_groups``.
+        Duplicate indices are fine (pad_to_multiple repeats
         index 0 with weight 0). One vectorized fancy-index gather per
         field (byte-identical to :meth:`_gather_cohort_loop`, the scalar
         reference the tests pin it against — the per-client Python copy
@@ -241,6 +291,40 @@ class FederatedStore:
                 mask=jnp.asarray(split(mask)),
                 counts=jnp.asarray(counts),
             )
+
+    def plan_groups(self, indices,
+                    group: int) -> List[Tuple[np.ndarray, int]]:
+        """The size groups of a sampled cohort, from the counts alone:
+        slots ordered by step need (``ceil(count / batch)``; stable, so
+        ties keep cohort order) and cut into ``group`` clients a group,
+        each with the power-of-two bucket of ITS largest member. Returns
+        ``[(slots [group] int32, steps)]``, smallest needs first; the
+        buckets are among ``bucket_steps_for_counts(self.counts)``
+        whatever the round draws."""
+        idx = np.asarray(indices)
+        if group < 1 or len(idx) % group:
+            raise ValueError(
+                f"groups of {group} do not divide a cohort of {len(idx)}")
+        need = np.maximum(
+            -(-self.counts[idx].astype(np.int64) // self.batch_size), 1)
+        order = np.argsort(need, kind="stable").astype(np.int32)
+        # ascending: a group's largest member is its last
+        return [(order[lo:lo + group],
+                 _bucket_steps(int(need[order[lo + group - 1]])))
+                for lo in range(0, len(idx), group)]
+
+    def gather_groups(self, indices, group: int) -> List[CohortGroup]:
+        """The sampled cohort as its size groups (``plan_groups``), each
+        ``gather_cohort(indices[slots], steps)`` — the same fill, mask, put
+        and spans at the group's ``[group, steps, B, ...]`` shape — so the
+        round trains no client at a larger bucket than its group's."""
+        idx = np.asarray(indices)
+        out = []
+        for slots, steps in self.plan_groups(idx, group):
+            fed = self.gather_cohort(idx[slots], steps=steps)
+            with planned_transfer():
+                out.append(CohortGroup(fed, jnp.asarray(slots), steps))
+        return out
 
     def _gather_cohort_loop(self, indices,
                             steps: Optional[int] = None) -> FederatedArrays:
@@ -400,23 +484,31 @@ class FederatedStore:
 class CohortPrefetcher:
     """Double buffer: prepare round r+1's cohort (host gather + async H2D)
     on a worker thread while round r computes. ``get`` blocks on the
-    in-flight preparation only if it has not finished yet."""
+    in-flight preparation only if it has not finished yet. ``group`` > 0
+    prepares and hands over the cohort as its size groups
+    (``gather_groups``) instead of one ``FederatedArrays``."""
 
     def __init__(self, store: FederatedStore):
         self.store = store
         self._pending: Dict[int, threading.Thread] = {}
-        self._ready: Dict[int, tuple] = {}  # round -> (indices, cohort)
+        # round -> (indices, group, cohort)
+        self._ready: Dict[int, tuple] = {}
         self._lock = threading.Lock()
 
-    def prefetch(self, round_idx: int, indices) -> None:
+    def _gather(self, indices, group: int):
+        if group:
+            return self.store.gather_groups(indices, group)
+        return self.store.gather_cohort(indices)
+
+    def prefetch(self, round_idx: int, indices, group: int = 0) -> None:
         indices = np.asarray(indices)
 
         def work():
             try:
                 with span("fed.cohort.prefetch", round=round_idx):
-                    cohort = self.store.gather_cohort(indices)
+                    cohort = self._gather(indices, group)
                 with self._lock:
-                    self._ready[round_idx] = (indices, cohort)
+                    self._ready[round_idx] = (indices, group, cohort)
             finally:
                 # Always clear pending — a worker failure (host OOM, bad
                 # index) must not permanently block future prefetches for
@@ -436,7 +528,7 @@ class CohortPrefetcher:
             self._pending[round_idx] = t
         t.start()
 
-    def get(self, round_idx: int, indices) -> FederatedArrays:
+    def get(self, round_idx: int, indices, group: int = 0):
         # On a miss the synchronous gather's fed.store.* spans lie inside
         # this one, on the caller's thread: that nesting is the miss signal.
         with span("fed.cohort.wait", round=round_idx):
@@ -450,12 +542,13 @@ class CohortPrefetcher:
                 for r in [r for r in self._ready if r < round_idx]:
                     self._ready.pop(r)
             # The prefetched cohort is only valid for the EXACT index list
-            # the caller now wants — sampling inputs may have changed between
-            # the prefetch and the round (cfg mutation, subclass overrides).
-            if hit is not None and np.array_equal(hit[0],
-                                                  np.asarray(indices)):
-                return hit[1]
-            return self.store.gather_cohort(indices)
+            # and form the caller now wants — sampling inputs may have
+            # changed between the prefetch and the round (cfg mutation,
+            # subclass overrides).
+            if hit is not None and hit[1] == group and np.array_equal(
+                    hit[0], np.asarray(indices)):
+                return hit[2]
+            return self._gather(indices, group)
 
 
 class WindowPrefetcher:

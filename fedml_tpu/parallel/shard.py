@@ -195,6 +195,39 @@ def whole_stack_needed(group: int, **users) -> None:
             "once — use client_group_size=0 (the whole cohort)")
 
 
+def fold_init(params):
+    """The empty running sums of :func:`fold_group`: ``(sum w_i theta_i
+    (float32, shaped like ``params``), sum w_i, sum lw_i loss_i, sum
+    lw_i)``."""
+    zero = jnp.zeros((), jnp.float32)
+    return (jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params),
+            zero, zero, zero)
+
+
+def fold_group(local_train, nan_guard, params, carry, xg, yg, mg, wg, lwg,
+               rg, unbatched: bool = False):
+    """One group of clients ``[g, ...]`` trained from ``params`` under
+    :func:`run_clients_guarded` (``nan_guard`` a group at a time) and folded
+    into the running sums ``carry`` (:func:`fold_init`). The ONE fold: the
+    scan body of :func:`fold_client_groups` (groups inside one program) and
+    the step of :func:`make_size_group_round` (one dispatch a group).
+    Returns ``(carry, losses [g])``."""
+    acc, sum_w, sum_loss, sum_lw = carry
+    nets, losses, finite = run_clients_guarded(
+        local_train, None, nan_guard, params, xg, yg, mg, rg,
+        unbatched=unbatched)
+    with jax.named_scope("fed.aggregate"), \
+            jax.named_scope("fed.client_fold"):
+        wg = wg.astype(jnp.float32) * finite
+        lwg = lwg.astype(jnp.float32) * finite
+        acc = jax.tree.map(
+            lambda a, p: a + jnp.einsum(
+                "c,c...->...", wg, p.astype(jnp.float32)), acc, nets)
+        carry = (acc, sum_w + jnp.sum(wg),
+                 sum_loss + jnp.sum(losses * lwg), sum_lw + jnp.sum(lwg))
+    return carry, losses
+
+
 def fold_client_groups(local_train, nan_guard, group, params, x, y, mask,
                        weights, loss_weights, rngs):
     """The cohort ``[C, ...]`` trained ``group`` clients at a time: a
@@ -216,27 +249,11 @@ def fold_client_groups(local_train, nan_guard, group, params, x, y, mask,
         return a.reshape((n // group, group) + a.shape[1:])
 
     def body(carry, operands):
-        acc, sum_w, sum_loss, sum_lw = carry
-        xg, yg, mg, wg, lwg, rg = operands
-        nets, losses, finite = run_clients_guarded(
-            local_train, None, nan_guard, params, xg, yg, mg, rg,
-            unbatched=group == 1)
-        with jax.named_scope("fed.aggregate"), \
-                jax.named_scope("fed.client_fold"):
-            wg = wg.astype(jnp.float32) * finite
-            lwg = lwg.astype(jnp.float32) * finite
-            acc = jax.tree.map(
-                lambda a, p: a + jnp.einsum(
-                    "c,c...->...", wg, p.astype(jnp.float32)), acc, nets)
-            carry = (acc, sum_w + jnp.sum(wg),
-                     sum_loss + jnp.sum(losses * lwg), sum_lw + jnp.sum(lwg))
-        return carry, losses
+        return fold_group(local_train, nan_guard, params, carry, *operands,
+                          unbatched=group == 1)
 
-    zero = jnp.zeros((), jnp.float32)
-    init = (jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params),
-            zero, zero, zero)
     (acc, sum_w, sum_loss, sum_lw), losses = jax.lax.scan(
-        body, init, tuple(grouped(a) for a in (
+        body, fold_init(params), tuple(grouped(a) for a in (
             x, y, mask, weights, loss_weights, rngs)))
     return acc, sum_w, sum_loss, sum_lw, losses.reshape(n)
 
@@ -575,6 +592,52 @@ def make_fused_round_step(round_fn, server_update=None):
         return (new_net, new_extra), loss
 
     return step_fn
+
+
+def make_size_group_round(local_train, nan_guard: bool = False,
+                          server_update=None):
+    """The streamed round, one dispatch a SIZE GROUP: the host cuts the
+    sampled cohort into groups of clients with a like step need
+    (``FederatedStore.plan_groups``), each padded to its own step bucket
+    instead of the cohort's largest, and the round is
+
+    ``carry = init(net)``; for each group ``carry = group_step(net, carry,
+    x, y, mask, counts, slots, wmask, key)``; ``((net', extra'), loss) =
+    finish(net, extra, carry, key)``.
+
+    ``group_step`` is :func:`fold_group` (the grouped round's own fold) on
+    ``[g, S_g, B, ...]`` operands: one program a step bucket, whatever the
+    round draws. ``slots [g]`` are the members' slots in the sampled
+    cohort: each client's rng stream is ``fold_in(key, slot)``, what
+    :func:`client_rngs` gives it in the whole-cohort round, and the
+    trainer's streams are prefix-stable in the step count, so a client
+    trained at its group's bucket ends where it ends at the cohort's; only
+    the association of the float32 sums differs. ``wmask [k]`` is the
+    cohort's pad mask, ``counts [g]`` the members' sample counts: weight
+    ``counts * wmask[slots]`` for the model and the loss alike (the
+    streaming host loop's convention). ``finish`` is :func:`_grouped_mean`
+    + the algorithm's pure ``server_update``, as
+    :func:`make_fused_round_step` ends. Callers jit ``group_step`` donating
+    ``carry`` and ``finish`` donating ``(net, extra)``. Mean aggregation
+    without a client transform or corruptor only
+    (:func:`whole_stack_needed`); one device."""
+
+    def group_step(net, carry, x, y, mask, counts, slots, wmask, key):
+        w = counts.astype(jnp.float32) * wmask[slots]
+        rngs = jax.vmap(lambda i: jax.random.fold_in(key, i))(slots)
+        carry, _ = fold_group(local_train, nan_guard, net, carry, x, y,
+                              mask, w, w, rngs)
+        return carry
+
+    def finish(net, extra, carry, key):
+        with jax.named_scope("fed.aggregate"):
+            avg, loss = _grouped_mean(net, *carry)
+            if server_update is None:
+                return (avg, extra), loss
+            new_net, new_extra = server_update(net, avg, extra, key)
+        return (new_net, new_extra), loss
+
+    return fold_init, group_step, finish
 
 
 def make_fused_stateful_round_step(round_fn):

@@ -142,6 +142,22 @@ def test_refusals_of_the_drill_a_custom_round_and_a_k_that_does_not_divide():
 
 # --- k = C is the parent's round, byte for byte ---------------------------
 
+def _resident_steps(mix: dict, config: dict, batch: int) -> int:
+    """Steps a client of a resident mix: every client is padded to the
+    largest, which the mix's own law and seed give."""
+    if "lda" not in mix:
+        return -(-int(mix["counts"]["per_client"]) // batch)
+    from fedml_tpu.data.partition import partition_dirichlet
+
+    lda = mix["lda"]
+    y = np.random.RandomState(int(lda["seed"])).permutation(np.repeat(
+        np.arange(int(config["classes"]), dtype=np.int32),
+        int(lda["samples_per_class"])))
+    parts = partition_dirichlet(y, int(mix["clients"]), float(lda["alpha"]),
+                                seed=int(lda["seed"]))
+    return -(-max(len(p) for p in parts.values()) // batch)
+
+
 def _cell_step_text(cell: str) -> str:
     """The lowered text of the round step ``train_one_round`` dispatches in
     an accepted cell of the benchmark: its configuration's model and
@@ -165,7 +181,7 @@ def _cell_step_text(cell: str) -> str:
     shape = tuple(config["input_shape"])
     mesh = client_mesh(4) if entry["chips"] == 4 else None
     resident = mix["placement"] == "resident"
-    steps = -(-int(mix["counts"]["per_client"]) // batch) if resident else 5
+    steps = _resident_steps(mix, config, batch) if resident else 5
     # the API is built on a federation of one step; the step is lowered on
     # the shapes of the cell's own
     small = build_federated_arrays(
@@ -201,14 +217,53 @@ def _cell_step_text(cell: str) -> str:
     return lowered.as_text()
 
 
+def _grouped_toy_step_text() -> str:
+    """The grouped round (``client_group_size`` 1) that ``qwen3next_c4_s4k``
+    dispatches, with the cell's ``fed_config`` and cohort (4 silos a round,
+    2 sequences each, batch 1) at the widths of ``tests/test_qwen3_next.py``:
+    the published ones are 424 M parameters."""
+    from functools import partial
+
+    from test_qwen3_next import SMALL, T
+
+    from fedml_tpu.models import create_model
+    from fedml_tpu.trainer.local import seq_softmax_ce
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "qwen3_next_80b_a3b.json")) as f:
+        fed_config = json.load(f)["fed_config"]
+    assert fed_config["client_group_size"] == 1
+    clients, cohort, per_client = 8, 4, 2
+    ids = np.ones((clients * per_client, T), np.int32)
+    small = build_federated_arrays(
+        ids, ids, {c: np.arange(c * per_client, (c + 1) * per_client)
+                   for c in range(clients)}, 1)
+    cfg = FedConfig(client_num_in_total=clients, client_num_per_round=cohort,
+                    comm_round=10, epochs=1, batch_size=1, lr=0.2, seed=0,
+                    **fed_config)
+    api = FedAvgAPI(create_model("qwen3_next", **SMALL), small, None, cfg,
+                    loss_fn=partial(seq_softmax_ce, pad_id=0))
+    _, gather = api._fused_round_step()
+    spec = jax.ShapeDtypeStruct
+    return gather.lower(
+        api.net, api._window_carry_init(), small,
+        spec((cohort,), jnp.int32), spec((cohort,), jnp.float32),
+        jax.random.PRNGKey(0)).as_text()
+
+
 @pytest.mark.parametrize("cell", ["resnet56_c16", "femnist_cnn_3400",
-                                  "resnet56_c64_4chip"])
+                                  "resnet56_c64_4chip", "resnet56_lda_c10",
+                                  "grouped_round_toy"])
 def test_the_accepted_cells_round_step_is_the_parents_text(cell):
     """``tests/fixtures/round_step_text.json`` holds the SHA-256 of this
-    text as the parent of the PR that brought the grouped round lowered it
-    (commit ecc2d4e, the same function run in a clone of it)."""
+    text as the parent of the PR that pinned it lowered it (the same
+    function run in a clone of that commit): ecc2d4e for the first three,
+    c7fc5db for ``resnet56_lda_c10`` (27 steps) and for the grouped round
+    at toy widths, which the size-grouped store round shares its fold with.
+    ``femnist_cnn_3400`` is the whole-cohort step, the fall-back there."""
     with open(os.path.join(ROOT, "tests", "fixtures",
                            "round_step_text.json")) as f:
         want = json.load(f)["sha256"][cell]
-    text = _cell_step_text(cell)
+    text = (_grouped_toy_step_text() if cell == "grouped_round_toy"
+            else _cell_step_text(cell))
     assert hashlib.sha256(text.encode()).hexdigest() == want

@@ -162,8 +162,9 @@ def test_a_missed_prefetch_gathers_on_the_main_thread(tmp_path):
         ``get``: what was prepared is for another cohort."""
         if r == 2:
             _join_prefetch(api)
-            idx, cohort = api._cohort_prefetcher._ready[2]
-            api._cohort_prefetcher._ready[2] = (np.roll(idx, 1), cohort)
+            idx, group, cohort = api._cohort_prefetcher._ready[2]
+            api._cohort_prefetcher._ready[2] = (np.roll(idx, 1), group,
+                                                cohort)
 
     events = _profiled(api, (1, 2), tmp_path, before=spoil)
     main = next(e[0] for e in events if e[1] == "fed.round")
