@@ -215,7 +215,7 @@ def rel_err(got, ref) -> float:
 
 def token_fed(n_clients, per_client, batch, t, vocab, seed=0):
     """Synthetic next-token federation from a seed: tokens in [1, vocab) so
-    ``pad_id=0`` never collides (bench.py's ``_token_fed`` law)."""
+    ``pad_id=0`` never collides."""
     import numpy as np
 
     from fedml_tpu.data.batching import build_federated_arrays

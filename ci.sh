@@ -253,7 +253,7 @@ for a, b in zip(jax.tree.leaves((host.net.params, host.server_h,
                                  win.client_grads))):
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 rec = win.capability()
-assert rec.fused and rec.windowed and rec.pipelined
+assert rec.fused and rec.windowed
 print("zoo carry-record smoke OK: FedDyn windowed == host "
       f"(5 rounds, W=2, losses[-1]={lb[-1]:.4f})")
 PYEOF

@@ -13,10 +13,10 @@ argument with three different guards before it ran fast.
 This module derives ONE record per algorithm class from its declarations
 (:func:`record_for`) and makes everything downstream consume it:
 
-- the tier entry points (``train_rounds_windowed`` / ``_pipelined`` /
-  ``_on_device`` and the fused round step) key their guards on the
-  record and refuse with :func:`refusal` — a message derived from the
-  record, naming the reason the class declared;
+- the tier entry points (``train_rounds_windowed`` / ``_on_device``
+  and the fused round step) key their guards on the record and refuse
+  with :func:`refusal` — a message derived from the record, naming the
+  reason the class declared;
 - the EXECUTION.md algorithm × tier support matrix is GENERATED from the
   records (:func:`render_matrix`, ``scripts/gen_support_matrix.py``) and
   drift-tested, so the docs cannot silently diverge from the guards;
@@ -83,7 +83,7 @@ ZOO = (
 class CarryCapability:
     """One algorithm's declared + structurally derived capability record.
 
-    ``fused``/``pipelined``/``windowed``/``on_device`` are the STATIC
+    ``fused``/``windowed``/``on_device`` are the STATIC
     tier eligibilities (what the class can ever do); runtime conditions
     — a resident layout where windowed needs a store, oort selection,
     a subsampled mesh for the on-device scan — still gate per call."""
@@ -99,7 +99,6 @@ class CarryCapability:
     round_aux: bool               # per-round host-computed aux operands
     streaming: bool               # supports FederatedStore cohorts
     fused: bool
-    pipelined: bool
     windowed: bool
     on_device: bool
 
@@ -123,13 +122,9 @@ def _fedavg_family_record(cls, name, carry, excluded) -> CarryCapability:
     aux = (cls._round_aux is not FederatedLoop._round_aux
            or cls._window_scan_extras is not FedAvgAPI._window_scan_extras)
     streaming = bool(cls.supports_streaming)
-    fused = pipelined = windowed = on_device = False
+    fused = windowed = on_device = False
     if proto == "round":
         fused = not custom_round and pure
-        # The pipelined loop applies _server_update host-side, so even
-        # an impure/stateful override rides it — only a custom round
-        # refuses (its per-round procedure would be silently dropped).
-        pipelined = not custom_round
         windowed = fused and streaming
         # The on-device scan threads the same pure carry between rounds
         # but samples (or keeps full participation) INSIDE the jit — a
@@ -139,14 +134,13 @@ def _fedavg_family_record(cls, name, carry, excluded) -> CarryCapability:
         has_scan = (custom_step or cls._build_window_scan
                     is not FedAvgAPI._build_window_scan)
         fused = custom_step
-        pipelined = custom_step   # the fused step pipelines like a round
         windowed = has_scan and streaming
     return CarryCapability(
         algorithm=name, protocol=proto, carry=carry, excluded=excluded,
         custom_round=custom_round, custom_builders=custom_builders,
         custom_step=custom_step, pure_server_update=pure, round_aux=aux,
-        streaming=streaming, fused=fused, pipelined=pipelined,
-        windowed=windowed, on_device=on_device)
+        streaming=streaming, fused=fused, windowed=windowed,
+        on_device=on_device)
 
 
 @lru_cache(maxsize=None)
@@ -176,7 +170,6 @@ def record_for(cls) -> CarryCapability:
         pure_server_update=False, round_aux=False,
         streaming=bool(getattr(cls, "supports_streaming", False)),
         fused=bool(tiers.get("fused", False)),
-        pipelined=bool(tiers.get("pipelined", False)),
         windowed=bool(tiers.get("windowed", False)),
         on_device=bool(tiers.get("on_device", False)))
 
@@ -248,8 +241,8 @@ class ExcludedScanTiers:
     algorithm that doesn't override them fails with its declared reason)
     and the standalone training loops outside it (FedGKT's alternating
     distillation, SplitNN's relay ring, vertical FL), instead of an
-    AttributeError that says nothing. FedAvgAPI overrides all three with
-    the real tiers."""
+    AttributeError that says nothing. FedAvgAPI overrides both with the
+    real tiers."""
 
     #: Carry capability declarations (see module docstring): subclasses
     #: publish explicit tiers (``capability_tiers``) or declare WHY they
@@ -260,10 +253,6 @@ class ExcludedScanTiers:
     def train_rounds_windowed(self, *a, **k):
         raise NotImplementedError(refusal(type(self),
                                           "train_rounds_windowed"))
-
-    def train_rounds_pipelined(self, *a, **k):
-        raise NotImplementedError(refusal(type(self),
-                                          "train_rounds_pipelined"))
 
     def train_rounds_on_device(self, *a, **k):
         raise NotImplementedError(refusal(type(self),
@@ -294,17 +283,16 @@ def render_matrix() -> str:
     Every ✓ is backed by the record the tier guards consume — the table
     CANNOT say yes where the guard says no."""
     lines = [
-        "| algorithm | protocol | carry | pipelined | fused round | "
+        "| algorithm | protocol | carry | fused round | "
         "windowed scan | on-device scan |",
-        "|---|---|---|---|---|---|---|",
+        "|---|---|---|---|---|---|",
     ]
     excluded = []
     for name, cls, rec in zoo_records():
         proto = rec.protocol if rec.protocol else "—"
         lines.append(
-            f"| {name} | {proto} | {rec.carry} | {_cell(rec.pipelined)} | "
-            f"{_cell(rec.fused)} | {_cell(rec.windowed)} | "
-            f"{_cell(rec.on_device)} |")
+            f"| {name} | {proto} | {rec.carry} | {_cell(rec.fused)} | "
+            f"{_cell(rec.windowed)} | {_cell(rec.on_device)} |")
         if rec.excluded:
             excluded.append(f"- **{name}** — {rec.excluded}")
     out = "\n".join(lines)

@@ -46,7 +46,7 @@ class FedConfig:
     # "mean" (the bit-equal fast path), "coord_median",
     # "trimmed_mean<beta>", "krum<f>", "multi_krum<f>-<m>",
     # "geometric_median<iters>". Rides every execution tier (host loop,
-    # pipelined, windowed, on-device scan); on a client mesh non-mean
+    # windowed, on-device scan); on a client mesh non-mean
     # aggregators all_gather the cohort. docs/ROBUSTNESS.md.
     aggregator: str = "mean"
     # Hierarchical sparse reduction on a client mesh (parallel/shard.py):
@@ -139,7 +139,7 @@ class FedConfig:
     # from Cin to 25·Cin (CNNOriginalFedAvg only; ~1-ulp tolerance, the
     # CNN family's documented class).
     compute_layout: str = "none"
-    # bf16 client-step compute (docs/EXECUTION.md "MFU playbook"):
+    # bf16 client-step compute (docs/EXECUTION.md "Client-step levers"):
     # "fp32" (default), or "bf16" — the jitted client step's layer
     # compute runs in bfloat16 (flax compute-dtype twin,
     # parallel/layout.step_dtype_model) while the PARAM TREE, gradients,

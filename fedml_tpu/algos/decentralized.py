@@ -56,14 +56,12 @@ class DecentralizedAPI(FederatedLoop):
 
     Carry capability record: the gossip state ``(nets, push_weights)``
     is a pure carry and the round is already ONE dispatch, so the scan
-    tiers that apply to a full-participation resident federation ride:
-    :meth:`train_rounds_on_device` scans n rounds in one donated
+    tier that applies to a full-participation resident federation
+    rides: :meth:`train_rounds_on_device` scans n rounds in one donated
     dispatch (zero host round-trips between gossip exchanges — the
-    mixing einsum chains on device), and :meth:`train_rounds_pipelined`
-    enqueues per-round dispatches without the per-round loss sync. The
-    windowed STORE tier does not apply — nothing streams (every client
-    trains on its resident shard every round), which the record-derived
-    refusal explains."""
+    mixing einsum chains on device). The windowed STORE tier does not
+    apply — nothing streams (every client trains on its resident shard
+    every round), which the record-derived refusal explains."""
 
     window_protocol = "custom"
     window_carry = "client-stacked models + push weights"
@@ -72,8 +70,8 @@ class DecentralizedAPI(FederatedLoop):
         "no cohort ever streams from a store, so the windowed store tier "
         "does not apply; train_rounds_on_device IS the multi-round scan "
         "fast path here")
-    capability_tiers = {"fused": True, "pipelined": True,
-                        "windowed": False, "on_device": True}
+    capability_tiers = {"fused": True, "windowed": False,
+                        "on_device": True}
 
     def __init__(
         self,
@@ -158,21 +156,6 @@ class DecentralizedAPI(FederatedLoop):
             self.nets, self.push_weights, f.x, f.y, f.mask, rnd_rng
         )
         return {"round": round_idx, "train_loss": float(loss)}
-
-    def train_rounds_pipelined(self, n_rounds: int, start_round: int = 0):
-        """``n_rounds`` gossip rounds with the per-round ``float(loss)``
-        sync deferred to the end — per-round semantics identical to
-        :meth:`train_one_round` in a loop (the rng chain and round math
-        are the same; only the host sync moves)."""
-        f = self.train_fed
-        losses = []
-        for _ in range(n_rounds):
-            # fedlint: disable=R1(deliberate round-order chain: identical to train_one_round's per-round split so the pipelined loop is bit-equal to the host loop)
-            self.rng, rnd_rng = jax.random.split(self.rng)
-            self.nets, self.push_weights, loss = self.round_fn(
-                self.nets, self.push_weights, f.x, f.y, f.mask, rnd_rng)
-            losses.append(loss)
-        return [float(l) for l in losses]
 
     def train_rounds_on_device(self, n_rounds: int):
         """``n_rounds`` WHOLE gossip rounds in one jitted ``lax.scan``

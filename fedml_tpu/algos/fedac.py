@@ -2,8 +2,8 @@
 FedAc and server averaging.
 
 Both are PURE server-state updates — exactly the shape the windowed
-carry protocol scans — so they run fused + windowed + pipelined +
-on-device from day one, with their sequences living on device between
+carry protocol scans — so they run fused + windowed + on-device from
+day one, with their sequences living on device between
 rounds. They are the "accuracy-per-round for free" counterpart to the
 throughput story: same client compute, better round-for-round progress.
 

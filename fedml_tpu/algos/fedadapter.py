@@ -54,7 +54,7 @@ class FedAdapterAPI(FedAvgAPI):
     frozen base params (never trained, never uploaded, never donated —
     jit captures it once as device constants).
 
-    Rides fused / pipelined / windowed / on-device execution day one via
+    Rides fused / windowed / on-device execution day one via
     the derived carry capability record ("round" protocol, no carry).
     Personalization: :meth:`personalize_cohort` runs the ditto-style
     interpolated local finetune for a cohort and persists the result in
@@ -197,8 +197,8 @@ class FedAdapterAPI(FedAvgAPI):
         """Sample-weighted per-client quality of the PERSONALIZED
         adapters vs the global adapters on each client's shard.
         ``arrays`` defaults to the training shards; pass per-client
-        HELD-OUT arrays for the honest personalization delta (the bench
-        does). Clients never personalized evaluate at the global (their
+        HELD-OUT arrays for the honest personalization delta (REPRO.md's
+        pin does). Clients never personalized evaluate at the global (their
         stored state IS the global default)."""
         f = arrays if arrays is not None else self.train_fed
         store = self.personal_store()

@@ -1237,63 +1237,9 @@ class FedAvgAPI(FederatedLoop):
             self._update_oort_state(round_idx, idx, wmask)
         return loss
 
-    def train_rounds_pipelined(self, n_rounds: int, start_round: int = 0):
-        """Run ``n_rounds`` host-loop rounds back-to-back WITHOUT the
-        per-round host sync: ``train_one_round``'s ``float(loss)`` blocks
-        until the round finishes, serializing device compute against the
-        next round's host work. Here every round's jitted dispatch is
-        enqueued as soon as its cohort is ready — async dispatch chains
-        the net dependency, so the device trains round r while the host
-        samples/gathers round r+1 (with the streaming store's prefetcher
-        this pipelines host gather + H2D + compute three-deep). Losses
-        are fetched once at the end. Per-round semantics are identical to
-        calling ``train_one_round`` in a loop (tested bit-equal) — use
-        this between eval points; it skips the eval-cadence bookkeeping.
-        Works for every subclass whose round rides ``run_round``
-        (server updates are device math, so they pipeline too).
-
-        Measured caveat: where every dispatch carries a high fixed cost
-        (a remotely attached device) the synced per-round loop can be
-        faster — the streaming prefetcher already overlaps the next
-        gather with the loss wait, and a flood of unsynced dispatches
-        cost more there than the syncs saved (A/B on the 3400-client
-        FEMNIST bench config, 2026-07-30: ~8.8 vs ~5.5 rounds/sec; not
-        re-measured on a directly-attached chip)."""
-        # Capability-record guard: "round"-protocol algorithms pipeline
-        # whenever their per-round procedure is run_round +
-        # _server_update (stateful host-side _server_update overrides
-        # like FedOpt's included — purity only matters inside the
-        # windowed scan); "custom"-protocol algorithms pipeline through
-        # their fused one-dispatch step. Everything else refuses with
-        # the record-derived reason.
-        if not self.capability().pipelined:
-            from fedml_tpu.algos.capability import refusal
-
-            raise NotImplementedError(
-                refusal(type(self), "train_rounds_pipelined"))
-        if self.cfg.client_selection == "oort":
-            raise NotImplementedError(
-                "oort updates per-client utilities after every round "
-                "(train_one_round); the pipelined loop skips that hook — "
-                "use the per-round loop")
-        losses = []
-        fused = self._fused_round_step()
-        for r in range(start_round, start_round + n_rounds):
-            if fused is not None:
-                # One donated dispatch per round (train + aggregate +
-                # server update) — same async-dispatch pipelining, one
-                # fewer dispatch and no undonated intermediates.
-                losses.append(self._train_round_fused(r))
-            else:
-                avg, loss = self.run_round(r)
-                self.net = self._server_update(self.net, avg)
-                self._emit_reduce_obs()
-                losses.append(loss)
-        return [float(l) for l in losses]
-
     # --- windowed carry protocol ------------------------------------------
     #: How (whether) this algorithm rides the multi-round scan tiers
-    #: (``train_rounds_windowed`` / ``train_rounds_pipelined``):
+    #: (``train_rounds_windowed`` / ``train_rounds_on_device``):
     #:
     #: - ``"round"`` — the per-round procedure is exactly ``run_round``
     #:   + ``_server_update``. The windowed scan replays ``round_fn``
@@ -1303,8 +1249,7 @@ class FedAvgAPI(FederatedLoop):
     #: - ``"custom"`` — the subclass builds its own scan body
     #:   (:meth:`_build_window_scan`) and threads its own carry
     #:   (SCAFFOLD: server control + the full client-control stack,
-    #:   gathered/scattered per scanned round). Custom rounds do not
-    #:   pipeline — their per-round host procedure IS the round.
+    #:   gathered/scattered per scanned round).
     #: - ``None`` — host loop only.
     #:
     #: The guards key on THIS declaration (plus a consistency check that
@@ -1498,8 +1443,8 @@ class FedAvgAPI(FederatedLoop):
         ``self._window_stats`` records the split for introspection.
 
         Returns the per-round losses as floats — ONE host sync at the
-        end, like :meth:`train_rounds_pipelined`. Eval-cadence-aware
-        splitting lives in :meth:`train_windowed`."""
+        end. Eval-cadence-aware splitting lives in
+        :meth:`train_windowed`."""
         from fedml_tpu.data.store import WindowPrefetcher
 
         self._check_windowed_supported()
@@ -1601,10 +1546,10 @@ class FedAvgAPI(FederatedLoop):
             self._window_carry_commit(extra)
             self._emit_reduce_obs(n_rounds=length)
             losses.extend(list(span_losses))
-        # ONE end-of-loop host sync for the losses — planned by design
-        # (train_rounds_pipelined contract), so mark it for sanitized()
-        # regions (the D2H fetch is implicit and would otherwise trip
-        # the transfer guard on backends that guard D2H).
+        # ONE end-of-loop host sync for the losses — planned by design,
+        # so mark it for sanitized() regions (the D2H fetch is implicit
+        # and would otherwise trip the transfer guard on backends that
+        # guard D2H).
         with planned_transfer():
             return [float(l) for l in losses]
 

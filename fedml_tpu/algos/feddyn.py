@@ -65,8 +65,8 @@ class FedDynAPI(FedAvgAPI):
     per-client state, not data — while the round's cohort arrives
     through the shared :meth:`FedAvgAPI._cohort` path. The carry
     capability record below is the whole fast-path story: the fused
-    one-dispatch round, the pipelined loop, and the W-rounds-per-
-    dispatch windowed scan all derive from ONE ``_build_fused_step``,
+    one-dispatch round and the W-rounds-per-dispatch windowed scan
+    both derive from ONE ``_build_fused_step``,
     with carry ``(net, (server_h, client_grads))``."""
 
     supports_streaming = True  # corrections device-resident; cohort streams

@@ -19,8 +19,8 @@ algorithm. The discriminator emits logits and losses use
 Capability record: since the record refactor ``FedGanAPI`` IS a
 ``FedAvgAPI`` whose local step is the adversarial D/G loop — the server
 update is the plain client average ("round" protocol, no carry), so
-FedGAN rides the fused round step, the pipelined loop, the windowed
-streaming scan and the on-device scan like plain FedAvg (the GAN local
+FedGAN rides the fused round step, the windowed streaming scan and the
+on-device scan like plain FedAvg (the GAN local
 step is prefix-stable in the step count: per-step noise keys fold_in on
 the step index, padded steps are tree_select no-ops). Only ``evaluate``
 differs: GANs have no accuracy — the reference logs only losses.

@@ -22,8 +22,8 @@ Hessian-vector approximation (architect.py:229) needed under XLA.
 Capability record: since the record refactor ``FedNASAPI`` IS a
 ``FedAvgAPI`` whose local step is the bilevel search (server update =
 plain client average, "round" protocol, no carry) — FedNAS rides the
-fused round step, the pipelined loop, the windowed streaming scan and
-the on-device scan. For that the train/valid split had to become
+fused round step, the windowed streaming scan and the on-device
+scan. For that the train/valid split had to become
 MASK-AWARE: the halves are cut at ``n_real // 2`` where ``n_real`` is
 the client's true (non-padded) step count, so a store cohort forced onto
 a larger window-max step bucket trains on exactly the same batches as

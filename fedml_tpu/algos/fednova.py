@@ -23,7 +23,7 @@ pure function of the cohort's sample counts, so the q-weights and the
 interpolation scalar are host-computed (float64, exactly the pre-record
 host loop's math) and ride the aux slot: ``_round_aux`` on the host/fused
 tiers, ``_window_scan_extras`` as ``[W, C]``/``[W]`` scanned operands on
-the windowed tier. That makes FedNova fused + windowed + pipelined with
+the windowed tier. That makes FedNova fused + windowed with
 no carry at all; only the on-device scan (which samples inside the jit
 and has no host-aux slot) refuses, with the record-derived reason.
 """

@@ -20,7 +20,7 @@ from fedml_tpu.trainer.local import make_local_train_fn_from_cfg
 class FedProxAPI(FedAvgAPI):
     """FedAvg whose LOCAL objective carries the proximal term — nothing
     else changes, so FedProx rides every execution tier FedAvg does
-    (pipelined, windowed streaming) through the inherited "round" carry
+    (fused, windowed streaming) through the inherited "round" carry
     protocol with NO carry at all: the μ term lives inside
     ``round_fn``'s local trainer, which the windowed scan replays as-is
     (docs/EXECUTION.md support matrix; bit-equality pinned in

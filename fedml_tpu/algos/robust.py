@@ -33,8 +33,7 @@ Byzantine-robustness stack (docs/ROBUSTNESS.md):
 - the weak-DP noise stream is now keyed by ``fold_in`` on the ROUND's
   rng key instead of a carried ``self.rng`` split chain (the PR-2
   prefix-stability discipline), which is what lets robust runs ride
-  ``train_rounds_windowed`` / ``train_rounds_pipelined`` bit-equal to
-  the host loop instead of flooring at per-round dispatch RTT.
+  ``train_rounds_windowed`` bit-equal to the host loop.
 """
 
 from __future__ import annotations

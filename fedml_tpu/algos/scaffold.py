@@ -64,8 +64,8 @@ class ScaffoldAPI(FedAvgAPI):
     #: Windowed carry protocol: the round itself consumes/produces the
     #: carried state (server control + client-control stack), so the
     #: step is custom — see _build_fused_step, which serves the fused
-    #: host round, the pipelined loop AND the windowed scan (the
-    #: capability record derives all three from it).
+    #: host round AND the windowed scan (the capability record derives
+    #: both from it).
     window_protocol = "custom"
     window_carry = "server control + client-control stack"
 

@@ -2,9 +2,10 @@
 
 PR 11 measured the wall this module breaks: every upload funnels through
 ONE single-threaded dispatch loop doing codec decode + delta
-reconstruction + accumulator fold, and ``ingest_occupancy`` on the bench
-drill sits at ~0.78 — the dispatch thread IS the serving ceiling, the
-software analogue of the server-side ingest bottleneck PAPERS.md
+reconstruction + accumulator fold, and ``ingest_occupancy`` on a CPU
+drill (script removed in PR 30) sat at ~0.78 — the dispatch thread IS
+the serving ceiling, the software analogue of the server-side ingest
+bottleneck PAPERS.md
 "Performance Improvement of Federated Learning Server using Smart NIC"
 (arXiv:2307.06561) names as *the* FL scaling limit. The decode and fold
 are pure numpy over model-sized arrays — exactly the work CPython

@@ -111,9 +111,9 @@ class ServerManager(_Manager):
     """Server managers additionally clock their dispatch thread: every
     upload funnels through this single-threaded handler loop — the
     server-ingest wall (arXiv:2307.06561) — and ``busy seconds ÷
-    (first→last message span)`` is the ``ingest_occupancy`` figure the
-    bench's ``ingest_profile`` section reports and a parallel-ingest PR
-    must beat. Attribute defaults via ``getattr`` so subclasses need no
+    (first→last message span)`` is the ``ingest_occupancy`` figure
+    ``ingest_profile()`` reports and a parallel-ingest PR must beat.
+    Attribute defaults via ``getattr`` so subclasses need no
     constructor coordination; the fake-clock protocol tests that invoke
     handlers directly simply record no occupancy."""
 

@@ -102,8 +102,7 @@ class TcpCommManager(BaseCommunicationManager):
 
     def _send_once(self, receiver: int, host: str, port: int,
                    blob: bytes) -> None:
-        """One transport attempt — the unit the RetryPolicy wraps (also
-        the no-policy side of bench.py's ``chaos_clean_overhead`` A/B).
+        """One transport attempt — the unit the RetryPolicy wraps.
         bytes → const uint8* zero-copy (argtype c_char_p)."""
         rc = self._lib.mn_send(self._sender, host.encode(), port, blob,
                                len(blob))

@@ -33,10 +33,8 @@ contract, bit-identically:
 
 The reduction-side counterpart (hierarchical sparse aggregation over
 groups instead of a client-stacked ``all_gather``) lives in
-``parallel/shard.py`` (``group_reduce``) and ``algos/hierarchical.py``;
-``bench.py``'s ``synthetic_1m`` section drives both at 1M+ synthetic
-clients with peak host RSS as a first-class submetric. See
-docs/EXECUTION.md "Scale tiers".
+``parallel/shard.py`` (``group_reduce``) and ``algos/hierarchical.py``.
+See docs/EXECUTION.md "Scale tiers".
 """
 
 from __future__ import annotations
@@ -230,8 +228,8 @@ class ShardedFederatedStore(FederatedStore):
 
     def nbytes(self) -> int:
         """Total DATASET bytes across shards (memmap shards count their
-        file size, not their resident pages — see ``bench.py``'s RSS
-        submetrics for what is actually paged in)."""
+        file size, not their resident pages — ``utils.rss_mb`` samples
+        what is actually paged in)."""
         return sum(sh.x.nbytes + sh.y.nbytes for sh in self._shards)
 
     @property
@@ -299,7 +297,7 @@ class ShardedFederatedStore(FederatedStore):
         global client ids (contiguous blocks, in shard order). Each
         shard is generated, memmap-spilled, and DROPPED before the next
         is built, so construction peak RSS is O(one shard) — the path
-        the million-client bench takes. ``progress(s)`` is called before
+        a million-client federation takes. ``progress(s)`` is called before
         each shard build (deadline checks / logging)."""
         os.makedirs(spill_dir, exist_ok=True)
         shards: List[StoreShard] = []

@@ -123,10 +123,9 @@ def make_stackoverflow_shard(
     counts)`` with pareto per-client sentence counts and next-token
     targets over [1, vocab). The single source of the count/token
     distribution: :func:`make_stackoverflow_nwp` builds the flat
-    federation from it, and ``bench.py``'s million-client
-    ``synthetic_1m`` section feeds it per shard to
-    ``ShardedFederatedStore.from_shard_builder`` — the 342k and 1M
-    scale points can never drift apart in law.
+    federation from it, and a million-client federation feeds it per
+    shard to ``ShardedFederatedStore.from_shard_builder`` — the 342k
+    and 1M scale points can never drift apart in law.
 
     ``law`` picks the TOKEN law (the count law is shared, so the two
     laws emit identical per-client sizes at one ``seed``):
@@ -203,8 +202,8 @@ def make_stackoverflow_nwp(
     stackoverflow_nwp/data_loader.py): pareto per-client sentence counts,
     next-token targets, tokens drawn from [1, vocab) so pad_id=0 never
     collides. Returns ``(x, y, client_indices)`` for FederatedStore /
-    build_federated_arrays. Shared by the full-scale store test and the
-    bench submetric so the two can never drift. ``law_kw`` forwards the
+    build_federated_arrays. The one builder of this federation
+    (tests/test_store.py's full-scale test reads it). ``law_kw`` forwards the
     token-law knobs (``law="dialect"`` + friends) to
     :func:`make_stackoverflow_shard`."""
     x, y, counts = make_stackoverflow_shard(n_clients, seq_len, vocab, seed,

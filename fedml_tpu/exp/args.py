@@ -186,7 +186,7 @@ def add_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
                         "none | auto (pad channel dims to MXU lane/"
                         "sublane multiples inside the jitted step; "
                         "logical shapes everywhere else — "
-                        "docs/EXECUTION.md MFU playbook) | im2col "
+                        "docs/EXECUTION.md Client-step levers) | im2col "
                         "(rephrase the 5x5 stem conv as patches + a 1x1 "
                         "conv — conv lane shaping beyond s2d, "
                         "CNNOriginalFedAvg only)")
@@ -195,7 +195,7 @@ def add_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
                         "bf16 — layer compute in bfloat16 inside the "
                         "jitted client step; params, gradients, "
                         "optimizer, aggregation and server carry stay "
-                        "fp32 (docs/EXECUTION.md MFU playbook)")
+                        "fp32 (docs/EXECUTION.md Client-step levers)")
     p.add_argument("--client_group_size", type=int, default=0,
                    help="clients trained at a time inside one round: 0 "
                         "(default) the whole cohort under one vmap | k > 0 "

@@ -7,7 +7,7 @@
 
 NHWC layout (TPU-native; the reference is NCHW torch).
 
-Lane-fill hooks (docs/ROOFLINE.md, parallel/layout.py): both nets take
+Lane-fill hooks (parallel/layout.py): both nets take
 ``stem="s2d"`` — a 2x2 space-to-depth input transform (1→4 channels at
 half spatial), the same MXU lane-fill lever the CIFAR ResNets carry
 first-class — and ``widths=(c1, c2)`` conv-width overrides, which is how

@@ -13,7 +13,7 @@ sigmoid gate. This module is ONE SHARD of an expert-parallel deployment
 ``first_expert_held`` on, and computes their part; a token none of whose
 experts is held gets the shared expert only.
 
-The equations, written out, are ``models/qwen3_next_reference.py``'s, which
+The equations, written out, are ``benchmark/reference_qwen3_next.py``'s, which
 imports nothing from here; the tests hold the two together. No biases.
 Norms, gates, decays, routing and the logits are float32; the matrix
 products take ``dtype`` inputs (``parallel/layout.step_dtype_model`` clones

@@ -49,9 +49,9 @@ class Norm(nn.Module):
     ``"gn_fused"`` (the pallas fused GroupNorm kernel,
     fedml_tpu.ops.group_norm — same math and param tree as ``"gn"``;
     measured SLOWER than XLA's conv-fused lowering at CIFAR-ResNet
-    shapes, so not the default — docs/ROOFLINE.md), or ``"none"``
-    (identity — the measurement ablation docs/ROOFLINE.md uses to
-    attribute normalization cost; not a training configuration).
+    shapes, so not the default — ops/group_norm.py), or ``"none"``
+    (identity — an ablation that attributes normalization cost by
+    leaving it out; not a training configuration).
 
     ``logical_channels`` (lane-fill compute layouts,
     ``parallel/layout.py``): when the module runs a lane-PADDED physical
@@ -190,7 +190,7 @@ def space_to_depth(x, block: int = 2):
     """[B, H, W, C] → [B, H/b, W/b, C·b²]: move 2x2 spatial patches into
     channels — the classic TPU transform for small-channel CNN stems
     (narrow early stages under-fill the 128-lane MXU; see
-    docs/ROOFLINE.md)."""
+    parallel/layout.py)."""
     b, h, w, c = x.shape
     x = x.reshape(b, h // block, block, w // block, block, c)
     return jnp.transpose(x, (0, 1, 3, 2, 4, 5)).reshape(
@@ -206,9 +206,9 @@ class CifarResNet(nn.Module):
     stage widths doubled to (32, 64, 128). Per-conv FLOPs stay ~equal
     (H·W·C² is invariant under half-spatial/double-channel), but every
     stage's channel count doubles its MXU lane fill — stage 3 fills all
-    128 lanes. NOT the reference model (4x params per conv): the bench
-    keeps the primary config on the standard stem and reports the s2d
-    variant as a separate submetric."""
+    128 lanes. NOT the reference model (4x params per conv): the
+    benchmark's ResNet-56 cells keep the standard stem, and no cell
+    runs this variant."""
 
     layers: Sequence[int] = (6, 6, 6)  # 56 = 6*3*3 + 2
     num_classes: int = 10
@@ -311,8 +311,8 @@ def resnet56(num_classes: int = 10, norm: str = "gn", dtype=None,
 def resnet56_s2d(num_classes: int = 10, norm: str = "gn", dtype=None, **_):
     """The measured lane-fill variant as a first-class registry name
     (CLI: ``--model resnet56_s2d``): 2x2 space-to-depth stem, stage
-    widths doubled — docs/ROOFLINE.md measured it at ~3.2x the reference
-    stem's samples/sec (MFU 2.9% → 8.7%) at equal per-conv FLOPs. NOT
+    widths doubled — ~3.2x the reference stem's samples/sec through the
+    retired attachment (no ledger line) at equal per-conv FLOPs. NOT
     weight-compatible with the reference model (4x params per conv) —
     ``torch_convert`` refuses reference checkpoints for it loudly."""
     return CifarResNet(layers=(6, 6, 6), num_classes=num_classes, norm=norm,

@@ -188,8 +188,8 @@ def transformer_lm(vocab_size: int = 90, d_model: int = 128, n_heads: int = 4,
                    **_):
     """``attn="flash"`` swaps in the pallas fused kernel
     (fedml_tpu.ops.flash_attention) — O(T) memory, faster than dense on
-    TPU from T≈2k with bf16 activations (measured crossover: bench
-    flash_attention_sweep). ``attn_fn`` (a callable) overrides both.
+    TPU from T≈2k with bf16 activations (a crossover read through the
+    retired attachment). ``attn_fn`` (a callable) overrides both.
 
     ``adapter_rank > 0`` injects LoRA pairs (scope ``attn`` | ``mlp`` |
     ``all``) for parameter-efficient federated finetuning — see

@@ -208,7 +208,7 @@ def payload_nbytes(tree) -> int:
 
 def hist_fields(hist: Histogram, name: str) -> Dict[str, Optional[float]]:
     """``{name_p50, name_p95, name_mean, name_count}`` — the compact
-    per-histogram record the bench's ``ingest_profile`` section reports."""
+    per-histogram record ``ingest_profile()`` reports."""
     if not hist.count:
         return {f"{name}_count": 0}
     return {

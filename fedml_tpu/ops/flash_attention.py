@@ -319,9 +319,9 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int | None = None,
     Differentiable (custom VJP, FlashAttention-2-style backward).
 
     Default blocks are (512, 1024) at every T (clamped to divisors of
-    T): the r4 re-sweep with floor-calibrated timing
-    (scripts/sweep_flash_bwd.py + the fwd confirm sweep, v5e,
-    2026-07-31) measures (512, 1024) ahead of the r3-era (256, 512)
+    T): the r4 re-sweep with floor-calibrated timing (v5e through
+    the retired attachment, 2026-07-31; not re-measured on this
+    benchmark) measured (512, 1024) ahead of the r3-era (256, 512)
     default at EVERY point — fwd +39% @ T=2048, +81% @ 4096; training
     +27% / +42% — the r3 "small blocks win at short T" conclusion was an
     artifact of dispatch-polluted timing (each r3 call carried ~0.1 s of

@@ -30,7 +30,7 @@ decays and the state are float32.
 The backward pass is JAX's, through the batched products and the scan (whose
 per-chunk states it saves: ``T / chunk`` states of ``dk x dv`` a head).
 ``gated_delta_rule_recurrent`` is the token-by-token rule, the oracle of the
-tests; the model's reference (``models/qwen3_next_reference.py``) has its own
+tests; the model's reference (``benchmark/reference_qwen3_next.py``) has its own
 copy and imports nothing from here.
 """
 

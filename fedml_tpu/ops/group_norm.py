@@ -1,14 +1,14 @@
 """Fused GroupNorm as pallas TPU kernels (fwd + custom VJP).
 
-Why it exists: the s2d round-time attribution
-(scripts/sweep_s2d_attrib.py, v5e, 2026-07-31) measured GroupNorm's
+Why it exists: a round-time attribution (v5e through the retired
+attachment, 2026-07-31; its script was removed in PR 30) put GroupNorm's
 MARGINAL cost at ~38% of the full federated round, so a fused
 one-VMEM-pass kernel (stats + normalize + affine; backward recomputes
 instead of saving temporaries) was the round's designated lever.
 
-Measured OUTCOME — a documented dead end at CIFAR-ResNet shapes
-(docs/ROOFLINE.md): the fused-GN round runs 98.2 ms vs 44.1 ms for
-XLA's lowering (same config, same params). The ablation's 38% is the
+Measured OUTCOME — a dead end at CIFAR-ResNet shapes (same attachment,
+not re-measured on this benchmark): the fused-GN round ran 98.2 ms vs
+44.1 ms for XLA's lowering (same config, same params). The ablation's 38% is the
 marginal cost of GN *fused into the surrounding conv chains* — XLA
 folds the normalize/affine into conv epilogues, so swapping in an
 opaque pallas call severs those fusions and forces extra HBM
@@ -16,8 +16,8 @@ round-trips per layer that the kernel's own efficiency cannot buy
 back. The op stays available (``models.resnet.Norm(kind="gn_fused")``,
 param-compatible with ``"gn"``); models default to ``"gn"``.
 
-The reserved use case is now MEASURED, not hypothetical
-(scripts/sweep_gn_standalone.py, v5e, 2026-07-31, random cotangent —
+The reserved use case was MEASURED, not hypothetical (same attachment,
+2026-07-31, script removed in PR 30; random cotangent —
 an all-ones cotangent lets XLA simplify the mean-subtracted backward
 and was rejected as an unfair workload): standalone wide-channel GN
 TRAINING steps (fwd+bwd) run 0.67-0.73x of flax's time at C=2048-4096

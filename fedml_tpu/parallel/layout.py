@@ -1,8 +1,8 @@
 """Lane-fill compute layouts: logical model, lane-aligned client step.
 
-docs/ROOFLINE.md pins why the CIFAR CNN hot path under-delivers: channel
-dims below the MXU's 128-lane width leave lanes idle (dw-eff 0.31 → 1.04
-exactly as channels reach 128). This module makes channel-dim padding a
+Why the CIFAR CNN hot path under-delivers: channel dims below the MXU's
+128-lane width leave lanes idle (a reading through the retired attachment,
+not re-measured on this benchmark). This module makes channel-dim padding a
 FRAMEWORK capability instead of a per-model fork, with a hard invisibility
 contract:
 
@@ -59,8 +59,8 @@ class LayoutPolicy:
     """The pad policy: round channel dims up to ``sublane`` multiples,
     and snap to the next ``lane`` multiple when already within
     ``lane_snap`` of it (96 → 128 at the default 0.25; 16 stays 16 —
-    padding 8x the FLOPs for an already-paid lane pass hurts,
-    docs/ROOFLINE.md)."""
+    padding 8x the FLOPs for an already-paid lane pass
+    hurts)."""
 
     lane: int = 128
     sublane: int = 8
@@ -338,7 +338,7 @@ def step_dtype_model(model, dtype):
 
 
 def im2col_layout(model, sample_x):
-    """Conv lane shaping beyond s2d (docs/EXECUTION.md "MFU playbook"):
+    """Conv lane shaping beyond s2d (docs/EXECUTION.md "Client-step levers"):
     a :class:`ComputeLayout` whose physical twin rephrases the 5x5 STEM
     conv as patch extraction + a 1x1 conv — the MXU contraction dim
     grows from Cin (1, or 4 under s2d) to k²·Cin (25/100), one dense
@@ -349,7 +349,7 @@ def im2col_layout(model, sample_x):
     than the conv lowering, so the step carries the CNN family's
     documented ~1-ulp tolerance rather than the ResNet family's
     bit-exactness. Widths are NOT padded here — compose measurement-wise
-    with ``compute_layout`` via the bench A/B, not structurally.
+    with ``compute_layout`` by measuring both, not structurally.
 
     Supported: ``CNNOriginalFedAvg`` (stem "conv" or "s2d"). Dropout
     models refuse for the usual mask-shape reason; other families have

@@ -30,7 +30,7 @@ For tokens/s the module also carries :class:`AdapterDecoder`, a
 KV-cached prefill + per-step decode over the SAME merged params —
 single-token steps never recompute the prompt. Attention for the
 full-sequence path follows the flash-attention sweep: causal flash above
-the measured crossover (``T >= 2048``, bench ``flash_attention_sweep``),
+the crossover (``T >= 2048``, :data:`FLASH_CROSSOVER_T`),
 dense below it (:func:`pick_attention`).
 """
 
@@ -45,8 +45,8 @@ import numpy as np
 from fedml_tpu.models.transformer import lora_delta_batched
 from fedml_tpu.trainer.local import NetState
 
-#: Measured flash-vs-dense crossover on the bench sweep
-#: (bench.py flash_attention_sweep; docs/EXECUTION.md): the pallas fused
+#: Flash-vs-dense crossover (v5e through the retired attachment,
+#: 2026-07-31; not re-measured on this benchmark): the pallas fused
 #: kernel wins from T≈2048 with bf16 activations, dense wins below.
 FLASH_CROSSOVER_T = 2048
 

@@ -104,9 +104,8 @@ class FleetResult:
             "final_accuracy": self.final_accuracy,
             "churn_killed_uploads": self.churn_killed,
             # The memory axis of the serving story (ROADMAP item 1) —
-            # CURRENT host RSS at summary time, the same single-sourced
-            # sample bench.py records per section, so sim drills report
-            # it without the bench harness.
+            # CURRENT host RSS at summary time (utils.rss_mb, the one
+            # sampler), so sim drills report it themselves.
             "host_rss_mb": round(rss_mb(), 1),
             "evictions": self.health.get("evictions", 0),
             # Churn recovery: the sync tier counts re-admissions of

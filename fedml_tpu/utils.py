@@ -116,8 +116,8 @@ def rss_mb() -> float:
     the getrusage peak elsewhere). Current, not ru_maxrss: the process
     peak is monotone, so point-in-time memory claims (the sharded
     store's flat-RSS story, a sim drill's host-memory axis) need live
-    samples. Single-sourced here for bench.py's per-section trajectory
-    AND ``sim.FleetResult.summary()``'s host-RSS axis."""
+    samples. Single-sourced here for ``sim.FleetResult.summary()``'s
+    host-RSS axis."""
     try:
         with open("/proc/self/statm") as f:
             return int(f.read().split()[1]) * os.sysconf("SC_PAGE_SIZE") / 1e6

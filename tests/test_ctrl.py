@@ -8,8 +8,7 @@ order, interval gating, failure containment with detach-after-3), the
 controller-off bit-equality pins, a seconds-scale spiked-sim actuation
 smoke, and the same-controller-object sim→loopback portability pin. The
 full load-spike drill (controller vs static arms, two-run reproducible)
-is ``slow``-marked; bench's ``adaptive_control`` section runs its
-headline twin.
+is ``slow``-marked.
 """
 
 import hashlib
@@ -585,8 +584,7 @@ def test_spike_defaults_are_inert():
     assert hot.load_factor(9.9) == 1.0 and hot.load_factor(20.0) == 1.0
 
 
-# -- the load-spike drill (pinned config; bench `adaptive_control` runs
-#    the headline twin) ------------------------------------------------------
+# -- the load-spike drill (pinned config) ------------------------------------
 
 DRILL_SPEC = FleetSpec(n_devices=8, seed=11, horizon_s=20000.0,
                        mean_online=0.92, base_round_s=20.0, slot_s=400.0,

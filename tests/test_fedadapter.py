@@ -1,5 +1,5 @@
 """Frozen-base adapter finetuning (PR 15): the split/merge seam, the
-FedAdapterAPI tiers (windowed/pipelined/on-device bit-equality, zero
+FedAdapterAPI tiers (windowed/on-device bit-equality, zero
 steady-state recompiles, checkpoint at a window boundary incl. the
 personalized adapter stacks), the frozen base's fp32 bitwise invariance
 (host loop AND under the codec on the message-passing tiers), the
@@ -212,17 +212,6 @@ def test_windowed_vs_host_bit_equal_non_dividing():
     np.testing.assert_array_equal(la, lb)
     _trees_equal(host.net.params, win.net.params)
     _trees_equal(base0, win.base)  # frozen through the scan too
-
-
-def test_pipelined_and_fused_bit_equal():
-    x, y, parts = _token_data()
-    fed = build_federated_arrays(x, y, parts, B)
-    host = _mk(fed)
-    la = [host.train_one_round(r)["train_loss"] for r in range(5)]
-    pipe = _mk(fed)
-    lb = pipe.train_rounds_pipelined(5)
-    np.testing.assert_array_equal(la, lb)
-    _trees_equal(host.net.params, pipe.net.params)
 
 
 def test_on_device_scan_runs():
@@ -571,7 +560,7 @@ def test_capability_record_all_tiers():
 
     rec = record_for(FedAdapterAPI)
     assert rec.protocol == "round"
-    assert rec.fused and rec.pipelined and rec.windowed and rec.on_device
+    assert rec.fused and rec.windowed and rec.on_device
     assert rec.streaming
 
 
@@ -580,7 +569,7 @@ def test_support_matrix_has_fedadapter_row():
 
     row = [l for l in render_matrix().splitlines()
            if l.startswith("| FedAdapter ")]
-    assert row and row[0].count("✓") == 4
+    assert row and row[0].count("✓") == 3
 
 
 # ---------------------------------------------------- driver rejections --

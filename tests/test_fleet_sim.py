@@ -7,7 +7,7 @@ availability/speed schedules and identical fedbuff aggregation order),
 ``staleness_weight`` edge cases, the buffered server's fake-clock
 eviction/staleness accounting, the task-seq dedupe regression, a
 seconds-scale loopback fedbuff smoke, and a tiny SIM-fabric run. The
-churn serving drill backing the bench ``fleet_sim`` section (sync
+churn serving drill (sync
 first-k vs buffered(k) vs pure async on one seeded diurnal trace) is
 ``slow``-marked.
 """
@@ -428,7 +428,7 @@ def test_sim_sync_mode_drives_real_first_k_path():
 
 @pytest.mark.slow
 def test_fleet_churn_serving_drill():
-    """The bench fleet_sim acceptance, pinned as a test: on one fixed
+    """The fleet-sim acceptance, pinned as a test: on one fixed
     seeded diurnal trace with mid-round churn, buffered(k) sustains
     strictly higher round-throughput than sync first-k(k), holds a lower
     staleness tail than pure async, and lands in the clean-run accuracy

@@ -1,10 +1,9 @@
 """Fused pallas GroupNorm ≡ flax nn.GroupNorm (fwd + grads).
 
-The kernel exists because GN measured 37.9% marginal cost of the s2d
-federated round under XLA's lowering (scripts/sweep_s2d_attrib.py with
-floor-calibrated windows; the earlier ~45% figure came from the
-un-calibrated scan windows r4 discredited — docs/ROOFLINE.md's
-attribution table). Equivalence here is what licenses swapping it into
+The kernel exists because GN read ~38% marginal cost of the s2d
+federated round under XLA's lowering (v5e through the retired
+attachment, 2026-07-31; script removed in PR 30, not re-measured on this
+benchmark). Equivalence here is what licenses swapping it into
 models via ``Norm(kind="gn_fused")``.
 Runs in pallas interpreter mode on the CPU mesh.
 """

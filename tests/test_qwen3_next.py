@@ -1,5 +1,6 @@
 """Qwen3-Next (``models/qwen3_next.py``) against its plain reference
-(``models/qwen3_next_reference.py``, which imports nothing from the model),
+(``benchmark/reference_qwen3_next.py``, the one the cell is judged by, which
+imports nothing from the program: tests/test_benchmark_selfcheck.py),
 on seeded weights at a small size: hidden 64, one period of 4 layers, 16
 experts top-4, 2 + 4 linear heads and 4 over 2 attention heads of size 16,
 vocabulary 256, T 128, chunks of 16. CPU, float32 at the highest precision
@@ -21,12 +22,22 @@ from fedml_tpu.algos.fedavg import FedAvgAPI
 from fedml_tpu.data.batching import build_federated_arrays
 from fedml_tpu.models import create_model
 from fedml_tpu.models import qwen3_next as qn
-from fedml_tpu.models import qwen3_next_reference as ref
 from fedml_tpu.ops.gated_delta import (gated_delta_rule,
                                        gated_delta_rule_recurrent)
 from fedml_tpu.trainer.local import seq_softmax_ce
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load_benchmark(relative: str, name: str):
+    path = os.path.join(ROOT, "benchmark", relative)
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+ref = _load_benchmark("reference_qwen3_next.py", "bench_reference_qwen3_next")
 T, VOCAB = 128, 256
 SMALL = dict(
     vocab_size=VOCAB, hidden_size=64, num_hidden_layers=4,
@@ -340,11 +351,8 @@ def test_one_round_through_fedavg_equals_the_reference_round():
 # --- (g) the bf16 step inside the chip comparison's tolerances -------------
 
 def _runner():
-    path = os.path.join(ROOT, "benchmark", "runners", "fed_lm_round.py")
-    spec = importlib.util.spec_from_file_location("bench_fed_lm_round", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+    return _load_benchmark(os.path.join("runners", "fed_lm_round.py"),
+                           "bench_fed_lm_round")
 
 
 def test_the_bf16_step_is_inside_the_chip_comparisons_tolerances():

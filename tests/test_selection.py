@@ -179,13 +179,11 @@ def test_oort_deterministic_and_padded():
         b.train_one_round(r)
 
 
-def test_oort_rejects_scan_and_pipelined_paths():
+def test_oort_rejects_scan_paths():
     fed = _noisy_clients()
     api = FedAvgAPI(LogisticRegression(num_classes=2), fed, None, _ocfg())
     with pytest.raises(NotImplementedError):
         api.train_rounds_on_device(2)
-    with pytest.raises(NotImplementedError, match="oort"):
-        api.train_rounds_pipelined(2)
 
 
 def test_oort_over_streaming_store():

@@ -280,78 +280,6 @@ def test_max_steps_truncates_clients():
 
 
 @pytest.mark.slow  # >5.4 s drill; tier-1 re-fit to the 870 s budget on the 2-core box (r20 audit)
-def test_pipelined_rounds_match_per_round_loop():
-    """train_rounds_pipelined defers the loss fetches but must produce
-    EXACTLY the per-round host loop's sequence (same rng chain, same
-    round functions) — on the streaming store and the resident layout."""
-    x, y, parts = _classification(8, 64)
-    for make in (lambda: FederatedStore(x, y, parts, batch_size=16),
-                 lambda: build_federated_arrays(x, y, parts, batch_size=16)):
-        a = FedAvgAPI(LogisticRegression(num_classes=2), make(), None,
-                      _cfg(8, 4, rounds=6))
-        b = FedAvgAPI(LogisticRegression(num_classes=2), make(), None,
-                      _cfg(8, 4, rounds=6))
-        la = [a.train_one_round(r)["train_loss"] for r in range(6)]
-        lb = b.train_rounds_pipelined(6)
-        np.testing.assert_allclose(la, lb, rtol=0, atol=0)
-        for pa, pb in zip(jax.tree.leaves(a.net.params),
-                          jax.tree.leaves(b.net.params)):
-            np.testing.assert_array_equal(np.asarray(pa), np.asarray(pb))
-
-
-def test_pipelined_rounds_fedopt_subclass():
-    """FedOpt rides the 'round' carry protocol: the pipelined loop must
-    be BIT-EQUAL to its per-round host loop (same rng chain, same jitted
-    server step applied between rounds), params and opt state."""
-    from fedml_tpu.algos.fedopt import FedOptAPI
-
-    x, y, parts = _classification(8, 64)
-
-    def mk():
-        cfg = _cfg(8, 4, rounds=5)
-        cfg.server_optimizer = "adam"
-        cfg.server_lr = 0.05
-        return FedOptAPI(LogisticRegression(num_classes=2),
-                         FederatedStore(x, y, parts, batch_size=16), None,
-                         cfg)
-
-    host, pipe = mk(), mk()
-    la = [host.train_one_round(r)["train_loss"] for r in range(5)]
-    lb = pipe.train_rounds_pipelined(5)
-    np.testing.assert_allclose(la, lb, rtol=0, atol=0)
-    for a, b in zip(jax.tree.leaves(host.net.params),
-                    jax.tree.leaves(pipe.net.params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    for a, b in zip(jax.tree.leaves(host.server_opt_state),
-                    jax.tree.leaves(pipe.server_opt_state)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-@pytest.mark.slow  # >5.8 s drill; tier-1 re-fit to the 870 s budget on the 2-core box (r20 audit)
-def test_pipelined_rounds_reject_custom_round_subclasses():
-    """Algorithms whose capability record has no fused step must refuse
-    the pipelined loop instead of silently running plain FedAvg rounds
-    (SCAFFOLD PIPELINES now — its record publishes the fused stateful
-    step; TurboAggregate's host-side MPC round is the real refusal)."""
-    from fedml_tpu.algos.scaffold import ScaffoldAPI
-    from fedml_tpu.algos.turboaggregate import TurboAggregateAPI
-
-    x, y, parts = _classification(8, 64)
-    fed = build_federated_arrays(x, y, parts, batch_size=16)
-    turbo = TurboAggregateAPI(LogisticRegression(num_classes=2), fed,
-                              None, _cfg(8, 8))
-    with pytest.raises(NotImplementedError, match="MPC"):
-        turbo.train_rounds_pipelined(2)
-    sc = ScaffoldAPI(LogisticRegression(num_classes=2), fed, None,
-                     _cfg(8, 8))
-    host = ScaffoldAPI(LogisticRegression(num_classes=2), fed, None,
-                       _cfg(8, 8))
-    la = [host.train_one_round(r)["train_loss"] for r in range(2)]
-    lb = sc.train_rounds_pipelined(2)
-    np.testing.assert_array_equal(la, lb)
-
-
-@pytest.mark.slow  # >5.4 s drill; tier-1 re-fit to the 870 s budget on the 2-core box (r20 audit)
 def test_sharded_scan_repeat_calls_continue_bit_equal():
     """Two chunked scan calls (4+4 rounds) must equal one 8-round host
     loop exactly — pins that the scan reads the replicated federation the
@@ -431,8 +359,8 @@ def test_full_stackoverflow_scale_342477_clients():
     store = FederatedStore(x, y, parts, batch_size=16)
     assert store.num_clients == 342_477
 
-    # Small LSTM dims keep the CI-suite compile fast; the bench submetric
-    # (bench.py stackoverflow_342k) runs the reference's real 96/670 dims.
+    # Small LSTM dims keep the CI-suite compile fast (the reference's
+    # real dims are 96/670).
     api = FedAvgAPI(
         RNNStackOverflow(vocab_size=V, embedding_dim=16, hidden_size=32),
         store, None, _cfg(C, 50, rounds=3, batch=16, lr=0.3),

@@ -570,8 +570,6 @@ def test_windowed_guards():
                        _cfg(12, 4, 4))
     with pytest.raises(NotImplementedError, match="customizes the round"):
         api.train_rounds_windowed(4)
-    with pytest.raises(NotImplementedError, match="customizes the round"):
-        api.train_rounds_pipelined(4)
 
     # "custom" WITHOUT a custom scan body would inherit the plain round
     # replay — refuse (symmetric to the inherited-"round" check).

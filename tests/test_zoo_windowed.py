@@ -1,5 +1,5 @@
 """Whole-zoo carry capability records: every pure-server-state algorithm
-rides fused + windowed + pipelined execution, pinned bit-equal to its
+rides fused + windowed execution, pinned bit-equal to its
 host loop; excluded algorithms refuse with the record-derived reason;
 the EXECUTION.md support matrix is generated from the records and
 drift-tested.
@@ -489,9 +489,6 @@ def test_decentralized_on_device_scan_bit_equal():
         np.testing.assert_allclose(hl, np.asarray(dl), rtol=1e-6,
                                    atol=1e-6)
         _assert_trees_equal(host.nets, dev.nets)
-        pipe = mk(mode)
-        pl = pipe.train_rounds_pipelined(4)
-        np.testing.assert_array_equal(hl, pl)
         # Record-derived refusal: nothing streams in gossip.
         with pytest.raises(NotImplementedError, match="gossip"):
             dev.train_rounds_windowed(4)
@@ -536,7 +533,6 @@ def test_excluded_algorithms_refuse_with_their_declared_reason():
     turbo = TurboAggregateAPI(LogisticRegression(num_classes=2), fed,
                               None, _cfg(4, 4, 2))
     for entry in (turbo.train_rounds_windowed,
-                  turbo.train_rounds_pipelined,
                   turbo.train_rounds_on_device):
         with pytest.raises(NotImplementedError, match="MPC protocol"):
             entry(2)
@@ -558,7 +554,7 @@ def test_fedseg_record_rides_for_free():
 
     rec = record_for(FedSegAPI)
     assert rec.protocol == "round"
-    assert rec.fused and rec.windowed and rec.pipelined and rec.on_device
+    assert rec.fused and rec.windowed and rec.on_device
 
 
 # ------------------------------------------- generated matrix drift ------
