@@ -13,8 +13,11 @@ weights from a seed:
 - ``train_transformer``: a FedAvg round of the d512 x 4 transformer LM at
   T=512, once with dense attention and once with the pallas flash kernels;
 - ``kernels``: flash attention forward + all three gradients at T=2048 for
-  d_head 64 and 128, and the fused GroupNorm at ResNet-56's shapes, under a
-  vmap over clients, against float32 ``jax.numpy`` references;
+  d_head 64 and 128, the gated delta rule's hand-over kernels with all five
+  gradients at Qwen3-Next's head size (128 x 128, chunk 64), and the fused
+  GroupNorm at ResNet-56's shapes, under a vmap over clients, against
+  float32 ``jax.numpy`` references; the ``GatedDeltaNet`` layer at the
+  published heads (16 key, 32 value) is lowered and its Mosaic calls counted;
 - ``adapter_round``: a FedAdapter round over the frozen d512 x 4 base;
 - ``serve``: 64 requests through ``ServeManager`` (batched multi-adapter
   prefill + KV-cached decode), one row checked against the B=1 path;
@@ -90,6 +93,7 @@ class Sizes:
     lm_cohort: int
     kernel_t: int
     kernel_heads: tuple      # ((H, d_head), ...)
+    gdn_heads: tuple         # (key heads, value heads, d_head)
     gn_shapes: tuple         # ((height == width, channels), ...)
     gn_batch: int
     serve_seq: int
@@ -104,7 +108,7 @@ REAL = Sizes(
     resnet="resnet56", clients=128, per_client=256, cohort=8, batch=32,
     vocab=10004, d_model=512, n_heads=8, n_layers=4, seq_len=512,
     lm_clients=16, lm_per_client=32, lm_batch=8, lm_cohort=8,
-    kernel_t=2048, kernel_heads=((8, 64), (4, 128)),
+    kernel_t=2048, kernel_heads=((8, 64), (4, 128)), gdn_heads=(16, 32, 128),
     gn_shapes=((32, 16), (8, 256)), gn_batch=32,
     serve_seq=128, serve_batch=32, serve_new=16, serve_requests=64,
     chain_dim=4096, chain_s=0.5)
@@ -112,7 +116,7 @@ TOY = Sizes(
     resnet="resnet20", clients=8, per_client=16, cohort=4, batch=8,
     vocab=64, d_model=32, n_heads=2, n_layers=1, seq_len=32,
     lm_clients=4, lm_per_client=4, lm_batch=2, lm_cohort=2,
-    kernel_t=128, kernel_heads=((2, 16), (1, 32)),
+    kernel_t=128, kernel_heads=((2, 16), (1, 32)), gdn_heads=(1, 2, 128),
     gn_shapes=((8, 16), (4, 32)), gn_batch=4,
     serve_seq=16, serve_batch=4, serve_new=3, serve_requests=8,
     chain_dim=256, chain_s=0.05)
@@ -398,6 +402,40 @@ def phase_kernels(ctx: Ctx, out: dict) -> None:
             jax.vmap(partial(flash_attention, causal=True)),
             jax.vmap(partial(reference_attention, causal=True)),
             do, q, k, v)       # rel_err order: o, dq, dk, dv
+
+    from fedml_tpu.models.qwen3_next import GatedDeltaNet, Qwen3NextShapes
+    from fedml_tpu.ops.gated_delta import (gated_delta_rule,
+                                           gated_delta_rule_recurrent)
+
+    hk, hv, d = s.gdn_heads
+    keys = jax.random.split(jax.random.PRNGKey(d), 6)
+    shape = (n_clients, 1, s.kernel_t, hv // hk, d)
+
+    def unit(key):      # what the layer feeds the rule: rows of norm 1
+        x = jax.random.normal(key, shape)
+        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True))
+
+    compare(
+        f"gated_delta_h{hv // hk}_d{d}",
+        jax.vmap(partial(gated_delta_rule, chunk=64)),
+        jax.vmap(gated_delta_rule_recurrent),
+        jax.random.normal(keys[0], shape, jnp.bfloat16),
+        (unit(keys[1]) * d ** -0.5).astype(jnp.bfloat16),
+        unit(keys[2]).astype(jnp.bfloat16),
+        jax.random.normal(keys[3], shape, jnp.bfloat16),
+        -0.1 * jnp.exp(jax.random.normal(keys[4], shape[:-1])),
+        jax.nn.sigmoid(jax.random.normal(keys[5], shape[:-1])))
+    # rel_err order: o, dq, dk, dv, dg, dbeta
+    layer = GatedDeltaNet(Qwen3NextShapes(
+        hidden_size=s.d_model, linear_num_key_heads=hk,
+        linear_num_value_heads=hv, linear_key_head_dim=d,
+        linear_value_head_dim=d), jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((1, s.kernel_t, s.d_model), jnp.bfloat16)
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+    # lowered, not run: no error to report (``max_rel_err`` reads every entry)
+    out["gated_deltanet_layer"] = {"rel_err": [], "mosaic_calls": mosaic_calls(
+        ctx, jax.jit(jax.grad(lambda p, a: jnp.sum(layer.apply(p, a)))).lower(
+            params, x), "GatedDeltaNet forward + backward")}
 
     def gn_reference(groups, x, gamma, beta):
         n, hh, ww, c = x.shape

@@ -9,6 +9,7 @@ unless a test says bf16.
 
 import functools
 import importlib.util
+import json
 import os
 from functools import partial
 
@@ -22,8 +23,10 @@ from fedml_tpu.algos.fedavg import FedAvgAPI
 from fedml_tpu.data.batching import build_federated_arrays
 from fedml_tpu.models import create_model
 from fedml_tpu.models import qwen3_next as qn
+from fedml_tpu.ops import gated_delta
 from fedml_tpu.ops.gated_delta import (gated_delta_rule,
-                                       gated_delta_rule_recurrent)
+                                       gated_delta_rule_recurrent,
+                                       takes_kernel)
 from fedml_tpu.trainer.local import seq_softmax_ce
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -173,12 +176,25 @@ def _delta_inputs(t, seed=0, heads=4, dk=16, dv=16, batch=2):
     return q, k, v, g, beta
 
 
-@pytest.mark.parametrize("t, chunk", [(128, 16), (100, 16), (192, 64),
-                                      (37, 64)])
+# heads of 128 x 128 with a chunk of 64 take the Pallas hand-over kernels
+# (interpreted on the CPU); everything narrower keeps the scan
+KERNEL_SHAPE = dict(heads=2, dk=128, dv=128)
+
+
+@pytest.mark.parametrize("t, chunk, shape", [
+    pytest.param(128, 16, {}, id="128-16"),
+    pytest.param(100, 16, {}, id="100-16"),
+    pytest.param(192, 64, {}, id="192-64"),
+    pytest.param(37, 64, {}, id="37-64"),
+    pytest.param(256, 64, KERNEL_SHAPE, id="kernel-256-64"),
+    pytest.param(200, 64, KERNEL_SHAPE, id="kernel-200-64"),
+    pytest.param(37, 64, KERNEL_SHAPE, id="kernel-37-64")])
 @_highest
-def test_chunked_delta_rule_forward_and_backward(t, chunk):
+def test_chunked_delta_rule_forward_and_backward(t, chunk, shape):
     """``t`` not a multiple of the chunk, and shorter than one, included."""
-    args = _delta_inputs(t)
+    args = _delta_inputs(t, **shape)
+    assert takes_kernel(args[0].shape[-1], args[2].shape[-1], chunk) == bool(
+        shape)
     chunked = partial(gated_delta_rule, chunk=chunk)
     _close(jax.jit(chunked)(*args),
            jax.jit(gated_delta_rule_recurrent)(*args), 1e-5)
@@ -189,6 +205,96 @@ def test_chunked_delta_rule_forward_and_backward(t, chunk):
 
     for got, want in zip(grads(chunked), grads(gated_delta_rule_recurrent)):
         _close(got, want, 2e-5)
+
+
+def _xla_rule(monkeypatch):
+    """``gated_delta_rule`` held to its ``lax.scan``, whatever the shapes."""
+    monkeypatch.setattr(gated_delta, "takes_kernel", lambda *shape: False)
+    return partial(gated_delta_rule, chunk=64)
+
+
+def test_the_kernels_equal_the_scan_in_bf16(monkeypatch):
+    """Same products in the same dtype, the state float32 on both sides:
+    apart by bf16 rounding of differently associated sums and no more."""
+    args = _delta_inputs(200, **KERNEL_SHAPE)
+    args = tuple(a.astype(jnp.bfloat16) for a in args[:3]) + args[3:]
+
+    def both(fn):
+        out, vjp = jax.vjp(fn, *args)
+        return (out,) + vjp(jnp.ones_like(out))
+
+    got = jax.jit(partial(both, partial(gated_delta_rule, chunk=64)))()
+    want = jax.jit(partial(both, _xla_rule(monkeypatch)))()
+    for g, w, what in zip(got, want, ("o", "dq", "dk", "dv", "dg", "dbeta")):
+        assert g.dtype == w.dtype, what
+        _close(g.astype(jnp.float32), w.astype(jnp.float32), 2 ** -6, what)
+
+
+@_highest
+def test_the_kernels_under_vmap_and_checkpoint():
+    """What the round does to the rule: ``nn.remat`` around the layer and
+    (in the vmapped round) a leading client axis."""
+    clients = [_delta_inputs(100, seed=s, batch=1, **KERNEL_SHAPE)
+               for s in (0, 1)]
+    args = tuple(jnp.stack(a) for a in zip(*clients))
+
+    def loss(fn, *a):
+        rule = jax.vmap(jax.checkpoint(fn))
+        return jnp.sum(jnp.sin(rule(*a)))
+
+    def grads(fn):
+        return jax.jit(jax.value_and_grad(
+            partial(loss, fn), argnums=(0, 1, 2, 3, 4)))(*args)
+
+    (got, dgot), (want, dwant) = (grads(partial(gated_delta_rule, chunk=64)),
+                                  grads(gated_delta_rule_recurrent))
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for g, w in zip(dgot, dwant):
+        _close(g, w, 2e-5)
+
+
+@_highest
+def test_the_inverse_has_its_own_derivative():
+    """``dA = -T^T dT T^T`` (the kernel path) against JAX's transpose of the
+    series (the scan's), on strictly lower triangular chunks."""
+    a = jnp.tril(0.3 * jax.random.normal(jax.random.PRNGKey(3), (3, 64, 64)),
+                 -1)
+    cot = jax.random.normal(jax.random.PRNGKey(4), a.shape)
+
+    def grad(inverse):
+        return jax.jit(jax.grad(lambda x: jnp.sum(inverse(x) * cot)))(a)
+
+    want = grad(gated_delta._inverse_unit_lower)
+    _close(grad(gated_delta._inverse_unit_lower_saved), want, 1e-5)
+
+
+def test_the_kernels_are_chosen_from_the_shapes_alone():
+    """The published heads take the kernels, the tests' model the scan; the
+    traced program says which (``pallas_call`` forward, and forward with
+    residuals + backward under ``grad``)."""
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "qwen3_next_80b_a3b.json")) as f:
+        published = json.load(f)["factory_kwargs"]
+    cfg = qn.qwen3_next(**published).cfg
+    assert takes_kernel(cfg.linear_key_head_dim, cfg.linear_value_head_dim,
+                        cfg.linear_chunk_size)
+    assert not takes_kernel(SMALL["linear_key_head_dim"],
+                            SMALL["linear_value_head_dim"],
+                            SMALL["linear_chunk_size"])
+
+    def calls(dk, dv, chunk):
+        args = _delta_inputs(2 * chunk, heads=2, dk=dk, dv=dv, batch=1)
+        rule = partial(gated_delta_rule, chunk=chunk)
+        loss = lambda *a: jnp.sum(rule(*a))
+        return (str(jax.make_jaxpr(rule)(*args)).count("pallas_call"),
+                str(jax.make_jaxpr(jax.grad(loss))(*args)).count(
+                    "pallas_call"))
+
+    assert calls(cfg.linear_key_head_dim, cfg.linear_value_head_dim,
+                 cfg.linear_chunk_size) == (1, 2)
+    assert calls(SMALL["linear_key_head_dim"], SMALL["linear_value_head_dim"],
+                 SMALL["linear_chunk_size"]) == (0, 0)
+    assert calls(128, 64, 64) == calls(96, 128, 64) == (0, 0)
 
 
 # --- (d) the shares add up -------------------------------------------------
