@@ -13,12 +13,16 @@ weights from a seed:
 - ``train_transformer``: a FedAvg round of the d512 x 4 transformer LM at
   T=512, once with dense attention and once with the pallas flash kernels;
 - ``kernels``: flash attention forward + all three gradients at T=2048 for
-  d_head 64 and 128, the gated delta rule's hand-over kernels with all five
+  d_head 64 and 128 and at Granite 4.0-H's 32 heads of 64 with its softmax
+  scale 1/64, Mamba-2's chunked scan at Granite's 64 heads of 64 x 128 with
+  all five gradients (XLA, no Mosaic call), the gated delta rule's hand-over
+  kernels with all five
   gradients at Qwen3-Next's head size (128 x 128, chunk 64), and the fused
   GroupNorm at ResNet-56's shapes, under a vmap over clients, against
   float32 ``jax.numpy`` references; the ``GatedDeltaNet`` layer at the
   published heads (16 key, 32 value) is lowered and its Mosaic calls counted;
-- ``adapter_round``: a FedAdapter round over the frozen d512 x 4 base;
+- ``adapter_round``: a FedAdapter round over the frozen d512 x 4 base, an
+  operand of the round's program (``base_bytes_operand``);
 - ``serve``: 64 requests through ``ServeManager`` (batched multi-adapter
   prefill + KV-cached decode), one row checked against the B=1 path;
 - ``timing_facts``: does ``block_until_ready`` wait, does the profiler work;
@@ -94,6 +98,8 @@ class Sizes:
     kernel_t: int
     kernel_heads: tuple      # ((H, d_head), ...)
     gdn_heads: tuple         # (key heads, value heads, d_head)
+    granite_attn: tuple      # (query heads, d_head, softmax scale)
+    ssd_heads: tuple         # (heads, d_head, d_state, chunk)
     gn_shapes: tuple         # ((height == width, channels), ...)
     gn_batch: int
     serve_seq: int
@@ -109,6 +115,7 @@ REAL = Sizes(
     vocab=10004, d_model=512, n_heads=8, n_layers=4, seq_len=512,
     lm_clients=16, lm_per_client=32, lm_batch=8, lm_cohort=8,
     kernel_t=2048, kernel_heads=((8, 64), (4, 128)), gdn_heads=(16, 32, 128),
+    granite_attn=(32, 64, 0.015625), ssd_heads=(64, 64, 128, 256),
     gn_shapes=((32, 16), (8, 256)), gn_batch=32,
     serve_seq=128, serve_batch=32, serve_new=16, serve_requests=64,
     chain_dim=4096, chain_s=0.5)
@@ -117,6 +124,7 @@ TOY = Sizes(
     vocab=64, d_model=32, n_heads=2, n_layers=1, seq_len=32,
     lm_clients=4, lm_per_client=4, lm_batch=2, lm_cohort=2,
     kernel_t=128, kernel_heads=((2, 16), (1, 32)), gdn_heads=(1, 2, 128),
+    granite_attn=(2, 16, 0.0625), ssd_heads=(2, 16, 16, 32),
     gn_shapes=((8, 16), (4, 32)), gn_batch=4,
     serve_seq=16, serve_batch=4, serve_new=3, serve_requests=8,
     chain_dim=256, chain_s=0.05)
@@ -376,10 +384,11 @@ def phase_kernels(ctx: Ctx, out: dict) -> None:
         with jax.default_matmul_precision("highest"):
             return jax.jit(with_grads(f))(cot.astype(jnp.float32), *up)
 
-    def compare(name, kernel, ref_fn, cot, *a):
+    def compare(name, kernel, ref_fn, cot, *a, mosaic: bool = True):
         sub = out[name] = {}
         fn = jax.jit(with_grads(kernel))
-        sub["mosaic_calls"] = mosaic_calls(ctx, fn.lower(cot, *a), name)
+        if mosaic:      # an XLA-only op (the state-space scan) has none
+            sub["mosaic_calls"] = mosaic_calls(ctx, fn.lower(cot, *a), name)
         sub["compile_s"], got = timed(lambda: fn(cot, *a))
         sub["run_s"], got = timed_steady(lambda: fn(cot, *a))
         check_on_device(name, got, ctx.platform)
@@ -402,6 +411,38 @@ def phase_kernels(ctx: Ctx, out: dict) -> None:
             jax.vmap(partial(flash_attention, causal=True)),
             jax.vmap(partial(reference_attention, causal=True)),
             do, q, k, v)       # rel_err order: o, dq, dk, dv
+
+    # Granite 4.0-H's attention core: head 64, the model's own scale (1/64,
+    # not 1/8); scaling q by scale * sqrt(d) gives the reference that scale
+    h, d, scale = s.granite_attn
+    keys = jax.random.split(jax.random.PRNGKey(h * 1000 + d + 1), 4)
+    q, k, v, do = (jax.random.normal(
+        kk, (n_clients, 1, s.kernel_t, h, d), jnp.bfloat16) for kk in keys)
+    compare(
+        f"flash_h{h}_d{d}_scaled",
+        jax.vmap(partial(flash_attention, causal=True, scale=scale)),
+        jax.vmap(lambda q, k, v: reference_attention(
+            q * (scale * d ** 0.5), k, v, causal=True)),
+        do, q, k, v)
+
+    # Mamba-2's chunked scan at Granite's heads against the recurrence
+    from fedml_tpu.ops.ssd import ssd_recurrence, ssd_scan
+
+    h, p, n, chunk = s.ssd_heads
+    keys = jax.random.split(jax.random.PRNGKey(h + p + n), 6)
+    # 1,024 tokens (Granite's): the recurrence's backward keeps a state a token
+    lead = (n_clients, 1, min(s.kernel_t, 1024))
+    compare(
+        f"ssd_h{h}_p{p}_n{n}",
+        jax.vmap(partial(ssd_scan, chunk=chunk)), jax.vmap(ssd_recurrence),
+        jax.random.normal(keys[0], lead + (h, p), jnp.bfloat16),
+        jax.random.normal(keys[1], lead + (h, p), jnp.bfloat16),
+        jax.nn.softplus(jax.random.normal(keys[2], lead + (h,)) - 3.0),
+        -jnp.exp(jax.random.uniform(keys[3], (n_clients, h), maxval=2.7)),
+        (jax.random.normal(keys[4], lead + (1, n)) * n ** -0.5).astype(
+            jnp.bfloat16),
+        jax.random.normal(keys[5], lead + (1, n), jnp.bfloat16),
+        mosaic=False)   # rel_err order: y, dx, ddt, da, db, dc
 
     from fedml_tpu.models.qwen3_next import GatedDeltaNet, Qwen3NextShapes
     from fedml_tpu.ops.gated_delta import (gated_delta_rule,

@@ -3,10 +3,12 @@ transformer with low-rank (LoRA-style) adapters.
 
 The cross-device LLM scenario the reference predates (ROADMAP item 3;
 FedNLP arXiv:2104.08815, low-rank updates arXiv:2108.06098): the base
-transformer is FROZEN — initialized once, device-resident once, fp32
-bitwise-unchanged across rounds (test-pinned) — and the federated net IS
-the adapter tree. Every layer of the existing machinery then applies
-unchanged to a model that is smaller by the rank ratio:
+transformer is FROZEN — initialized once, device-resident once, bitwise-
+unchanged across rounds (test-pinned), the first OPERAND of every program
+this class jits (never a constant of one, never donated, never copied per
+client) — and the federated net IS the adapter tree. Every layer of the
+existing machinery then applies unchanged to a model that is smaller by
+the rank ratio:
 
 - the jitted client step trains only the adapters (the optimizer inits
   on the adapter tree; gradients never materialize base-param updates),
@@ -51,8 +53,9 @@ class FedAdapterAPI(FedAvgAPI):
     "transformer_lm", adapter_rank=r, adapter_scope=...)``); the
     constructor refuses dense models loudly instead of silently training
     the dense arm. ``self.net`` is the adapter tree; ``self.base`` the
-    frozen base params (never trained, never uploaded, never donated —
-    jit captures it once as device constants).
+    frozen base params (never trained, never uploaded, never donated):
+    ``_jit`` hands it to every program as its first operand, so the vmap
+    over clients sees it unbatched and a 6.4 GB base costs its bytes once.
 
     Rides fused / windowed / on-device execution day one via
     the derived carry capability record ("round" protocol, no carry).
@@ -78,18 +81,13 @@ class FedAdapterAPI(FedAvgAPI):
                 "FedAdapter net is the ADAPTER tree while the compute "
                 "runs through the merged full model — the lane-fill "
                 "twin cannot apply; run the logical layout")
-        if getattr(cfg, "client_step_dtype", "fp32") not in ("fp32", ""):
-            raise NotImplementedError(
-                "cfg.client_step_dtype clones the model handed to "
-                "_build_local_train, which for FedAdapter is the merged "
-                "frozen-base apply, not a flax module — build the model "
-                "with dtype='bf16' instead (the adapter tree stays fp32)")
         if mesh is not None:
             raise NotImplementedError(
-                "FedAdapterAPI keeps the frozen base as a jit-captured "
-                "constant, which the client-mesh shard_map round does "
-                "not thread; run the single-device vmap simulator or "
-                "the message-passing tiers (cfg.adapter_rank there)")
+                "FedAdapterAPI hands the frozen base to its programs as "
+                "one device's operand, which the client-mesh shard_map "
+                "round does not replicate; run the single-device vmap "
+                "simulator or the message-passing tiers "
+                "(cfg.adapter_rank there)")
         if not 0.0 <= personal_interp <= 1.0:
             raise ValueError(
                 f"personal_interp must be in [0, 1], got {personal_interp}")
@@ -100,8 +98,15 @@ class FedAdapterAPI(FedAvgAPI):
         super().__init__(model, train_fed, test_global, cfg, mesh=mesh,
                          loss_fn=loss_fn, pad_id=pad_id, nan_guard=nan_guard)
         #: The frozen base params — everything the clients never train.
-        #: Pinned fp32-bitwise-invariant across rounds by tests.
+        #: Pinned bitwise-invariant across rounds by tests.
         self.base = self._adapter_holder["base"]
+        from fedml_tpu.models.adapter import param_count
+        from fedml_tpu.obs.registry import MetricsRegistry, payload_nbytes
+
+        self._adapter_bytes = payload_nbytes(self.net.params)
+        reg = self._adapter_registry = MetricsRegistry()
+        reg.gauge("base_bytes_operand").set(payload_nbytes(self.base))
+        reg.gauge("adapter_params").set(param_count(self.net.params))
         self.personal_interp = float(personal_interp)
         self._personal_spill_dir = personal_spill_dir
         self._personal_store = None
@@ -114,21 +119,46 @@ class FedAdapterAPI(FedAvgAPI):
         return adapter_model_fns(model, holder=self._adapter_holder,
                                  base_params=self._base_params)
 
+    def _jit(self, fn, donate_argnums=()):
+        return _BaseOperand(self.fns, fn, donate_argnums)
+
+    def _emit_reduce_obs(self, n_rounds: int = 1) -> None:
+        """Besides the base class's gauges, what the round just folded: an
+        adapter tree for every client of its cohort whose weight was
+        positive (a padded slot and a client with no samples upload
+        nothing), by the round's own memoized cohort. Host-loop rounds
+        only, like ``dispatch_profile``: a windowed span (``n_rounds`` > 1)
+        and the on-device scan are not counted. A client that ``nan_guard``
+        drops on the device still is: the host does not see it without a
+        fence."""
+        super()._emit_reduce_obs(n_rounds)
+        if n_rounds != 1:
+            return
+        _, idx, wmask = self._sample_cache
+        uploads = np.count_nonzero(
+            self._host_counts()[np.asarray(idx)] * np.asarray(wmask))
+        self._adapter_registry.counter("adapter_bytes_folded").inc(
+            int(uploads) * self._adapter_bytes)
+
     def _on_client_lr_change(self):
         self._personal_train_jit = None  # bakes in the live optimizer/lr
 
     # -- introspection ----------------------------------------------------
     def adapter_profile(self) -> Dict[str, float]:
         """The rank-ratio story in numbers: trainable adapter params vs
-        the frozen base, and the wire-relevant ratio (uploads carry the
-        adapter tree only)."""
+        the frozen base, the wire-relevant ratio (uploads carry the
+        adapter tree only), and the registry's running totals:
+        ``base_bytes_operand`` (what every program is handed, never
+        copied) and ``adapter_bytes_folded`` (what the host-loop rounds'
+        clients would have uploaded: the clients whose weight was positive
+        x the adapter tree's bytes)."""
         from fedml_tpu.models.adapter import param_count
 
         a = param_count(self.net.params)
         b = param_count(self.base)
-        return {"adapter_params": a, "base_params": b,
-                "total_params": a + b,
-                "adapter_ratio": a / max(a + b, 1)}
+        return {"base_params": b, "total_params": a + b,
+                "adapter_ratio": a / max(a + b, 1),
+                **self._adapter_registry.snapshot()}
 
     # -- personalization (ditto-style interpolation + local finetune) -----
     def personal_store(self):
@@ -152,7 +182,7 @@ class FedAdapterAPI(FedAvgAPI):
             def rounds(nets, x, y, mask, rngs):
                 return jax.vmap(local_train)(nets, x, y, mask, rngs)
 
-            fn = self._personal_train_jit = jax.jit(rounds)
+            fn = self._personal_train_jit = self._jit(rounds)
         return fn
 
     def personalize_cohort(self, clients, seed: int = 0) -> np.ndarray:
@@ -188,7 +218,7 @@ class FedAdapterAPI(FedAvgAPI):
     def _personal_eval_fn(self):
         fn = self._personal_eval_jit
         if fn is None:
-            fn = self._personal_eval_jit = jax.jit(jax.vmap(
+            fn = self._personal_eval_jit = self._jit(jax.vmap(
                 lambda net, x, y, mask: self.eval_fn(net, x, y, mask)))
         return fn
 
@@ -247,6 +277,23 @@ class FedAdapterAPI(FedAvgAPI):
         super().load_checkpoint_extra_state(extra)
         if extra and "personal_vecs" in extra:
             self.personal_store().load_state_dict(extra)
+
+
+class _BaseOperand:
+    """``jax.jit`` of a program of the adapter round, with the frozen base
+    bound as its first operand (``AdapterFns.bind``). Called inside another
+    such program, it hands on the operand that one was given."""
+
+    def __init__(self, fns, fn, donate_argnums=()):
+        self._base = fns.base
+        self._jitted = jax.jit(
+            fns.bind(fn), donate_argnums=tuple(i + 1 for i in donate_argnums))
+
+    def __call__(self, *args):
+        return self._jitted(self._base(), *args)
+
+    def lower(self, *args):
+        return self._jitted.lower(self._base(), *args)
 
 
 def _gather_shards(fed, idx):

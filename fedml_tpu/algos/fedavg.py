@@ -331,13 +331,13 @@ class FedAvgAPI(FederatedLoop):
             # one the trainer applies, so it is the one cloned to bf16.
             base = (self._layout.physical_model if self._layout is not None
                     else model)
-            self._step_fns = model_fns(
+            self._step_fns = self._model_fns(
                 step_dtype_model(base, jnp.bfloat16))
             self._step_dtype = jnp.bfloat16
         self._client_lr = None
         self._fused_step_fn = None
         self.set_client_lr(cfg.lr)
-        self.eval_fn = jax.jit(make_eval_fn(self.fns.apply, loss_fn, pad_id=pad_id))
+        self.eval_fn = self._jit(make_eval_fn(self.fns.apply, loss_fn, pad_id=pad_id))
 
         rng = jax.random.PRNGKey(cfg.seed)
         self.rng, init_rng = jax.random.split(rng)
@@ -419,8 +419,8 @@ class FedAvgAPI(FederatedLoop):
                 w = sub.counts.astype(jnp.float32) * wmask
                 return round_fn(net, sub.x, sub.y, sub.mask, w, w, rng)
 
-            self.round_fn_fused = jax.jit(fused)
-        self.round_fn = jax.jit(round_fn)
+            self.round_fn_fused = self._jit(fused)
+        self.round_fn = self._jit(round_fn)
 
     # --- hooks subclasses override (FedOpt/FedProx/...) -------------------
     #: Set True by the one subclass that READS cfg.adapter_rank
@@ -832,7 +832,7 @@ class FedAvgAPI(FederatedLoop):
                 sub = gather_clients(fed, idx)
                 return per_client(net, sub.x, sub.y, sub.mask)["loss"]
 
-            fn = jax.jit(losses_fn)
+            fn = self._jit(losses_fn)
             self._cohort_losses_jit = fn
         return np.asarray(fn(self._eval_net(), self.train_fed,
                              jnp.asarray(idx)))
@@ -1041,7 +1041,7 @@ class FedAvgAPI(FederatedLoop):
             # (obs.sanitizer.donation_audit pins the 1-copy steady
             # state). For custom-protocol carries this also donates the
             # client-state STACK — one live copy instead of two.
-            pre = jax.jit(step, donate_argnums=(0, 1))
+            pre = self._jit(step, donate_argnums=(0, 1))
             gather = None
             take = self._cohort_gather
             if take is not None and self.window_protocol == "round":
@@ -1050,7 +1050,7 @@ class FedAvgAPI(FederatedLoop):
                     w = sub.counts.astype(jnp.float32) * wmask
                     return step(net, extra, sub.x, sub.y, sub.mask, w, key)
 
-                gather = jax.jit(gather_step, donate_argnums=(0, 1))
+                gather = self._jit(gather_step, donate_argnums=(0, 1))
             fn = self._fused_step_fn = (pre, gather)
         return fn
 
@@ -1151,8 +1151,8 @@ class FedAvgAPI(FederatedLoop):
             init, step, finish = make_size_group_round(
                 self.local_train, self._nan_guard,
                 self._window_server_update())
-            fns = (jax.jit(init), jax.jit(step, donate_argnums=(1,)),
-                   jax.jit(finish, donate_argnums=(0, 1)))
+            fns = (self._jit(init), self._jit(step, donate_argnums=(1,)),
+                   self._jit(finish, donate_argnums=(0, 1)))
             init, step, finish = fns
             store, group = self.train_fed, self._size_group()
             smallest = np.argsort(store.counts, kind="stable")[:group]
@@ -1394,7 +1394,7 @@ class FedAvgAPI(FederatedLoop):
             # replaced by the scan's outputs, so XLA reuses the old
             # buffers (the driver rebinds/commits before anything reads
             # the donated originals again).
-            fn = jax.jit(self._build_window_scan(), donate_argnums=(0, 1))
+            fn = self._jit(self._build_window_scan(), donate_argnums=(0, 1))
             self._window_scan_fn = fn
         return fn
 
@@ -1670,7 +1670,7 @@ class FedAvgAPI(FederatedLoop):
             # replaces self.net / commits the carry from the scan
             # result, so XLA may reuse the old buffers instead of
             # holding both copies live.
-            scan_fn = jax.jit(scan_fn, donate_argnums=(0, 1))
+            scan_fn = self._jit(scan_fn, donate_argnums=(0, 1))
             self._rounds_scan_fn = scan_fn
 
         # Reproduce the host loop's per-round rng chain exactly.

@@ -138,6 +138,11 @@ class FederatedLoop(ExcludedScanTiers):
             *aux
         ))
 
+    def _jit(self, fn, **kwargs):
+        """``jax.jit`` for the loop's own programs. FedAdapterAPI binds its
+        frozen base as every program's first operand here."""
+        return jax.jit(fn, **kwargs)
+
     def _unpack_round(self, out):
         """Rounds built with ``with_client_losses`` return a third,
         per-client-loss output (oort's in-round utility observable);
@@ -157,7 +162,7 @@ class FederatedLoop(ExcludedScanTiers):
         of the same kernel)."""
         fn = getattr(self, "_clients_eval_fn", None)
         if fn is None:
-            fn = jax.jit(jax.vmap(
+            fn = self._jit(jax.vmap(
                 lambda n, x, y, mask: self.eval_fn(n, x, y, mask),
                 in_axes=(None, 0, 0, 0)))
             self._clients_eval_fn = fn

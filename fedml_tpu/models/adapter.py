@@ -17,8 +17,9 @@ federated machinery that should only ever see the adapter tree:
   reassemble it, a lossless bijection (``merge(split(p)) == p``, tested).
 - :func:`adapter_model_fns` — a drop-in :class:`~fedml_tpu.trainer.
   local.ModelFns` twin whose ``init`` returns the ADAPTER tree as the
-  trainable net (the frozen base is captured once on device) and whose
-  ``apply`` merges base + adapters per call. Everything downstream —
+  trainable net (the frozen base is held once on device) and whose
+  ``apply`` merges base + adapters per call; ``bind`` makes the base an
+  OPERAND of a program instead of its constant. Everything downstream —
   the jitted client step, aggregation, codecs (``tree_spec`` of the
   adapter net), checkpoints, the wire — operates on the adapter tree
   without knowing adapters exist.
@@ -37,6 +38,12 @@ import threading
 from typing import Any, Callable, NamedTuple, Optional
 
 import numpy as np
+
+#: The largest frozen base a program may hold as constants: ``apply``
+#: outside ``bind`` refuses a larger one, so a jit that misses the operand
+#: fails where it is built. The serving plane's and the message-passing
+#: tiers' GPT-2-sized bases (under 0.3 GB a program) stay under it.
+BAKED_BASE_LIMIT = 1 << 30
 
 #: Leaf-name prefix marking adapter params (models/transformer._lora_delta
 #: names every injected pair ``lora_<site>_a`` / ``lora_<site>_b``).
@@ -95,11 +102,17 @@ class AdapterFns(NamedTuple):
     """:class:`~fedml_tpu.trainer.local.ModelFns`-compatible functional
     interface over the ADAPTER tree, plus the holder dict ``init``
     populates with the frozen base (``holder["base"]``) — exposed so
-    drills can pin the base's bitwise invariance."""
+    drills can pin the base's bitwise invariance. ``bind(fn)`` is
+    ``fn'(base, *args)``: while ``fn`` runs (is traced), ``apply`` merges
+    the adapters with THAT base, so a jitted ``fn'`` takes the frozen tree
+    as an operand (3.19 G parameters cannot be a program's constants);
+    ``base()`` is the base ``apply`` would merge right now."""
 
     init: Callable
     apply: Callable
     holder: dict
+    bind: Callable
+    base: Callable
 
 
 def adapter_model_fns(model, holder: Optional[dict] = None,
@@ -107,15 +120,22 @@ def adapter_model_fns(model, holder: Optional[dict] = None,
     """Build the adapter-level ModelFns for a model injected with
     ``lora_*`` params: ``init(rng, x)`` runs the FULL deterministic init,
     splits off the frozen base into ``holder["base"]`` (device-resident
-    once — jit captures it as a constant, it is never re-uploaded or
+    once, in the dtypes the model created it in, never re-uploaded or
     donated), and returns a NetState whose ``params`` are the adapter
-    tree alone; ``apply`` merges base + adapters per call.
+    tree alone; ``apply`` merges base + adapters per call. Inside
+    ``bind(fn)`` the base is the operand ``fn'`` was called with
+    (``FedAdapterAPI`` jits every program so); outside it, ``apply`` reads
+    ``holder["base"]`` at trace time and jit bakes it into the program
+    (the serving plane's and the message-passing tiers' small bases; one
+    above ``BAKED_BASE_LIMIT`` is refused there, so a jit that misses the
+    operand fails where it is built).
 
     ``base_params`` swaps a PRETRAINED base in for the fresh init's (the
     finetuning story: a dense-trained checkpoint's params — adapter
     leaves absent since injection leaves base paths unchanged — become
     the frozen base while the adapters still start at the exact-identity
-    LoRA init). Structure must match the split base or ``init`` raises.
+    LoRA init; only they are materialised then). Structure must match the
+    split base or ``init`` raises.
 
     Raises when the model has NO adapter params (an adapter config
     against a dense model must refuse, not silently train the dense arm)
@@ -129,7 +149,10 @@ def adapter_model_fns(model, holder: Optional[dict] = None,
     holder = {} if holder is None else holder
 
     def init(rng, sample_x) -> "NetState":
-        full = full_fns.init(rng, sample_x)
+        if base_params is None:
+            full = full_fns.init(rng, sample_x)
+        else:   # shapes only: the base handed in is the one that is held
+            full = jax.eval_shape(full_fns.init, rng, sample_x)
         base, adapters = split_frozen(full.params)
         if not jax.tree.leaves(adapters):
             raise ValueError(
@@ -152,17 +175,47 @@ def adapter_model_fns(model, holder: Optional[dict] = None,
                     f"structure: expected {want}, got {got} — pass the "
                     "dense checkpoint's params (adapter leaves excluded)")
             base = jax.tree.map(jnp.asarray, base_params)
+            # One program whose only results are the adapters, the full
+            # init's bit for bit: the compiler drops the fresh base's draws,
+            # so a 6.4 GB base is never held beside a second one.
+            adapters = jax.jit(lambda r, x: split_frozen(
+                full_fns.init(r, x).params)[1])(rng, sample_x)
         holder["base"] = base
         return NetState(adapters, full.model_state)
 
+    def base():
+        return holder.get("operand", holder.get("base"))
+
+    def bind(fn):
+        def bound(operand, *args):
+            outer = holder.get("operand")
+            holder["operand"] = operand
+            try:
+                return fn(*args)
+            finally:
+                if outer is None:
+                    del holder["operand"]
+                else:
+                    holder["operand"] = outer
+
+        return bound
+
     def apply(net: "NetState", x, train=False, rng=None):
-        # The base lookup happens at TRACE time: jit captures the frozen
-        # tree as on-device constants shared across calls.
-        full = NetState(merge_params(holder["base"], net.params),
-                        net.model_state)
+        frozen = base()
+        if "operand" not in holder:
+            baked = sum(a.nbytes for a in jax.tree.leaves(frozen))
+            if baked > BAKED_BASE_LIMIT:
+                raise ValueError(
+                    f"outside bind() a jitted program would hold the frozen "
+                    f"base as {baked / 1e9:.2f} GB of constants (the limit "
+                    f"is {BAKED_BASE_LIMIT / 1e9:.2f} GB): jit it as "
+                    "jax.jit(fns.bind(fn)) and call it with fns.base() first "
+                    "(FedAdapterAPI._jit)")
+        full = NetState(merge_params(frozen, net.params), net.model_state)
         return full_fns.apply(full, x, train=train, rng=rng)
 
-    return AdapterFns(init=init, apply=apply, holder=holder)
+    return AdapterFns(init=init, apply=apply, holder=holder, bind=bind,
+                      base=base)
 
 
 class PersonalAdapterStore:

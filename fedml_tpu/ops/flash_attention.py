@@ -283,25 +283,22 @@ def _bwd(q3, k3, v3, o3, lse, do3, scale, causal, blk_q, blk_k):
 # Public API with custom VJP
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q3, k3, v3, causal, blocks):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q3, k3, v3, causal, blocks, scale):
     blk_q, blk_k = blocks[:2]
-    scale = 1.0 / (q3.shape[-1] ** 0.5)
     o, _ = _fwd(q3, k3, v3, scale, causal, blk_q, blk_k)
     return o
 
 
-def _flash_fwd(q3, k3, v3, causal, blocks):
+def _flash_fwd(q3, k3, v3, causal, blocks, scale):
     blk_q, blk_k = blocks[:2]
-    scale = 1.0 / (q3.shape[-1] ** 0.5)
     o, lse = _fwd(q3, k3, v3, scale, causal, blk_q, blk_k)
     return o, (q3, k3, v3, o, lse)
 
 
-def _flash_bwd(causal, blocks, res, do3):
+def _flash_bwd(causal, blocks, scale, res, do3):
     q3, k3, v3, o3, lse = res
     bwd_blk_q, bwd_blk_k = blocks[2:]
-    scale = 1.0 / (q3.shape[-1] ** 0.5)
     return _bwd(q3, k3, v3, o3, lse, do3, scale, causal,
                 bwd_blk_q, bwd_blk_k)
 
@@ -312,8 +309,12 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(q, k, v, causal: bool = False, block_q: int | None = None,
                     block_k: int | None = None,
                     bwd_block_q: int | None = None,
-                    bwd_block_k: int | None = None):
+                    bwd_block_k: int | None = None,
+                    scale: float | None = None):
     """Fused attention: q/k/v [B, T, H, D] → o [B, T, H, D].
+
+    ``scale`` multiplies ``q k^T`` before the softmax: ``1/sqrt(D)`` unless
+    the caller's model states another (Granite's ``attention_multiplier``).
 
     T must be a multiple of the (clamped) block sizes; pad upstream if not.
     Differentiable (custom VJP, FlashAttention-2-style backward).
@@ -350,5 +351,8 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int | None = None,
     def to3(x):
         return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
 
-    o3 = _flash(to3(q), to3(k), to3(v), causal, (blk_q, blk_k, bwd_q, bwd_k))
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
+    o3 = _flash(to3(q), to3(k), to3(v), causal, (blk_q, blk_k, bwd_q, bwd_k),
+                float(scale))
     return o3.reshape(b, h, t, d).transpose(0, 2, 1, 3)

@@ -1,7 +1,8 @@
 """The benchmark files of the Qwen3-Next cell and of the LDA cell (PR 28):
 the configuration against the catalog entry, the model it builds and the
 work counts; the two generators; the scope reducer on a hand-made trace; the
-nine readers against the manifest. CPU; nothing here reads a device number.
+nine readers against the manifest. The same of the Granite 4.0-H adapter cell
+(PR 32), at the end. CPU; nothing here reads a device number.
 """
 
 import importlib.util
@@ -347,3 +348,274 @@ def test_every_limit_lies_between_its_two_readings():
         kind for kind, _ in runner._KINDS} | {"loss"}
     for kind, t in runner.TOLERANCES.items():
         assert 2 * t["program"] <= t["limit"] <= t["control"] / 2, kind
+
+
+# --- Granite 4.0-H in the adapter round (PR 32) ------------------------------
+
+GRANITE, GRANITE_CELL = "granite_4_0_h_micro", "granite4h_lora_c4_s1k"
+GRANITE_READERS = [
+    "device_ms.ssm.round", "device_ms.mlp.round", "device_ms.lora.round",
+    "ssm_scan_roofline_pct", "adapter_upload_mb.round"]
+SHARED_READERS = [
+    "device_ms.attn.round", "device_ms.head.round", "attn_core_roofline_pct"]
+
+
+@pytest.fixture(scope="module")
+def granite():
+    return _json("benchmark", "configs", GRANITE + ".json")
+
+
+@pytest.fixture(scope="module")
+def lora_mix():
+    return _json("benchmark", "traffic", "resident_silos_c4_s1k_lora.json")
+
+
+def test_granite_holds_every_published_number_uncut(granite):
+    """The catalog entry's ``config`` key for key, equal: ``reduced`` is
+    empty, in the file and in the manifest; the factory is handed the same
+    dictionary plus the adapters and the compute choices."""
+    if not os.path.exists(CATALOG):
+        pytest.skip("the model-configs catalog is not on this machine")
+    with open(CATALOG) as f:
+        entry, = [row for row in map(json.loads, f)
+                  if row["source_url"] == granite["source"]]
+    for key, value in entry["config"].items():
+        assert granite[key] == value, key
+        assert granite["factory_kwargs"][key] == value, key
+    assert granite["reduced"] == []
+    manifest = _json("BENCHMARK.json")
+    listed, = [c for c in manifest["configs"] if c["name"] == GRANITE]
+    assert listed["reduced"] == [] and listed["source"] == granite["source"]
+    extra = set(granite["factory_kwargs"]) - set(entry["config"])
+    assert extra == {"adapter_rank", "adapter_alpha", "adapter_b_std",
+                     "attention", "base_dtype"}
+    assert granite["adapter"]["rank"] == granite["factory_kwargs"][
+        "adapter_rank"] == 16
+    assert set(granite["assumed"]) >= {"A_log", "dt_bias", "D", "adapter",
+                                       "adapter_b_std"}
+    from fedml_tpu.algos.config import FedConfig
+
+    assert all(hasattr(FedConfig(), k) for k in granite["fed_config"])
+
+
+def test_granite_parameters_and_flops_recounted(granite, lora_mix):
+    """The file's counts against the model's own trees (shapes only: no
+    3 G parameters are made) and against ``counts/granite_hybrid.py``; the
+    frozen FLOPs against the derivation, part by part and by hand."""
+    from fedml_tpu.models.adapter import split_frozen
+    from fedml_tpu.models.granite_hybrid import granite_hybrid
+
+    kwargs = granite["factory_kwargs"]
+    model = granite_hybrid(**kwargs)
+    ids = jax.ShapeDtypeStruct((1, 16), np.int32)
+    shapes = jax.eval_shape(
+        lambda i: model.init({"params": jax.random.PRNGKey(0)}, i), ids)
+    base, adapters = split_frozen(shapes["params"])
+
+    def count(tree):
+        return sum(int(np.prod(leaf.shape)) for leaf in jax.tree.leaves(tree))
+
+    assert {"base": count(base), "adapters": count(adapters)} \
+        == granite["parameters"] == {"base": 3191396096,
+                                     "adapters": 28823552}
+    assert {str(leaf.dtype) for leaf in jax.tree.leaves(base)} == {"bfloat16"}
+    counts = _load(granite["counts"])
+    assert counts.parameters(kwargs) == granite["parameters"]
+    assert (counts.train_flops_per_sequence(granite, lora_mix)
+            == granite["train_flops_per_sample"])
+    per_token = counts.forward_flops_per_token(kwargs, 1024)
+    assert {k: round(v) for k, v in per_token.items()} \
+        == granite["forward_flops_per_token"]
+    # by hand: every frozen matrix but the embedding's rows is a product
+    matrices = 36 * (2048 * 8512 + 4096 * 2048) + 4 * (
+        2 * 2048 * 2048 + 2 * 2048 * 512) + 40 * 3 * 2048 * 8192
+    assert per_token["matrices"] == 2 * matrices
+    assert per_token["head"] == 2 * 2048 * 100352
+    assert per_token["lora"] == 2 * granite["parameters"]["adapters"]
+    ops, moved = counts.ssm_scan_forward(kwargs, 1)
+    assert ops == 64 * (5 * 64 * 128 + 64)
+    assert moved == (2 * 64 * 64 + 2 * 128) * 2 + 64 * 4
+    ops, _ = counts.attn_core_forward(kwargs, 4)
+    assert ops == 32 * 4 * 64 * (4 * 5 // 2)
+    assert granite["train_flops_per_sample"] == round(1024 * (
+        2 * (per_token["matrices"] + per_token["conv"] + per_token["head"])
+        + 3 * (per_token["ssm_scan"] + per_token["attn_core"]
+               + per_token["lora"])))
+    assert counts.steps_per_round(lora_mix) == 8
+    peaks = _json("benchmark", "peaks.json")["TPU v5 lite"]
+    for kernel in ("ssm_scan", "attn_core"):
+        assert counts.roofline_ms_per_round(kernel, granite, lora_mix,
+                                            peaks) > 0
+
+
+def test_granite_dryrun_sizes_are_the_cpu_tests_sizes(granite):
+    import test_granite_hybrid
+
+    assert granite["dryrun"]["factory_kwargs"] == {
+        **test_granite_hybrid.CFG, "attention": "flash",
+        "base_dtype": "bfloat16"}
+    assert granite["dryrun"]["classes"] == 257
+
+
+def test_hybrid_reducer_books_the_low_rank_pairs_apart():
+    """``reduce_scopes_hybrid.py``: ``reduce_scopes``' walk with another
+    list. A pair's products inside a mixer are ``fed.model.lora``'s there
+    and the mixer's in the Qwen cell's reducer, which does not know them."""
+    rsh, rsc = _load("reduce_scopes_hybrid.py"), _load("reduce_scopes.py")
+    path = ("jit(f)/fed.local_train/transpose(jvp(fed.model.attn))/"
+            "fed.model.lora/dot_general")
+    assert rsh._reducer.scope_of([path]) == "fed.model.lora"
+    assert rsc.scope_of([path]) == "fed.model.attn"
+    assert rsh._reducer.scope_of(
+        ["x/fed.model.ssm/fed.model.ssm.scan/while/body/mul"]) \
+        == "fed.model.ssm.scan"
+    assert rsc.scope_of(["x/fed.model.ssm/fed.model.ssm.scan/mul"]) == ""
+    assert rsc.SCOPES[0] == "fed.model.gdn.scan"      # the other is untouched
+
+
+@pytest.mark.parametrize("name", GRANITE_READERS)
+def test_granite_reader_agrees_with_the_manifest(name, tmp_path, monkeypatch):
+    manifest = _json("BENCHMARK.json")
+    entry, = [m for m in manifest["per_layer"] if m["name"] == name]
+    reader = _load(f"layer_metrics/{name}.py")
+    assert {k: entry[k] for k in ("layer", "unit", "moves")} == reader.META
+    cells = [w["name"] for w in manifest["workloads"] if reader.applies(w)]
+    assert cells == entry["workloads"] == [GRANITE_CELL]
+    rs = (reader.rsh.rsc if hasattr(reader, "rsh") else reader.rsc).rs
+    monkeypatch.setattr(rs, "TRACE_DIR", str(tmp_path))
+    assert reader.read({"chips": 1, "rounds": 3,
+                        "device_kind": "TPU v5 lite"}) is None
+
+
+@pytest.mark.parametrize("name", SHARED_READERS)
+def test_one_partition_reads_the_granite_cell(name, granite):
+    """The accepted readers of the attention and head scopes read through
+    ``reduce_scopes.py``, which books a low-rank pair under the layer it
+    stands in: they keep their lists, and the cell's file names their
+    scopes apart (``scopes_unread``), so that every reader of the cell
+    reads ``reduce_scopes_hybrid.py``'s one partition."""
+    manifest = _json("BENCHMARK.json")
+    entry, = [m for m in manifest["per_layer"] if m["name"] == name]
+    assert entry["workloads"] == [CELL]
+    reader = _load(f"layer_metrics/{name}.py")
+    cell, = [w for w in manifest["workloads"] if w["name"] == GRANITE_CELL]
+    assert not reader.applies(cell)
+    assert reader.SCOPE in granite["scopes_unread"]
+    assert reader.SCOPE not in granite["scopes"]
+    for metric in manifest["per_layer"]:
+        if GRANITE_CELL in metric.get("workloads", []):
+            module = _load(f"layer_metrics/{metric['name']}.py")
+            assert not hasattr(module, "SCOPE") or hasattr(module, "rsh")
+
+
+@pytest.mark.parametrize("scope", [
+    "fed.model.attn.core", "fed.model.head", "fed.client_fold"])
+def test_the_two_reducers_agree_where_both_know_the_scope(scope, monkeypatch):
+    """``reduce_scopes_hybrid.py`` is a second copy of ``reduce_scopes.py``
+    with another list written over its private names: until scopes come
+    from the configuration (PERF.md section 7) this holds the copy to the
+    original. The names it overwrites exist; a scope both lists know, with
+    no low-rank pair inside, is booked alike by both and reads the same
+    time from the same reduction."""
+    rsh, rsc = _load("reduce_scopes_hybrid.py"), _load("reduce_scopes.py")
+    for private in ("SCOPES", "_SCOPE", "scope_of", "scope_ms", "traced",
+                    "roofline_pct"):
+        assert hasattr(rsc, private), private
+    assert scope in rsc.SCOPES and scope in rsh.SCOPES
+    assert rsh._reducer.SCOPES == rsh.SCOPES and rsc.SCOPES != rsh.SCOPES
+    paths = [f"jit(f)/fed.local_train/jvp({scope})/dot_general",
+             f"jit(f)/fed.local_train/transpose(jvp({scope}))/mul",
+             f"jit(f)/{scope}/while/body/add"]
+    for path in paths:
+        assert rsh._reducer.scope_of([path]) == rsc.scope_of([path]) == scope
+    reduction = {"rounds": 4, "device_ns_by_scope": {"": 5e6, scope: 12e6}}
+    monkeypatch.setattr(rsh._reducer, "traced", lambda: reduction)
+    monkeypatch.setattr(rsc, "traced", lambda: reduction)
+    assert rsh.scope_ms(scope) == rsc.scope_ms(scope) == pytest.approx(3.0)
+
+
+def test_granite_readers_on_a_reduction(granite, lora_mix, monkeypatch):
+    reduction = {"rounds": 4, "device_ns_by_scope": {
+        "": 5e6, "fed.model.ssm": 300e6, "fed.model.ssm.conv": 20e6,
+        "fed.model.ssm.scan": 400e6, "fed.model.mlp": 200e6,
+        "fed.model.lora": 40e6, "fed.model.attn.core": 8e6}}
+    summary = {"device_kind": "TPU v5 lite", "adapter_upload_mb_round": 461.2,
+               "counts": {"module": granite["counts"], "config": granite,
+                          "mix": lora_mix}}
+    rsh = _load("reduce_scopes_hybrid.py")
+    monkeypatch.setattr(rsh._reducer, "traced", lambda: reduction)
+    assert rsh.scope_ms("fed.model.ssm") == pytest.approx(180.0)
+    assert rsh.scope_ms("fed.model.lora") == pytest.approx(10.0)
+    counts = _load(granite["counts"])
+    least = counts.roofline_ms_per_round(
+        "ssm_scan", granite, lora_mix,
+        _json("benchmark", "peaks.json")["TPU v5 lite"])
+    share = rsh.roofline_pct(summary, "ssm_scan", "fed.model.ssm.scan")
+    assert share == pytest.approx(100.0 * least / 100.0) and 0 < share < 100
+    monkeypatch.setattr(rsh._reducer, "traced", lambda: {
+        "rounds": 4, "device_ns_by_scope": {"": 5e6}})
+    assert rsh.scope_ms("fed.model.ssm") is None    # a program with no scope
+    upload = _load("layer_metrics/adapter_upload_mb.round.py")
+    assert upload.read(summary) == 461.2 and upload.read({}) is None
+
+
+@pytest.mark.parametrize("stand_in, failing", [
+    (None, set()),
+    ("unchanged_state", {"ssm_a", "ssm_b", "attention_a", "attention_b",
+                         "mlp_a", "mlp_b"}),
+    ("last_batch_left_out", None),
+    ("reference_bits:4", None),
+])
+def test_the_adapter_cell_is_correct_and_its_stand_ins_are_not(
+        granite, lora_mix, stand_in, failing):
+    """``benchmark/run.py``'s own context and ``fed_adapter_lm_round`` at
+    the rehearsal's sizes on the CPU: the round passes every limit; a state
+    left unchanged reads 1 on every kind of pair; a client's last batch left
+    out and the reference with float8's 4-bit products each fail a limit."""
+    import argparse
+
+    run = _load("run.py")
+    manifest = run.load_manifest()
+    cell = run.by_name(manifest["workloads"], GRANITE_CELL, "workload")
+    args = argparse.Namespace(seed=3200000555, seconds=0.2, trace=0,
+                              dryrun_cpu=True)
+    mix = {**lora_mix, "stand_in": stand_in} if stand_in else lora_mix
+    ctx = run.Ctx(manifest, cell, granite, mix, args, "cpu")
+    runner = ctx.load_module(f"runners/{mix['runner']}.py")
+    result = runner.run(ctx)
+    summary = result["summary"]
+    errors = summary["reference_errors"]
+    over = {k for k, v in errors.items()
+            if v > runner.TOLERANCES[k]["limit"]}
+    assert result["correct"] == (stand_in is None), errors
+    if failing is None:
+        assert over, errors
+    else:
+        assert over == failing, errors
+    if stand_in == "unchanged_state":
+        assert all(errors[k] == pytest.approx(1.0) for k in failing)
+    assert summary["base_bytes_operand"] == 2 * summary["base_parameters"]
+    assert summary["adapter_upload_mb_round"] == pytest.approx(
+        2 * 4 * summary["adapter_parameters"] / 1e6)
+
+
+def test_every_adapter_limit_lies_between_its_two_readings():
+    runner = _load("runners/fed_adapter_lm_round.py")
+    assert set(runner.TOLERANCES) == {
+        f"{kind}_{half}" for kind, _ in runner._SITES
+        for half in "ab"} | {"loss"}
+    for kind, t in runner.TOLERANCES.items():
+        # the loss's two readings lie 3.3 times apart: it keeps twice the
+        # program's and has 1.5 times under the control's
+        least = 1.5 if kind == "loss" else 2
+        assert 2 * t["program"] <= t["limit"] <= t["control"] / least, kind
+    # the loss is held against the 4-bit control like the rest; what a
+    # planted fault reads is kept beside it and sets no limit
+    loss = runner.TOLERANCES["loss"]
+    assert loss["control"] < loss["fault"] and loss["limit"] < loss["control"]
+    assert [k for k, t in runner.TOLERANCES.items() if "fault" in t] == [
+        "loss"]
+    assert runner.kind_of("lora_in_proj_a") == "ssm_a"
+    assert runner.kind_of("lora_output_linear_b") == "mlp_b"
+    with pytest.raises(KeyError):
+        runner.kind_of("in_proj")

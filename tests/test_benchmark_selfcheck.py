@@ -9,7 +9,9 @@ a process of its own, as the driver starts them); (c) the plain references
 import nothing of the program they judge; (d) PERF.md names every cell and
 every per-layer metric of the manifest. A rehearsal's numbers are CPU numbers:
 only their presence and their units are asserted. ``qwen3next_c4_s4k`` is
-rehearsed by tests/test_benchmark_lm.py.
+rehearsed by tests/test_benchmark_lm.py; ``granite4h_lora_c4_s1k`` (PR 32,
+``runners/fed_adapter_lm_round.py``) here, traced, and there with its
+stand-ins.
 """
 
 import ast
@@ -85,8 +87,31 @@ def test_cell_rehearses_on_the_cpu(cell):
     assert all(v["value"] > 0 for v in line["metrics"].values())
 
 
+def test_the_adapter_cell_rehearses_on_the_cpu():
+    """``granite4h_lora_c4_s1k`` as the driver starts it, at the files'
+    dryrun sizes, traced: exit 0, ``correct`` (round 0 against the
+    reference, the base unchanged and one operand), the program counter's
+    metric on the line, and never a device number."""
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_PLATFORMS"] = "cpu"
+    done = subprocess.run(
+        [sys.executable, os.path.join("benchmark", "run.py"), "--workload",
+         "granite4h_lora_c4_s1k", "--seed", "3200000777", "--seconds", "2",
+         "--trace", "1", "--dryrun-cpu"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=900)
+    assert done.returncode == 0, done.stderr[-4000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert line["dryrun"] is True and line["correct"] is True
+    assert line["failed"] == 0 and line["attempted"] >= 1
+    assert line["device"]["platform"] == "cpu"
+    assert line["metrics"]["adapter_upload_mb.round"]["unit"] == "MB"
+    assert line["metrics"]["step_fill_pct"]["value"] == 100.0
+    assert not [k for k in line["metrics"] if k.startswith("device_")
+                or "roofline" in k or k.startswith("mfu")]
+
+
 @pytest.mark.parametrize("reference", [
-    "reference.py", "reference_qwen3_next.py"])
+    "reference.py", "reference_qwen3_next.py", "reference_granite_hybrid.py"])
 def test_reference_imports_nothing_of_the_program(reference):
     """The yardstick is independent of the code under test: by its syntax
     tree, no import of ``fedml_tpu`` (at any depth of the file), and no

@@ -580,12 +580,27 @@ def test_mesh_and_layout_refusals():
     with pytest.raises(NotImplementedError, match="compute_layout"):
         FedAdapterAPI(_model(), fed, None, _cfg(compute_layout="auto"),
                       loss_fn=LOSS)
-    with pytest.raises(NotImplementedError, match="client_step_dtype"):
-        FedAdapterAPI(_model(), fed, None, _cfg(client_step_dtype="bf16"),
-                      loss_fn=LOSS)
     with pytest.raises(ValueError, match="personal_interp"):
         FedAdapterAPI(_model(), fed, None, _cfg(), loss_fn=LOSS,
                       personal_interp=1.5)
+
+
+def test_bf16_client_step_trains_float32_adapters():
+    """``cfg.client_step_dtype="bf16"`` (refused until PR 32): the trainer
+    applies the bf16 twin merged with the SAME held base; the adapters, their
+    gradients and the aggregation stay float32, the base stays as it was."""
+    x, y, parts = _token_data()
+    fed = build_federated_arrays(x, y, parts, B)
+    api = FedAdapterAPI(_model(), fed, None, _cfg(client_step_dtype="bf16"),
+                        loss_fn=LOSS)
+    base0, adapters0 = _snap(api.base), _snap(api.net.params)
+    assert np.isfinite(api.train_one_round(0)["train_loss"])
+    _trees_equal(base0, api.base)
+    moved = jax.tree.map(lambda a, b: not np.array_equal(a, np.asarray(b)),
+                         adapters0, api.net.params)
+    assert any(jax.tree.leaves(moved))
+    assert {a.dtype for a in jax.tree.leaves(api.net.params)} == {
+        np.dtype("float32")}
 
 
 def test_driver_flag_rejection_matrix():
