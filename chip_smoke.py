@@ -17,7 +17,10 @@ weights from a seed:
   scale 1/64, Mamba-2's chunked scan at Granite's 64 heads of 64 x 128 with
   all five gradients (XLA, no Mosaic call), the gated delta rule's hand-over
   kernels with all five
-  gradients at Qwen3-Next's head size (128 x 128, chunk 64), and the fused
+  gradients at Qwen3-Next's head size (128 x 128, chunk 64), the frozen
+  projection with its low-rank pair in one pass (``ops/lora_linear.py``) at
+  Granite's ``input_linear`` (2,048 -> 16,384, plain and gated, the base
+  unbatched), and the fused
   GroupNorm at ResNet-56's shapes, under a vmap over clients, against
   float32 ``jax.numpy`` references; the ``GatedDeltaNet`` layer at the
   published heads (16 key, 32 value) is lowered and its Mosaic calls counted;
@@ -100,6 +103,7 @@ class Sizes:
     gdn_heads: tuple         # (key heads, value heads, d_head)
     granite_attn: tuple      # (query heads, d_head, softmax scale)
     ssd_heads: tuple         # (heads, d_head, d_state, chunk)
+    lora_linear: tuple       # (rows a client, depth, columns, rank)
     gn_shapes: tuple         # ((height == width, channels), ...)
     gn_batch: int
     serve_seq: int
@@ -116,6 +120,7 @@ REAL = Sizes(
     lm_clients=16, lm_per_client=32, lm_batch=8, lm_cohort=8,
     kernel_t=2048, kernel_heads=((8, 64), (4, 128)), gdn_heads=(16, 32, 128),
     granite_attn=(32, 64, 0.015625), ssd_heads=(64, 64, 128, 256),
+    lora_linear=(1024, 2048, 16384, 16),
     gn_shapes=((32, 16), (8, 256)), gn_batch=32,
     serve_seq=128, serve_batch=32, serve_new=16, serve_requests=64,
     chain_dim=4096, chain_s=0.5)
@@ -125,6 +130,7 @@ TOY = Sizes(
     lm_clients=4, lm_per_client=4, lm_batch=2, lm_cohort=2,
     kernel_t=128, kernel_heads=((2, 16), (1, 32)), gdn_heads=(1, 2, 128),
     granite_attn=(2, 16, 0.0625), ssd_heads=(2, 16, 16, 32),
+    lora_linear=(256, 128, 65536, 4),
     gn_shapes=((8, 16), (4, 32)), gn_batch=4,
     serve_seq=16, serve_batch=4, serve_new=3, serve_requests=8,
     chain_dim=256, chain_s=0.05)
@@ -443,6 +449,34 @@ def phase_kernels(ctx: Ctx, out: dict) -> None:
             jnp.bfloat16),
         jax.random.normal(keys[5], lead + (1, n), jnp.bfloat16),
         mosaic=False)   # rel_err order: y, dx, ddt, da, db, dc
+
+    # a frozen projection with its low-rank pair in one pass, at Granite
+    # 4.0-H's input_linear (the base unbatched under the vmap over clients),
+    # plain and gated, against the three products in float32
+    from fedml_tpu.ops.lora_linear import lora_linear, takes_kernel
+
+    m, k, n, r = s.lora_linear
+    check(takes_kernel(m, k, n, r, True), f"{s.lora_linear} keeps XLA's path")
+    keys = jax.random.split(jax.random.PRNGKey(m + k + n), 5)
+    x = jax.random.normal(keys[0], (n_clients, 1, m, k), jnp.bfloat16)
+    w = (jax.random.normal(keys[1], (k, n)) * k ** -0.5).astype(jnp.bfloat16)
+    a = jax.random.normal(keys[2], (n_clients, k, r)) * k ** -0.5
+    b = jax.random.normal(keys[3], (n_clients, r, n)) * r ** -0.5
+
+    def plain(gate, x, w, a, b):
+        y = x @ w + 2.0 * ((x @ a) @ b)
+        return jax.nn.silu(y[..., :n // 2]) * y[..., n // 2:] if gate else y
+
+    for gate in (False, True):
+        compare(
+            f"lora_linear{'_gate' if gate else ''}_m{m}_k{k}_n{n}",
+            jax.vmap(partial(lora_linear, scale=2.0, gate=gate,
+                             out_dtype=jnp.bfloat16 if gate else jnp.float32),
+                     in_axes=(0, None, 0, 0)),
+            jax.vmap(partial(plain, gate), in_axes=(0, None, 0, 0)),
+            jax.random.normal(keys[4], (n_clients, 1, m, n // 2 if gate else n),
+                              jnp.bfloat16),
+            x, w, a, b)         # rel_err order: y, dx, dw, da, db
 
     from fedml_tpu.models.qwen3_next import GatedDeltaNet, Qwen3NextShapes
     from fedml_tpu.ops.gated_delta import (gated_delta_rule,

@@ -92,6 +92,9 @@ class FedAdapterAPI(FedAvgAPI):
             raise ValueError(
                 f"personal_interp must be in [0, 1], got {personal_interp}")
         self._adapter_holder: dict = {}
+        #: ``{(k, n, rank): took the kernel}`` for every projection that
+        #: ``ops.lora_linear`` saw while a program of this class was traced
+        self._lora_traced: dict = {}
         #: Optional PRETRAINED dense params to freeze as the base (the
         #: finetuning story); None = the deterministic fresh init.
         self._base_params = base_params
@@ -120,7 +123,29 @@ class FedAdapterAPI(FedAvgAPI):
                                  base_params=self._base_params)
 
     def _jit(self, fn, donate_argnums=()):
-        return _BaseOperand(self.fns, fn, donate_argnums)
+        return _BaseOperand(self.fns, fn, donate_argnums, self._lora_traced)
+
+    def _lora_sites(self):
+        """``(sites, fused)``: the adapter tree's pairs (a stacked leaf is
+        one a layer) whose projection went through ``ops.lora_linear`` when
+        this class's programs were traced, and those of them whose shapes
+        take its one-pass kernel (``takes_kernel``, decided in that trace).
+        Nothing before the first program has run; a pair computed any other
+        way (``models/transformer``) is no site."""
+        from flax.traverse_util import flatten_dict
+
+        flat = flatten_dict(self.net.params)
+        sites = fused = 0
+        for path, a in flat.items():
+            if not path[-1].endswith("_a"):
+                continue
+            b = flat[path[:-1] + (path[-1][:-2] + "_b",)]
+            shape = (a.shape[-2], b.shape[-1], a.shape[-1])
+            if shape in self._lora_traced:
+                layers = int(np.prod(a.shape[:-2]))
+                sites += layers
+                fused += layers * self._lora_traced[shape]
+        return sites, fused
 
     def _emit_reduce_obs(self, n_rounds: int = 1) -> None:
         """Besides the base class's gauges, what the round just folded: an
@@ -149,11 +174,17 @@ class FedAdapterAPI(FedAvgAPI):
         the frozen base, the wire-relevant ratio (uploads carry the
         adapter tree only), and the registry's running totals:
         ``base_bytes_operand`` (what every program is handed, never
-        copied) and ``adapter_bytes_folded`` (what the host-loop rounds'
+        copied), ``adapter_bytes_folded`` (what the host-loop rounds'
         clients would have uploaded: the clients whose weight was positive
-        x the adapter tree's bytes)."""
+        x the adapter tree's bytes), and ``lora_sites`` /
+        ``lora_sites_fused`` (the projections that went through
+        ``ops.lora_linear`` when the rounds' programs were traced, and those
+        of them whose shapes take its one-pass kernel)."""
         from fedml_tpu.models.adapter import param_count
 
+        sites, fused = self._lora_sites()
+        self._adapter_registry.gauge("lora_sites").set(sites)
+        self._adapter_registry.gauge("lora_sites_fused").set(fused)
         a = param_count(self.net.params)
         b = param_count(self.base)
         return {"base_params": b, "total_params": a + b,
@@ -282,15 +313,24 @@ class FedAdapterAPI(FedAvgAPI):
 class _BaseOperand:
     """``jax.jit`` of a program of the adapter round, with the frozen base
     bound as its first operand (``AdapterFns.bind``). Called inside another
-    such program, it hands on the operand that one was given."""
+    such program, it hands on the operand that one was given. A call that
+    traces notes in ``traced`` which projections ``ops.lora_linear`` saw
+    (``FedAdapterAPI._lora_sites``)."""
 
-    def __init__(self, fns, fn, donate_argnums=()):
+    def __init__(self, fns, fn, donate_argnums, traced: dict):
         self._base = fns.base
+        self._traced = traced
         self._jitted = jax.jit(
             fns.bind(fn), donate_argnums=tuple(i + 1 for i in donate_argnums))
 
     def __call__(self, *args):
-        return self._jitted(self._base(), *args)
+        from fedml_tpu.ops.lora_linear import tally
+
+        with tally() as sites:
+            out = self._jitted(self._base(), *args)
+        for _, k, n, rank, fused in sites:
+            self._traced[(k, n, rank)] = fused
+        return out
 
     def lower(self, *args):
         return self._jitted.lower(self._base(), *args)

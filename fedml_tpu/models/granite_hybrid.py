@@ -28,7 +28,13 @@ Device scopes (``jax.named_scope``, read by the benchmark's reducers):
 ``fed.model.ssm`` (``.conv``, ``.scan``), ``fed.model.attn`` (``.core``),
 ``fed.model.mlp``, ``fed.model.lora`` (every low-rank pair's two products),
 ``fed.model.head`` (embedding, final norm, tied head; ``token_ce`` puts the
-loss there too).
+loss there too). Where a projection's shapes take ``ops/lora_linear.py``'s
+kernel (Granite 4.0-H Micro at 1,024 tokens a client: ``input_linear``) the
+forward's frozen product, the pair's second product, the sum, the gate and
+the cast are ONE Mosaic call, booked under the layer it stands in
+(``fed.model.mlp``): it is the frozen product with an epilogue. What stays
+under ``fed.model.lora`` there is ``x A`` and the backward pass's products
+with ``A`` and ``B``.
 """
 
 from __future__ import annotations
@@ -42,6 +48,7 @@ import jax.numpy as jnp
 
 from fedml_tpu.models.qwen3_next import token_ce  # noqa: F401  (the head-scoped loss)
 from fedml_tpu.models.registry import register_model
+from fedml_tpu.ops.lora_linear import gated, lora_linear
 from fedml_tpu.ops.ssd import ssd_scan
 
 F32 = jnp.float32
@@ -78,26 +85,25 @@ class _Layer(nn.Module):
     def base(self, name, init, shape):
         return self.param(name, _narrowed(init), shape, self.cfg.base_dtype)
 
-    def linear(self, name: str, x, out_dim: int):
-        """``x W + (alpha / r) (x A) B``: ``W`` frozen, ``A`` and ``B`` the
-        federated net (``lora_<name>_a``, ``lora_<name>_b``)."""
+    def linear(self, name: str, x, out_dim: int, gate: bool = False):
+        """``x W + (alpha / r) (x A) B`` in float32: ``W`` frozen, ``A`` and
+        ``B`` the federated net (``lora_<name>_a``, ``lora_<name>_b``). With
+        ``gate`` the result is ``silu(first half) * second half`` in ``x``'s
+        dtype. One pass over the output where the shapes take the kernel
+        (``ops/lora_linear.py``)."""
         c = self.cfg
         w = self.base(name, nn.initializers.normal(0.02),
                       (x.shape[-1], out_dim))
-        y = _mm(x, w)
         if not c.adapter_rank:
-            return y
+            y = _mm(x, w)
+            return gated(y).astype(x.dtype) if gate else y
         b_init = (nn.initializers.normal(c.adapter_b_std) if c.adapter_b_std
                   else nn.initializers.zeros)
         a = self.param(f"lora_{name}_a", nn.initializers.normal(0.02),
                        (x.shape[-1], c.adapter_rank))
         b = self.param(f"lora_{name}_b", b_init, (c.adapter_rank, out_dim))
-        # the pair's two products alone: the sum belongs to the layer, or a
-        # fusion of the frozen product with it would carry this scope's name
-        with jax.named_scope("fed.model.lora"):
-            low = (c.adapter_alpha / c.adapter_rank) * _mm(
-                _mm(x, a).astype(x.dtype), b)
-        return y + low
+        return lora_linear(x, w, a, b, c.adapter_alpha / c.adapter_rank,
+                           gate=gate, out_dtype=x.dtype if gate else F32)
 
 
 class Mamba2Mixer(_Layer):
@@ -187,10 +193,8 @@ class SharedMLP(_Layer):
     @nn.compact
     def __call__(self, x):
         f = self.cfg.shared_intermediate_size
-        ab = self.linear("input_linear", x, 2 * f)
-        hidden = jax.nn.silu(ab[..., :f]) * ab[..., f:]
-        return self.linear("output_linear", hidden.astype(x.dtype),
-                           self.cfg.hidden_size)
+        hidden = self.linear("input_linear", x, 2 * f, gate=True)
+        return self.linear("output_linear", hidden, self.cfg.hidden_size)
 
 
 class GraniteHybridLayer(_Layer):

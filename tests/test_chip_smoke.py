@@ -103,6 +103,20 @@ def test_the_verdict_line_has_the_contract_keys_and_no_others():
                                                       "device": device}
 
 
+@pytest.mark.parametrize("sizes", ["REAL", "TOY"])
+def test_the_kernels_phase_runs_lora_linear_where_it_takes_the_kernel(sizes):
+    """``lora_linear_m.._k.._n..`` and its gated twin: on the chip Granite
+    4.0-H's ``input_linear`` a client, in the dry run a shape that the same
+    rule sends through the (interpreted) kernel."""
+    import chip_smoke
+    from fedml_tpu.ops.lora_linear import takes_kernel
+
+    m, k, n, rank = getattr(chip_smoke, sizes).lora_linear
+    assert takes_kernel(m, k, n, rank) and takes_kernel(m, k, n, rank, True)
+    if sizes == "REAL":
+        assert (m, k, n, rank) == (1024, 2048, 2 * 8192, 16)
+
+
 @pytest.mark.slow  # ~2 min: every phase at toy width on the CPU
 def test_dryrun_cpu_says_it_is_a_dry_run():
     import chip_smoke

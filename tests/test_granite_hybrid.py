@@ -232,14 +232,14 @@ def federation():
     return x, y, parts
 
 
-def _api(federation, **cfg_more):
+def _api(federation, model_cfg=CFG, **cfg_more):
     x, y, parts = federation
     fed = build_federated_arrays(x, y, parts, BATCH)
     cfg = FedConfig(client_num_in_total=CLIENTS, client_num_per_round=CLIENTS,
                     comm_round=4, epochs=2, batch_size=BATCH,
                     client_optimizer="sgd", lr=0.5, seed=7, **cfg_more)
-    return FedAdapterAPI(granite_hybrid(**CFG, attention="flash"), fed, None,
-                         cfg, loss_fn=token_ce)
+    return FedAdapterAPI(granite_hybrid(**model_cfg, attention="flash"), fed,
+                         None, cfg, loss_fn=token_ce)
 
 
 def test_one_round_of_four_clients_is_the_references_round(federation,
@@ -294,8 +294,71 @@ def test_the_base_is_an_operand_of_the_round_not_its_constant(federation):
     api.train_one_round(1)
     after = api.adapter_profile()
     assert "adapter_rounds" not in after
+    # 6 Mamba-2 layers x 2 + 2 attention layers x 4 + 8 MLPs x 2 projections
+    # go through ops.lora_linear; at a depth of 64 none takes its kernel
+    assert (after["lora_sites"], after["lora_sites_fused"]) == (36, 0)
     assert after["adapter_bytes_folded"] == (
         2 * CLIENTS * 4 * after["adapter_params"])
+
+
+#: a width whose projections fill whole lanes: hidden 128, 2 periods of
+#: (Mamba-2, attention), 4 heads of 64 / 32, an MLP of 256
+WIDE = dict(CFG, hidden_size=128, num_hidden_layers=4,
+            layer_types=["mamba", "attention"] * 2, intermediate_size=256,
+            shared_intermediate_size=256, mamba_n_heads=4, mamba_d_head=64)
+
+
+def test_a_width_that_takes_the_kernel_is_the_references_round(
+        federation, reference, monkeypatch):
+    """Where the shapes take ``ops/lora_linear``'s kernel (here the gated
+    ``input_linear``, as in the benchmark's cell, and ``in_proj`` with its
+    partial last tile; the least result lowered to this width's) the model
+    and the round of four clients under one vmap are still the reference's,
+    in float32 to 1e-5, and ``adapter_profile`` counts the sites so."""
+    from fedml_tpu.ops import lora_linear as ll
+
+    rows = BATCH * T
+    monkeypatch.setattr(ll, "MIN_RESULT", rows * 512)
+    fused = {"in_proj": (128, 256 + 288 + 4), "input_linear": (128, 512)}
+    plain = {"out_proj": (256, 128), "output_linear": (256, 128),
+             "q_proj": (128, 128), "k_proj": (128, 64)}
+    assert all(ll.takes_kernel(rows, k, n, 4, name == "input_linear")
+               for name, (k, n) in fused.items())
+    assert not any(ll.takes_kernel(rows, k, n, 4) for k, n in plain.values())
+
+    x, y, parts = federation
+    model = granite_hybrid(**WIDE, attention="flash")
+    with jax.default_matmul_precision("highest"):
+        api = _api(federation, WIDE)
+        start = jax.tree.map(jnp.asarray, jax.tree.map(
+            np.asarray, api.net.params))
+        ids, labels = jnp.asarray(x[:BATCH]), jnp.asarray(y[:BATCH])
+        loss, grads = _loss_and_grad(model, api.base, start, ids, labels)
+        want_loss, want_grads = reference.loss_and_grad(
+            dict(WIDE, base=api.base))(start, ids, labels)
+        round_loss = api.train_one_round(0)["train_loss"]
+        clients = [([(x[parts[c]], y[parts[c]])], PER_CLIENT)
+                   for c in range(CLIENTS)]
+        want, want_round_loss = reference.fedavg_round(
+            start, clients, dict(WIDE, base=api.base), lr=0.5, epochs=2)
+    assert float(loss) == pytest.approx(float(want_loss), abs=1e-5)
+    assert _relative(grads, want_grads) < 1e-5
+    assert round_loss == pytest.approx(want_round_loss, abs=1e-5)
+    update = jax.tree.map(lambda a, b: a - b, api.net.params, start)
+    wanted = jax.tree.map(lambda a, b: a - b, want, start)
+    assert _relative(update, wanted) < 1e-4
+    # 2 Mamba-2 layers x 2 + 2 attention layers x 4 + 4 MLPs x 2; of them
+    # 2 in_proj and 4 input_linear take the kernel
+    profile = api.adapter_profile()
+    assert (profile["lora_sites"], profile["lora_sites_fused"]) == (20, 6)
+    _, gather = api._fused_round_step()
+    idx, wmask = api.sample_round(0)
+    text = str(gather._jitted.trace(
+        api.base, api.net, api._window_carry_init(), api.train_fed,
+        jnp.asarray(idx), jnp.asarray(wmask), api.rng).jaxpr)
+    # the first forward writes outputs only; the recomputed one the gate's sum
+    for name in ("lora_linear", "lora_linear_gate", "lora_linear_gate_saved"):
+        assert f"name={name}\n" in text or f"name={name} " in text, name
 
 
 def test_the_folded_bytes_count_the_clients_that_had_weight(federation):
