@@ -104,6 +104,8 @@ class Sizes:
     granite_attn: tuple      # (query heads, d_head, softmax scale)
     ssd_heads: tuple         # (heads, d_head, d_state, chunk)
     lora_linear: tuple       # (rows a client, depth, columns, rank)
+    window_attn: tuple       # (query heads, d_head, window)
+    held_experts: tuple      # (tokens, d, f, experts, held, top-k, rank)
     gn_shapes: tuple         # ((height == width, channels), ...)
     gn_batch: int
     serve_seq: int
@@ -121,6 +123,7 @@ REAL = Sizes(
     kernel_t=2048, kernel_heads=((8, 64), (4, 128)), gdn_heads=(16, 32, 128),
     granite_attn=(32, 64, 0.015625), ssd_heads=(64, 64, 128, 256),
     lora_linear=(1024, 2048, 16384, 16),
+    window_attn=(8, 128, 128), held_experts=(2048, 1024, 512, 64, 8, 8, 16),
     gn_shapes=((32, 16), (8, 256)), gn_batch=32,
     serve_seq=128, serve_batch=32, serve_new=16, serve_requests=64,
     chain_dim=4096, chain_s=0.5)
@@ -131,6 +134,7 @@ TOY = Sizes(
     kernel_t=128, kernel_heads=((2, 16), (1, 32)), gdn_heads=(1, 2, 128),
     granite_attn=(2, 16, 0.0625), ssd_heads=(2, 16, 16, 32),
     lora_linear=(256, 128, 65536, 4),
+    window_attn=(2, 16, 24), held_experts=(64, 32, 16, 16, 4, 3, 4),
     gn_shapes=((8, 16), (4, 32)), gn_batch=4,
     serve_seq=16, serve_batch=4, serve_new=3, serve_requests=8,
     chain_dim=256, chain_s=0.05)
@@ -430,6 +434,73 @@ def phase_kernels(ctx: Ctx, out: dict) -> None:
         jax.vmap(lambda q, k, v: reference_attention(
             q * (scale * d ** 0.5), k, v, causal=True)),
         do, q, k, v)
+
+    # K-EXAONE's window layers: head 128, a window of 128 (the grids hold
+    # each band's blocks only), against the masked plain softmax
+    h, d, window = s.window_attn
+    keys = jax.random.split(jax.random.PRNGKey(h * 1000 + d + window), 4)
+    q, k, v, do = (jax.random.normal(
+        kk, (n_clients, 1, s.kernel_t, h, d), jnp.bfloat16) for kk in keys)
+
+    def windowed(q, k, v):
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
+        back = jnp.arange(q.shape[1])[:, None] - jnp.arange(q.shape[1])[None]
+        scores = jnp.where((back >= 0) & (back < window), scores, -1e30)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v)
+
+    compare(
+        f"flash_h{h}_d{d}_window{window}",
+        jax.vmap(partial(flash_attention, causal=True, window=window)),
+        jax.vmap(windowed), do, q, k, v)    # rel_err order: o, dq, dk, dv
+
+    # the held experts' product for frozen experts with a pair a client
+    # (the matrices unbatched under the vmap over clients), against every
+    # held expert over every token masked by the routing; XLA products only
+    from fedml_tpu.parallel import expert_parallel as ep
+
+    n, d, f, experts, held, top_k, r = s.held_experts
+    keys = jax.random.split(jax.random.PRNGKey(n + d + f), 12)
+    x = jax.random.normal(keys[0], (n_clients, n, d), jnp.bfloat16)
+    w_router = jax.random.normal(keys[1], (d, experts)) * d ** -0.5
+    bias = 0.05 * jax.random.normal(keys[2], (experts,))
+    w_gate_up = (jax.random.normal(keys[3], (held, d, 2 * f))
+                 * d ** -0.5).astype(jnp.bfloat16)
+    w_down = (jax.random.normal(keys[4], (held, f, d))
+              * f ** -0.5).astype(jnp.bfloat16)
+    pairs = ep.ExpertPairs(*(
+        jax.random.normal(keys[5 + i], (n_clients, held) + shape) * scale
+        for i, (shape, scale) in enumerate((
+            ((d, r), d ** -0.5), ((r, f), r ** -0.5), ((d, r), d ** -0.5),
+            ((r, f), r ** -0.5), ((f, r), f ** -0.5), ((r, d), r ** -0.5)))))
+    idx, weight = jax.vmap(lambda x: ep.route_sigmoid(
+        x, w_router, bias, top_k, 2.5))(x)
+    rows = ep.slab_rows(n, top_k, experts)
+
+    def grouped(x, weight, *pairs):
+        return jax.vmap(lambda x, i, w, p: ep.held_lora_products(
+            x, w, ep.sort_held(i, held, 0), w_gate_up, w_down, p, 2.0,
+            rows)[0])(x, idx, weight, ep.ExpertPairs(*pairs))
+
+    def every_expert(x, weight, *pairs):
+        def one(x, i, w, p):
+            total = 0.0
+            for e in range(held):
+                w_e = jnp.sum(jnp.where(i == e, w, 0.0), -1)
+                wgu = w_gate_up[e].astype(x.dtype)
+                gate = x @ wgu[:, :f] + 2.0 * (x @ p.gate_a[e]) @ p.gate_b[e]
+                up = x @ wgu[:, f:] + 2.0 * (x @ p.up_a[e]) @ p.up_b[e]
+                hidden = jax.nn.silu(gate) * up
+                total = total + w_e[:, None] * (
+                    hidden @ w_down[e].astype(x.dtype)
+                    + 2.0 * (hidden @ p.down_a[e]) @ p.down_b[e])
+            return total
+        return jax.vmap(one)(x, idx, weight, ep.ExpertPairs(*pairs))
+
+    compare(
+        f"held_lora_n{n}_d{d}_f{f}_h{held}", grouped, every_expert,
+        jax.random.normal(keys[11], (n_clients, n, d), jnp.bfloat16),
+        x, weight, *pairs, mosaic=False)
+    # rel_err order: y, dx, dweight, the six pairs' gradients
 
     # Mamba-2's chunked scan at Granite's heads against the recurrence
     from fedml_tpu.ops.ssd import ssd_recurrence, ssd_scan

@@ -92,8 +92,9 @@ class FedAdapterAPI(FedAvgAPI):
             raise ValueError(
                 f"personal_interp must be in [0, 1], got {personal_interp}")
         self._adapter_holder: dict = {}
-        #: ``{(k, n, rank): took the kernel}`` for every projection that
-        #: ``ops.lora_linear`` saw while a program of this class was traced
+        #: ``{(k, n, rank, experts): took the kernel}`` for every projection
+        #: with a pair that ``ops.lora_linear.tally`` saw while a program of
+        #: this class was traced (``experts`` > 0: a grouped product's)
         self._lora_traced: dict = {}
         #: Optional PRETRAINED dense params to freeze as the base (the
         #: finetuning story); None = the deterministic fresh init.
@@ -110,6 +111,7 @@ class FedAdapterAPI(FedAvgAPI):
         reg = self._adapter_registry = MetricsRegistry()
         reg.gauge("base_bytes_operand").set(payload_nbytes(self.base))
         reg.gauge("adapter_params").set(param_count(self.net.params))
+        reg.gauge("experts_held").set(_experts_held(self.base))
         self.personal_interp = float(personal_interp)
         self._personal_spill_dir = personal_spill_dir
         self._personal_store = None
@@ -127,11 +129,12 @@ class FedAdapterAPI(FedAvgAPI):
 
     def _lora_sites(self):
         """``(sites, fused)``: the adapter tree's pairs (a stacked leaf is
-        one a layer) whose projection went through ``ops.lora_linear`` when
+        one a layer, or one a held expert) whose projection went through
+        ``ops.lora_linear`` or a grouped product that notes its pairs when
         this class's programs were traced, and those of them whose shapes
-        take its one-pass kernel (``takes_kernel``, decided in that trace).
-        Nothing before the first program has run; a pair computed any other
-        way (``models/transformer``) is no site."""
+        take ``lora_linear``'s one-pass kernel (``takes_kernel``, decided in
+        that trace). Nothing before the first program has run; a pair
+        computed any other way (``models/transformer``) is no site."""
         from flax.traverse_util import flatten_dict
 
         flat = flatten_dict(self.net.params)
@@ -141,10 +144,13 @@ class FedAdapterAPI(FedAvgAPI):
                 continue
             b = flat[path[:-1] + (path[-1][:-2] + "_b",)]
             shape = (a.shape[-2], b.shape[-1], a.shape[-1])
-            if shape in self._lora_traced:
-                layers = int(np.prod(a.shape[:-2]))
-                sites += layers
-                fused += layers * self._lora_traced[shape]
+            # a leaf of stacked experts first, else a stack of layers
+            for key in (shape + a.shape[-3:-2], shape + (0,)):
+                if key in self._lora_traced:
+                    layers = int(np.prod(a.shape[:-2]))
+                    sites += layers
+                    fused += layers * self._lora_traced[key]
+                    break
         return sites, fused
 
     def _emit_reduce_obs(self, n_rounds: int = 1) -> None:
@@ -177,6 +183,7 @@ class FedAdapterAPI(FedAvgAPI):
         copied), ``adapter_bytes_folded`` (what the host-loop rounds'
         clients would have uploaded: the clients whose weight was positive
         x the adapter tree's bytes), and ``lora_sites`` /
+        ``experts_held`` (the frozen expert MLPs in the base, all layers),
         ``lora_sites_fused`` (the projections that went through
         ``ops.lora_linear`` when the rounds' programs were traced, and those
         of them whose shapes take its one-pass kernel)."""
@@ -328,12 +335,21 @@ class _BaseOperand:
 
         with tally() as sites:
             out = self._jitted(self._base(), *args)
-        for _, k, n, rank, fused in sites:
-            self._traced[(k, n, rank)] = fused
+        for _, k, n, rank, fused, experts in sites:
+            self._traced[(k, n, rank, experts)] = fused
         return out
 
     def lower(self, *args):
         return self._jitted.lower(self._base(), *args)
+
+
+def _experts_held(base) -> int:
+    """Frozen expert MLPs in the base: the leading axis of every stacked
+    ``experts_down`` leaf, summed over the layers (0 for a model without)."""
+    from flax.traverse_util import flatten_dict
+
+    return int(sum(leaf.shape[-3] for path, leaf in flatten_dict(base).items()
+                   if path[-1] == "experts_down"))
 
 
 def _gather_shards(fed, idx):

@@ -137,10 +137,16 @@ def adapter_model_fns(model, holder: Optional[dict] = None,
     LoRA init; only they are materialised then). Structure must match the
     split base or ``init`` raises.
 
+    A model's other collections (an expert layer's ``counters``) are no
+    part of the base: they ride in the net's ``model_state`` beside the
+    adapters, and the round carries and averages them as it does for a
+    whole-weights model.
+
     Raises when the model has NO adapter params (an adapter config
     against a dense model must refuse, not silently train the dense arm)
-    or carries mutable collections (BatchNorm stats would mutate the
-    "frozen" base — transformers here are LayerNorm-only)."""
+    or carries ``batch_stats`` (BatchNorm's running statistics belong to
+    the "frozen" base's layers and would drift under it — transformers
+    here are LayerNorm-only)."""
     import jax
 
     from fedml_tpu.trainer.local import NetState, model_fns
@@ -159,11 +165,11 @@ def adapter_model_fns(model, holder: Optional[dict] = None,
                 "adapter finetuning needs a model with injected adapter "
                 f"params (no '{ADAPTER_PREFIX}*' leaves found) — build it "
                 "with adapter_rank > 0 (models/transformer.py)")
-        if full.model_state:
+        if "batch_stats" in full.model_state:
             raise NotImplementedError(
-                "adapter finetuning requires a frozen base with no "
-                "mutable collections (BatchNorm running stats would "
-                f"mutate it); got {sorted(full.model_state)}")
+                "adapter finetuning requires a frozen base without "
+                "BatchNorm (its running statistics would drift under the "
+                f"frozen layers); got {sorted(full.model_state)}")
         if base_params is not None:
             import jax.numpy as jnp
 
@@ -178,8 +184,10 @@ def adapter_model_fns(model, holder: Optional[dict] = None,
             # One program whose only results are the adapters, the full
             # init's bit for bit: the compiler drops the fresh base's draws,
             # so a 6.4 GB base is never held beside a second one.
-            adapters = jax.jit(lambda r, x: split_frozen(
-                full_fns.init(r, x).params)[1])(rng, sample_x)
+            adapters, state = jax.jit(lambda r, x: (lambda net: (
+                split_frozen(net.params)[1], net.model_state))(
+                    full_fns.init(r, x)))(rng, sample_x)
+            full = NetState(full.params, state)
         holder["base"] = base
         return NetState(adapters, full.model_state)
 

@@ -40,6 +40,7 @@ def create_model(name: str, **kwargs):
         import fedml_tpu.models.efficientnet  # noqa: F401
         import fedml_tpu.models.gan  # noqa: F401
         import fedml_tpu.models.granite_hybrid  # noqa: F401
+        import fedml_tpu.models.k_exaone  # noqa: F401
         import fedml_tpu.models.lr  # noqa: F401
         import fedml_tpu.models.mobilenet  # noqa: F401
         import fedml_tpu.models.mobilenet_v3  # noqa: F401

@@ -82,15 +82,28 @@ _TALLIES: list = []
 
 @contextlib.contextmanager
 def tally():
-    """Collects ``(m, k, n, rank, fused)`` for every projection traced while
-    it is open (host side, trace time): what ``FedAdapterAPI`` counts its
-    ``lora_sites`` from."""
+    """Collects ``(m, k, n, rank, fused, experts)`` for every projection with
+    a pair traced while it is open (host side, trace time): what
+    ``FedAdapterAPI`` counts its ``lora_sites`` from. ``experts`` is 0 for a
+    projection of this file, and the number of stacked experts for a grouped
+    product that computes its pairs itself (:func:`note`)."""
     calls: list = []
     _TALLIES.append(calls)
     try:
         yield calls
     finally:
         _TALLIES.remove(calls)
+
+
+def note(m: int, k: int, n: int, rank: int, fused: bool,
+         experts: int = 0) -> None:
+    """Adds a projection with a pair to every open :func:`tally`:
+    ``lora_linear`` notes its own; the model whose experts' pairs are
+    computed inside their grouped product
+    (``parallel.expert_parallel.held_lora_products``) notes those, unfused,
+    with the number of experts stacked in one leaf."""
+    for calls in _TALLIES:
+        calls.append((m, k, n, rank, fused, experts))
 
 
 def _divisor(n: int, most: int, unit: int) -> int:
@@ -254,8 +267,7 @@ def lora_linear(x, w, a, b, scale: float, *, gate: bool = False,
     ``out_dtype``; see the module's docstring."""
     m, (k, n) = math.prod(x.shape[:-1]), w.shape
     fused = takes_kernel(m, k, n, a.shape[-1], gate)
-    for calls in _TALLIES:
-        calls.append((m, k, n, a.shape[-1], fused))
+    note(m, k, n, a.shape[-1], fused)
     if fused:
         return _lora_linear(x, w, a, b, float(scale), gate, out_dtype)
     s = _summed(x, w, a, b, scale)

@@ -18,12 +18,18 @@ which contiguous range it holds, routes top-k over all of them, keeps every
 token (no capacity drops), and computes the part of the result its own
 experts give. On one chip it runs without its exchange, and nothing stands
 in for the absent shards: a token none of whose experts is held gets 0 here.
+
+``route_sigmoid`` / ``sort_held`` / ``held_lora_products`` are the same shard
+for FROZEN experts with a low-rank pair a client beside each matrix (the
+federated adapter round): the router scores with a sigmoid and selects on
+score + bias (the DeepSeek-V3 router), and the product reads the experts'
+matrices where they lie, whatever the clients' ``vmap`` batches.
 """
 
 from __future__ import annotations
 
 from functools import partial
-from typing import NamedTuple
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -273,3 +279,204 @@ def held_expert_products(x, routing: HeldRouting, w_gate_up, w_down,
 
     return jax.lax.cond(routing.overflow, dense, grouped, x, routing,
                         w_gate_up, w_down)
+
+
+# ---------------------------------------------------------------------------
+# One shard's part of a top-k layer of FROZEN experts with low-rank pairs
+# ---------------------------------------------------------------------------
+
+#: rows of an expert's slab over the mean number of assignments a held expert
+#: (``tokens * top_k / experts``): of the first pass's slab, and of a further
+#: pass's. The slab's rows cost products whether filled or not, but a further
+#: pass costs more than its rows (its own gather, weighted sum and ``while``
+#: step: 25 ms for a slab a fifth as tall as one that costs 80, on a v5e),
+#: and how many a step takes depends on its tokens, so the first slab holds
+#: what a step's fullest expert usually draws. Measured there (PERF.md
+#: section 6, PR 34): under a BALANCED router a client-step of Zipf tokens
+#: sends its fullest held expert 3.2 times the mean on average (a silo's
+#: commonest token is a seventh of its tokens and goes to the same 8
+#: experts); at 2.5 a step took 1.4 further passes a layer and a round's time
+#: swung by 12 %.
+SLAB_FACTOR, FURTHER_FACTOR = 4.5, 0.5
+
+
+def slab_rows(n_tokens: int, top_k: int, n_experts: int) -> tuple:
+    """``(rows of a held expert's slab in the first pass, in a further
+    pass)``: ``SLAB_FACTOR`` and ``FURTHER_FACTOR`` times its mean load, in
+    whole sublane tiles of 8."""
+    mean = n_tokens * top_k / n_experts
+    return tuple(max(8, int(-(-factor * mean // 8)) * 8)
+                 for factor in (SLAB_FACTOR, FURTHER_FACTOR))
+
+
+def route_sigmoid(x, w_router, bias, top_k: int, scale: float = 1.0,
+                  renormalise: bool = True):
+    """``(idx [N, k], weight [N, k])``: scores ``s = sigmoid(x W_r)`` over all
+    experts in float32 at the highest precision, the ``top_k`` largest of
+    ``s + bias`` (the bias SELECTS only), weights ``scale * s[idx] /
+    (sum s[idx] + 1e-20)`` (``renormalise``; ``scale * s[idx]`` without)."""
+    scores = jax.nn.sigmoid(
+        jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    weight = jnp.take_along_axis(scores, idx, axis=-1)
+    if renormalise:
+        weight = weight / (jnp.sum(weight, axis=-1, keepdims=True) + 1e-20)
+    return idx, scale * weight
+
+
+class HeldAssignments(NamedTuple):
+    """The ``N * k`` assignments sorted by held expert."""
+
+    order: jax.Array     # [N * k] assignment ids, a held expert's together
+    counts: jax.Array    # [H] int32 assignments of each held expert
+    start: jax.Array     # [H] int32 where each expert's begin in ``order``
+    unrouted: jax.Array  # [] int32 tokens none of whose experts is held
+
+
+def sort_held(idx, n_held: int, first: int) -> HeldAssignments:
+    """One stable sort of the chosen experts ``idx [N, k]``, the assignments
+    to experts outside ``first .. first + n_held - 1`` keyed last."""
+    local = idx - first
+    held = (local >= 0) & (local < n_held)
+    key = jnp.where(held, local, n_held).reshape(-1)
+    counts = jnp.sum(
+        (key[:, None] == jnp.arange(n_held)[None, :]).astype(jnp.int32),
+        axis=0)
+    return HeldAssignments(
+        order=jnp.argsort(key, stable=True).astype(jnp.int32), counts=counts,
+        start=jnp.cumsum(counts) - counts,
+        unrouted=jnp.sum(1 - jnp.any(held, axis=-1).astype(jnp.int32)))
+
+
+class ExpertPairs(NamedTuple):
+    """A low-rank pair beside each of a held expert's three matrices:
+    ``a [H, in, r]``, ``b [H, r, out]``."""
+
+    gate_a: Any
+    gate_b: Any
+    up_a: Any
+    up_b: Any
+    down_a: Any
+    down_b: Any
+
+
+def _mm(spec, a, b):
+    return jnp.einsum(spec, a, b.astype(a.dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def _slab_pass(first, x, weight, held: HeldAssignments, w_gate_up, w_down,
+               pairs: ExpertPairs, scale: float, rows: int):
+    """Assignments ``first .. first + rows - 1`` of every held expert:
+    ``[N, d]`` float32. The frozen matrices are operands of expert-batched
+    products as they lie (``[H, d, 2f]``, ``[H, f, d]``): no gather reads
+    them, so nothing copies them and a ``vmap`` over clients (which batches
+    ``x``, the routing and the pairs) leaves them one operand."""
+    n, d = x.shape
+    top_k = weight.shape[-1]
+    f = w_down.shape[1]
+    rank = first + jnp.arange(rows, dtype=jnp.int32)
+    valid = rank[None, :] < held.counts[:, None]                # [H, rows]
+    at = jnp.clip(held.start[:, None] + rank[None, :], 0, n * top_k - 1)
+    source = held.order[at]
+    token = source // top_k
+    slot_weight = jnp.where(valid, weight.reshape(-1)[source], 0.0)
+    slab = jnp.take(x, token, axis=0)                           # [H, rows, d]
+
+    def low(t, a, b):
+        return scale * _mm("hcr,hro->hco",
+                           _mm("hci,hir->hcr", t, a).astype(t.dtype), b)
+
+    gate_up = _mm("hcd,hdf->hcf", slab, w_gate_up)
+    gate = gate_up[..., :f] + low(slab, pairs.gate_a, pairs.gate_b)
+    up = gate_up[..., f:] + low(slab, pairs.up_a, pairs.up_b)
+    hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
+    out = _mm("hcf,hfd->hcd", hidden, w_down) + low(
+        hidden, pairs.down_a, pairs.down_b)
+    # weighted in float32, handed to the sum in the step's dtype: the sum
+    # itself is float32 (at most top_k terms a token)
+    out = (out * slot_weight[..., None]).astype(x.dtype).reshape(-1, d)
+    return jnp.zeros((n, d), jnp.float32).at[token.reshape(-1)].add(out)
+
+
+def _passes(counts, rows: tuple):
+    """Further passes the fullest held expert needs after the first."""
+    return -(-jnp.maximum(jnp.max(counts) - rows[0], 0) // rows[1])
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _further_passes(y, x, weight, held, w_gate_up, w_down, pairs, scale,
+                    rows):
+    """``y`` plus the further passes' slabs: a ``while`` whose trip count
+    is the fullest expert's, none at a load under ``SLAB_FACTOR``."""
+    def body(carry):
+        p, acc = carry
+        return p + 1, acc + _slab_pass(
+            rows[0] + p * rows[1], x, weight, held, w_gate_up, w_down, pairs,
+            scale, rows[1])
+
+    return jax.lax.while_loop(
+        lambda carry: carry[0] < _passes(held.counts, rows), body,
+        (jnp.int32(0), y))[1]
+
+
+def _further_fwd(y, x, weight, held, w_gate_up, w_down, pairs, scale, rows):
+    return (_further_passes(y, x, weight, held, w_gate_up, w_down, pairs,
+                            scale, rows),
+            (x, weight, held, w_gate_up, w_down, pairs))
+
+
+def _further_bwd(scale, rows, saved, dy):
+    """A pass's forward is computed again here and differentiated by
+    ``x``, the weights and the pairs; the frozen matrices get no gradient
+    (``None``: none is formed)."""
+    x, weight, held, w_gate_up, w_down, pairs = saved
+
+    def body(carry):
+        p, grads = carry
+        _, vjp = jax.vjp(
+            lambda x, weight, pairs: _slab_pass(
+                rows[0] + p * rows[1], x, weight, held, w_gate_up, w_down,
+                pairs, scale, rows[1]),
+            x, weight, pairs)
+        return p + 1, jax.tree.map(jnp.add, grads, vjp(dy))
+
+    zeros = jax.tree.map(jnp.zeros_like, (x, weight, pairs))
+    dx, dweight, dpairs = jax.lax.while_loop(
+        lambda carry: carry[0] < _passes(held.counts, rows), body,
+        (jnp.int32(0), zeros))[1]
+    return dy, dx, dweight, None, None, None, dpairs
+
+
+_further_passes.defvjp(_further_fwd, _further_bwd)
+
+
+def held_lora_products(x, weight, held: HeldAssignments, w_gate_up, w_down,
+                       pairs: ExpertPairs, scale: float, rows: tuple):
+    """The held experts' part of the layer, ``sum_{e in top-k(n), e held}
+    w_ne E_e(x_n)`` as float32 ``[N, d]``, every matrix of ``E_e`` the frozen
+    one plus ``scale`` times its client's pair: ``x [N, d]`` and
+    ``weight [N, k]`` (float32) the client's, ``w_gate_up [H, d, 2f]`` and
+    ``w_down [H, f, d]`` frozen, in ``x``'s dtype; ``rows`` from
+    :func:`slab_rows`.
+
+    A batched product over ``[H, rows, d]`` slabs, an expert's assignments in
+    its own slab, so the expert axis is a batch axis of the product and the
+    matrices are read in place (``_slab_pass``). The first pass is plain JAX
+    and differentiated as such: ``dx`` through the frozen matrices and the
+    pairs' gradients; the matrices are no argument of the round's gradient,
+    so no product forms theirs. What does not fit an expert's first slab
+    takes further passes of shorter slabs, as many as the fullest expert
+    needs (``_further_passes``, with its own backward): no ``lax.cond``, no
+    dense arm, no capacity past which an assignment is dropped. Returns
+    ``(y, computed, further)``: ``computed`` the assignments the passes
+    covered, ``min(count, rows of all passes)`` summed over the held experts,
+    which is every one of them; ``further`` the further passes taken."""
+    y = _slab_pass(0, x, weight, held, w_gate_up, w_down, pairs, scale,
+                   rows[0])
+    y = _further_passes(y, x, weight, held, w_gate_up, w_down, pairs, scale,
+                        rows)
+    further = _passes(held.counts, rows)
+    covered = rows[0] + further * rows[1]
+    return y, jnp.sum(jnp.minimum(held.counts, covered)), further

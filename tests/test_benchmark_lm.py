@@ -619,3 +619,308 @@ def test_every_adapter_limit_lies_between_its_two_readings():
     assert runner.kind_of("lora_output_linear_b") == "mlp_b"
     with pytest.raises(KeyError):
         runner.kind_of("in_proj")
+
+
+# --- K-EXAONE in the adapter round (PR 34) ------------------------------------
+
+KEXAONE, KEXAONE_CELL = "k_exaone_236b_a23b", "kexaone_lora_c4_s4k"
+KEXAONE_READERS = [
+    "device_ms.attn_window.round", "device_ms.attn_full.round",
+    "device_ms.moe_held.round", "attn_window_roofline_pct",
+    "moe_held_lora_roofline_pct", "moe_held_load_max_over_mean"]
+
+
+@pytest.fixture(scope="module")
+def kexaone():
+    return _json("benchmark", "configs", KEXAONE + ".json")
+
+
+@pytest.fixture(scope="module")
+def moe_lora_mix():
+    return _json("benchmark", "traffic", "resident_silos_c4_s4k_lora.json")
+
+
+def test_kexaone_holds_every_published_number_or_names_it_reduced(kexaone):
+    """The catalog row's ``config`` key for key: equal, or one of the three
+    cuts of scale with the published value beside it; every width as
+    published, in the file and in what the factory is handed."""
+    assert kexaone["reduced"] == ["num_hidden_layers", "num_experts",
+                                  "vocab_size"]
+    assert kexaone["published"] == {"num_hidden_layers": 48,
+                                    "num_experts": 128, "vocab_size": 153600}
+    held = {k: kexaone[k] for k in kexaone["reduced"]}
+    assert held == {"num_hidden_layers": 5, "num_experts": 16,
+                    "vocab_size": 19200}
+    kwargs = kexaone["factory_kwargs"]
+    # the router stays 128 wide and top-8; 16 experts from 0 are held
+    assert (kwargs["num_experts"], kwargs["num_experts_held"],
+            kwargs["first_expert_held"], kwargs["num_experts_per_tok"]) == (
+                128, 16, 0, 8)
+    for key, value in {"hidden_size": 6144, "num_attention_heads": 64,
+                       "num_key_value_heads": 8, "head_dim": 128,
+                       "intermediate_size": 18432,
+                       "moe_intermediate_size": 2048, "sliding_window": 128,
+                       "routed_scaling_factor": 2.5}.items():
+        assert kexaone[key] == kwargs[key] == value, key
+    manifest = _json("BENCHMARK.json")
+    listed, = [c for c in manifest["configs"] if c["name"] == KEXAONE]
+    assert listed["reduced"] == kexaone["reduced"]
+    assert listed["source"] == kexaone["source"]
+    assert set(kexaone["assumed"]) >= {"block", "qk_norm", "positions",
+                                       "router_bias", "weight_scale",
+                                       "adapter_b_std"}
+    assert any("multi-token-prediction" in d for d in kexaone["departures"])
+    assert "8 chips share each layer" in kexaone["deployment"]
+    from fedml_tpu.algos.config import FedConfig
+
+    assert all(hasattr(FedConfig(), k) for k in kexaone["fed_config"])
+    if not os.path.exists(CATALOG):
+        pytest.skip("the model-configs catalog is not on this machine")
+    with open(CATALOG) as f:
+        entry, = [row for row in map(json.loads, f)
+                  if row["source_url"] == kexaone["source"]]
+    for key, value in entry["config"].items():
+        if key in kexaone["reduced"]:
+            assert kexaone["published"][key] == value, key
+        else:
+            assert kexaone[key] == value, key
+            assert kwargs[key] == value, key
+
+
+def test_kexaone_parameters_and_flops_recounted(kexaone, moe_lora_mix):
+    """The file's counts against the model's own trees (shapes only: no
+    3.7 G parameters are made) and against ``counts/k_exaone.py``; the
+    frozen FLOPs against the derivation, part by part and by hand."""
+    from fedml_tpu.models.adapter import split_frozen
+    from fedml_tpu.models.k_exaone import k_exaone
+
+    kwargs = kexaone["factory_kwargs"]
+    model = k_exaone(**kwargs)
+    ids = jax.ShapeDtypeStruct((1, 16), np.int32)
+    shapes = jax.eval_shape(
+        lambda i: model.init({"params": jax.random.PRNGKey(0)}, i), ids)
+    base, adapters = split_frozen(shapes["params"])
+
+    def count(tree):
+        return sum(int(np.prod(leaf.shape)) for leaf in jax.tree.leaves(tree))
+
+    # ISSUE 34's 3,711,959,040 of matrices + 69,376 of norms and bias
+    assert {"base": count(base), "adapters": count(adapters)} \
+        == kexaone["parameters"] == {"base": 3711959040 + 69376,
+                                     "adapters": 31358976}
+    assert {str(leaf.dtype) for leaf in jax.tree.leaves(base)} == {"bfloat16"}
+    assert set(shapes["counters"]) == {f"layer_{i}" for i in range(1, 5)}
+    counts = _load(kexaone["counts"])
+    assert counts.parameters(kwargs) == kexaone["parameters"]
+    assert (counts.train_flops_per_sequence(kexaone, moe_lora_mix)
+            == kexaone["train_flops_per_sample"])
+    per_token = counts.forward_flops_per_token(kwargs, 4096)
+    assert {k: round(v) for k, v in per_token.items()} \
+        == kexaone["forward_flops_per_token"]
+    attn = 6144 * 8192 + 2 * 6144 * 1024 + 8192 * 6144
+    expert = 3 * 6144 * 2048
+    assert per_token["attn_projections"] == 2 * 5 * attn
+    assert per_token["dense_mlp"] == 2 * 3 * 6144 * 18432
+    assert per_token["shared_experts"] == per_token["held_experts"] \
+        == 2 * 4 * expert        # one held assignment a token at 16 of 128
+    assert per_token["head"] == 2 * 6144 * 19200
+    assert per_token["lora"] == 2 * (
+        5 * 688128 + 1179648 + 4 * 2 * 393216)
+    # the window core counts the visible pairs only
+    ops, _ = counts.attn_core_forward(kwargs, 4096, 128)
+    assert ops == 64 * 4 * 128 * (128 * 129 // 2 + (4096 - 128) * 128)
+    full, _ = counts.attn_core_forward(kwargs, 4096, 0)
+    assert full == 64 * 4 * 128 * (4096 * 4097 // 2) and ops < full / 15
+    frozen, low, moved, matrices = counts.held_experts_forward(kwargs, 4096)
+    assert frozen == 4096 * 2 * expert and matrices == 16 * expert * 2
+    assert low == 4096 * 2 * 16 * 3 * (6144 + 2048)
+    assert kexaone["train_flops_per_sample"] == round(4096 * (
+        2 * sum(v for k, v in per_token.items()
+                if k not in ("attn_core", "lora"))
+        + 3 * (per_token["attn_core"] + per_token["lora"])))
+    assert round(sum(per_token.values()) / 1e7) == 276     # 2.76 GFLOP
+    assert counts.steps_per_round(moe_lora_mix) == 8
+    peaks = _json("benchmark", "peaks.json")["TPU v5 lite"]
+    for kernel in ("attn_window", "moe_held_lora"):
+        assert counts.roofline_ms_per_round(kernel, kexaone, moe_lora_mix,
+                                            peaks) > 0
+    with pytest.raises(KeyError):
+        counts.roofline_ms_per_round("ssm_scan", kexaone, moe_lora_mix, peaks)
+
+
+def test_kexaone_mix_is_the_two_accepted_mixes_crossed(moe_lora_mix, mix):
+    """``resident_silos_c4_s4k``'s documents and sizes, the adapter mix's
+    runner family; only the learning rate is this cell's own."""
+    for key in ("clients", "counts", "cohort", "batch", "epochs",
+                "sequence_length", "zipf_exponent", "rank_offset_max",
+                "doc_length", "generator", "client_optimizer", "placement",
+                "trace_rounds"):
+        assert moe_lora_mix[key] == mix[key], key
+    assert moe_lora_mix["runner"] == "fed_adapter_moe_lm_round"
+    assert os.path.exists(os.path.join(
+        BENCHMARK, "runners", moe_lora_mix["runner"] + ".py"))
+
+
+@pytest.mark.parametrize("name", KEXAONE_READERS)
+def test_kexaone_reader_agrees_with_the_manifest(name, tmp_path, monkeypatch):
+    manifest = _json("BENCHMARK.json")
+    entry, = [m for m in manifest["per_layer"] if m["name"] == name]
+    reader = _load(f"layer_metrics/{name}.py")
+    assert {k: entry[k] for k in ("layer", "unit", "moves")} == reader.META
+    cells = [w["name"] for w in manifest["workloads"] if reader.applies(w)]
+    assert cells == entry["workloads"] == [KEXAONE_CELL]
+    monkeypatch.setattr(reader.rsm.rsc.rs, "TRACE_DIR", str(tmp_path))
+    assert reader.read({"chips": 1, "rounds": 3,
+                        "device_kind": "TPU v5 lite"}) is None
+
+
+def test_no_accepted_scope_reader_applies_to_the_kexaone_cell(kexaone):
+    """The accepted readers of ``fed.model.moe``, ``.attn``, ``.head``,
+    ``.mlp``, ``.lora`` and of ``expert_tokens`` keep their lists: the
+    cell's file keeps what they key on (``scopes``, ``counters``) clear of
+    their names and lists its own partition's apart."""
+    manifest = _json("BENCHMARK.json")
+    cell, = [w for w in manifest["workloads"] if w["name"] == KEXAONE_CELL]
+    for metric in manifest["per_layer"]:
+        reader = _load(f"layer_metrics/{metric['name']}.py")
+        listed = KEXAONE_CELL in metric.get("workloads", [KEXAONE_CELL])
+        assert reader.applies(cell) == listed, metric["name"]
+    assert kexaone["scopes"] == []
+    assert "expert_tokens" not in kexaone["counters"]
+    rsm = _load("reduce_scopes_swa_moe.py")
+    assert set(kexaone["scopes_swa_moe"]) | set(kexaone["scopes_unread"]) \
+        == set(rsm.SCOPES) - {"fed.client_fold"}
+
+
+def test_swa_moe_reducer_books_window_full_and_pairs_apart():
+    """``reduce_scopes_swa_moe.py``: ``reduce_scopes``' walk with this
+    model's list; the copy is held to the original as the hybrid one is."""
+    rsm, rsc = _load("reduce_scopes_swa_moe.py"), _load("reduce_scopes.py")
+    for private in ("SCOPES", "_SCOPE", "scope_of", "scope_ms", "traced",
+                    "roofline_pct", "_config_of"):
+        assert hasattr(rsc, private), private
+    of = rsm._reducer.scope_of
+    assert of(["jit(f)/fed.local_train/jvp(fed.model.attn.window)/"
+               "fed.model.attn.window.core/pallas_call"]) \
+        == "fed.model.attn.window.core"
+    assert of(["x/transpose(jvp(fed.model.attn.full))/fed.model.lora/dot"]) \
+        == "fed.model.lora"
+    assert of(["x/fed.model.moe/fed.model.moe.experts/while/body/dot"]) \
+        == "fed.model.moe.experts"
+    assert of(["x/fed.model.moe/fed.model.moe.shared/fed.model.lora/dot"]) \
+        == "fed.model.lora"
+    assert of(["x/fed.model.mlp/dot_general"]) == "fed.model.mlp"
+    assert rsc.SCOPES[0] == "fed.model.gdn.scan"      # the other is untouched
+    for scope in ("fed.model.head", "fed.client_fold",
+                  "fed.model.moe.route"):
+        path = f"jit(f)/fed.local_train/jvp({scope})/dot_general"
+        assert of([path]) == rsc.scope_of([path]) == scope
+
+
+def test_kexaone_readers_on_a_reduction(kexaone, moe_lora_mix, monkeypatch):
+    reduction = {"rounds": 4, "device_ns_by_scope": {
+        "": 5e6, "fed.model.attn.window": 300e6,
+        "fed.model.attn.window.core": 100e6, "fed.model.attn.full": 90e6,
+        "fed.model.attn.full.core": 30e6, "fed.model.moe": 40e6,
+        "fed.model.moe.route": 60e6, "fed.model.moe.experts": 2000e6,
+        "fed.model.moe.shared": 100e6, "fed.model.lora": 40e6}}
+    summary = {"device_kind": "TPU v5 lite",
+               "moe_held_load_max_over_mean": 1.9,
+               "counts": {"module": kexaone["counts"], "config": kexaone,
+                          "mix": moe_lora_mix}}
+    rsm = _load("reduce_scopes_swa_moe.py")
+    monkeypatch.setattr(rsm._reducer, "traced", lambda: reduction)
+    assert rsm.scope_ms("fed.model.attn.window") == pytest.approx(100.0)
+    assert rsm.scope_ms("fed.model.attn.full") == pytest.approx(30.0)
+    assert rsm.scope_ms("fed.model.moe") == pytest.approx(550.0)
+    counts = _load(kexaone["counts"])
+    peaks = _json("benchmark", "peaks.json")["TPU v5 lite"]
+    for kernel, scope, ms in (
+            ("attn_window", "fed.model.attn.window.core", 25.0),
+            ("moe_held_lora", "fed.model.moe.experts", 500.0)):
+        least = counts.roofline_ms_per_round(kernel, kexaone, moe_lora_mix,
+                                             peaks)
+        share = rsm.roofline_pct(summary, kernel, scope)
+        assert share == pytest.approx(100.0 * least / ms) and 0 < share < 100
+    monkeypatch.setattr(rsm._reducer, "traced", lambda: {
+        "rounds": 4, "device_ns_by_scope": {"": 5e6}})
+    assert rsm.scope_ms("fed.model.moe") is None    # a program with no scope
+    load = _load("layer_metrics/moe_held_load_max_over_mean.py")
+    assert load.read(summary) == 1.9 and load.read({}) is None
+
+
+@pytest.mark.parametrize("stand_in, failing", [
+    (None, set()),
+    ("unchanged_state", {"attention", "dense_mlp", "shared_expert",
+                         "held_experts"}),
+    ("last_batch_left_out", None),
+    ("reference_bits:4", None),
+])
+def test_the_kexaone_cell_is_correct_and_its_stand_ins_are_not(
+        kexaone, moe_lora_mix, stand_in, failing):
+    """``benchmark/run.py``'s own context and ``fed_adapter_moe_lm_round``
+    at the rehearsal's sizes on the CPU: the round passes every limit and
+    drops no token; a state left unchanged reads 1 on every kind of pair; a
+    client's last batch left out and the reference with float8's 4-bit
+    products each fail a limit."""
+    import argparse
+
+    run = _load("run.py")
+    manifest = run.load_manifest()
+    cell = run.by_name(manifest["workloads"], KEXAONE_CELL, "workload")
+    # a window of a dozen toy rounds: the first two alone end at the prior
+    args = argparse.Namespace(seed=3400000124, seconds=2.0, trace=0,
+                              dryrun_cpu=True)
+    mix = {**moe_lora_mix, "stand_in": stand_in} if stand_in else moe_lora_mix
+    ctx = run.Ctx(manifest, cell, kexaone, mix, args, "cpu")
+    runner = ctx.load_module(f"runners/{mix['runner']}.py")
+    result = runner.run(ctx)
+    summary = result["summary"]
+    errors = summary["reference_errors"]
+    over = {k for k, v in errors.items()
+            if v > runner.TOLERANCES[k]["limit"]}
+    assert result["correct"] == (stand_in is None), errors
+    if failing is None:
+        assert over, errors
+    else:
+        assert over == failing, errors
+    if stand_in == "unchanged_state":
+        assert all(errors[k] == pytest.approx(1.0) for k in failing)
+    assert summary["base_bytes_operand"] == 2 * summary["base_parameters"]
+    assert summary["moe_dropped_tokens"] == 0
+    assert summary["moe_held_load_max_over_mean"] >= 1
+    assert summary["experts_held"] == 4 * 4
+
+
+def test_every_kexaone_limit_lies_between_its_two_readings():
+    runner = _load("runners/fed_adapter_moe_lm_round.py")
+    assert set(runner.TOLERANCES) == {
+        "attention", "dense_mlp", "shared_expert", "held_experts", "loss"}
+    for kind, t in runner.TOLERANCES.items():
+        assert 2 * t["program"] <= t["limit"] <= t["control"] / 2, kind
+        assert t["why"]
+    # the loss is held against the fault; what the 4-bit control moves it by
+    # is kept beside it and lies too near the program's reading for a limit
+    loss = runner.TOLERANCES["loss"]
+    assert loss["program"] < loss["precision"] < loss["limit"]
+    assert [k for k, t in runner.TOLERANCES.items() if "precision" in t] == [
+        "loss"]
+    assert runner.kind_of(("layer_3", "attn", "lora_q_proj_a")) == "attention"
+    assert runner.kind_of(("layer_0", "mlp", "lora_up_proj_b")) == "dense_mlp"
+    assert runner.kind_of(("layer_2", "moe", "shared",
+                           "lora_down_proj_a")) == "shared_expert"
+    assert runner.kind_of(("layer_2", "moe",
+                           "lora_experts_gate_b")) == "held_experts"
+    with pytest.raises(KeyError):
+        runner.kind_of(("layer_2", "moe", "router"))
+    # a program whose adapter round carries no counters reports none
+    assert runner.counters_of(type("Api", (), {"net": type(
+        "Net", (), {"model_state": {}})()})()) == {}
+
+
+def test_kexaone_dryrun_sizes_name_every_kind_of_layer(kexaone):
+    kwargs = kexaone["dryrun"]["factory_kwargs"]
+    assert kwargs["layer_types"].count("full_attention") == 1
+    assert kwargs["mlp_layer_types"] == ["dense"] + ["sparse"] * 4
+    assert kwargs["num_experts_held"] < kwargs["num_experts"]
+    assert kexaone["dryrun"]["classes"] == kwargs["vocab_size"] == 257

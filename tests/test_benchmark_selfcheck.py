@@ -110,8 +110,33 @@ def test_the_adapter_cell_rehearses_on_the_cpu():
                 or "roofline" in k or k.startswith("mfu")]
 
 
+def test_the_expert_adapter_cell_rehearses_on_the_cpu():
+    """``kexaone_lora_c4_s4k`` as the driver starts it, at the files' dryrun
+    sizes, traced: exit 0, ``correct`` (round 0 against the reference, the
+    base unchanged and one operand, no token dropped), the program counter's
+    metric on the line, and never a device number."""
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_PLATFORMS"] = "cpu"
+    done = subprocess.run(
+        [sys.executable, os.path.join("benchmark", "run.py"), "--workload",
+         "kexaone_lora_c4_s4k", "--seed", "3400000777", "--seconds", "2",
+         "--trace", "1", "--dryrun-cpu"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=900)
+    assert done.returncode == 0, done.stderr[-4000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert line["dryrun"] is True and line["correct"] is True
+    assert line["failed"] == 0 and line["attempted"] >= 1
+    assert line["device"]["platform"] == "cpu"
+    assert line["metrics"]["moe_held_load_max_over_mean"]["unit"] == "ratio"
+    assert line["metrics"]["moe_held_load_max_over_mean"]["value"] >= 1
+    assert line["metrics"]["step_fill_pct"]["value"] == 100.0
+    assert not [k for k in line["metrics"] if k.startswith("device_")
+                or "roofline" in k or k.startswith("mfu")]
+
+
 @pytest.mark.parametrize("reference", [
-    "reference.py", "reference_qwen3_next.py", "reference_granite_hybrid.py"])
+    "reference.py", "reference_qwen3_next.py", "reference_granite_hybrid.py",
+    "reference_k_exaone.py"])
 def test_reference_imports_nothing_of_the_program(reference):
     """The yardstick is independent of the code under test: by its syntax
     tree, no import of ``fedml_tpu`` (at any depth of the file), and no

@@ -683,3 +683,60 @@ def test_dialect_law_properties():
     assert len(xg) == cg.sum()
     with pytest.raises(ValueError, match="unknown token law"):
         make_stackoverflow_shard(4, 12, 512, law="zipf")
+
+
+# ------------------------- a model's own collection rides the round (PR 34) --
+
+import flax.linen as nn  # noqa: E402
+
+
+class _CountingLM(nn.Module):
+    """A frozen embedding and head with one low-rank pair between them, and
+    a ``counters`` collection: the tokens each call saw, by parity of id."""
+
+    @nn.compact
+    def __call__(self, ids, train: bool = False):
+        embed = self.param("embed", nn.initializers.normal(0.1), (V, 8))
+        head = self.param("head", nn.initializers.normal(0.1), (8, V))
+        a = self.param("lora_mid_a", nn.initializers.normal(0.1), (8, 2))
+        b = self.param("lora_mid_b", nn.initializers.normal(0.1), (2, 8))
+        x = embed[ids]
+        if self.is_mutable_collection("counters"):
+            seen = self.variable("counters", "tokens_by_parity",
+                                 lambda: jnp.zeros(2, jnp.float32))
+            if not self.is_initializing():
+                seen.value = seen.value + jnp.stack([
+                    jnp.sum(ids % 2 == 0), jnp.sum(ids % 2 == 1)]).astype(
+                        jnp.float32)
+        return (x + x @ a @ b) @ head
+
+
+@pytest.mark.parametrize("group", [0, 1])
+def test_the_round_carries_a_models_counters_beside_the_adapters(group):
+    """A collection that is no part of the base rides ``NetState.
+    model_state`` through the adapter round, whole cohort or a client at a
+    time: each round adds the cohort's weighted mean of what a client's
+    local steps counted; the base stays bit for bit, and the pretrained
+    (``base_params``) path makes the collection too."""
+    x, y, parts = _token_data(n_clients=4, per=8)
+    train = build_federated_arrays(x, y, parts, B)
+    cfg = _cfg(n=4, cpr=4, client_group_size=group)
+    api = FedAdapterAPI(_CountingLM(), train, None, cfg, loss_fn=LOSS)
+    assert set(api.net.model_state) == {"counters"}
+    np.testing.assert_array_equal(
+        api.net.model_state["counters"]["tokens_by_parity"], [0, 0])
+    base0 = _snap(api.base)
+    api.train_one_round(0)
+    api.train_one_round(1)
+    _trees_equal(base0, api.base)
+    seen = np.asarray(api.net.model_state["counters"]["tokens_by_parity"])
+    # every client holds 8 sequences of T tokens and trains them once a round
+    assert seen.sum() == pytest.approx(2 * 8 * T)
+    even = np.mean([(x[parts[c]] % 2 == 0).sum() for c in range(4)])
+    assert seen[0] == pytest.approx(2 * even)
+    pretrained = FedAdapterAPI(_CountingLM(), train, None, cfg, loss_fn=LOSS,
+                               base_params=api.base)
+    assert pretrained.net.model_state["counters"][
+        "tokens_by_parity"].shape == (2,)
+    _trees_equal(pretrained.net.params, FedAdapterAPI(
+        _CountingLM(), train, None, cfg, loss_fn=LOSS).net.params)

@@ -185,6 +185,6 @@ def test_the_tally_sees_every_traced_projection():
     _, x, w, a, b = _operands(jnp.float32, N)
     with ll.tally() as calls:
         jax.eval_shape(lambda x: lora_linear(x, w, a, b, SCALE), x)
-    assert calls == [(M, K, N, RANK, False)]
+    assert calls == [(M, K, N, RANK, False, 0)]
     jax.eval_shape(lambda x: lora_linear(x, w, a, b, SCALE), x)
     assert len(calls) == 1      # closed: nothing is added afterwards
