@@ -454,8 +454,10 @@ def phase_kernels(ctx: Ctx, out: dict) -> None:
         jax.vmap(windowed), do, q, k, v)    # rel_err order: o, dq, dk, dv
 
     # the held experts' product for frozen experts with a pair a client
-    # (the matrices unbatched under the vmap over clients), against every
-    # held expert over every token masked by the routing; XLA products only
+    # (the matrices unbatched under the vmap over clients: the grouped Mosaic
+    # product over the sorted assignments, ``ops/grouped_matmul.py``), against
+    # every held expert over every token masked by the routing; as the router
+    # sends them, and with one held expert at 6 times the mean load
     from fedml_tpu.parallel import expert_parallel as ep
 
     n, d, f, experts, held, top_k, r = s.held_experts
@@ -472,35 +474,41 @@ def phase_kernels(ctx: Ctx, out: dict) -> None:
         for i, (shape, scale) in enumerate((
             ((d, r), d ** -0.5), ((r, f), r ** -0.5), ((d, r), d ** -0.5),
             ((r, f), r ** -0.5), ((f, r), f ** -0.5), ((r, d), r ** -0.5)))))
-    idx, weight = jax.vmap(lambda x: ep.route_sigmoid(
+    routed, weight = jax.vmap(lambda x: ep.route_sigmoid(
         x, w_router, bias, top_k, 2.5))(x)
-    rows = ep.slab_rows(n, top_k, experts)
+    rows = ep.chunk_rows(n, top_k, experts, held)
+    crowd = 6 * n * top_k // experts    # tokens whose first choice is expert 0
+    skewed = jnp.where(routed == 0, experts - 1, routed).at[
+        :, :crowd, 0].set(0)
 
-    def grouped(x, weight, *pairs):
-        return jax.vmap(lambda x, i, w, p: ep.held_lora_products(
-            x, w, ep.sort_held(i, held, 0), w_gate_up, w_down, p, 2.0,
-            rows)[0])(x, idx, weight, ep.ExpertPairs(*pairs))
+    for name, idx in ((f"held_lora_n{n}_d{d}_f{f}_h{held}", routed),
+                      (f"held_lora_n{n}_d{d}_f{f}_h{held}_skew6", skewed)):
+        def grouped(x, weight, *pairs, idx=idx):
+            return jax.vmap(lambda x, i, w, p: ep.held_lora_products(
+                x, w, ep.sort_held(i, held, 0), w_gate_up, w_down, p, 2.0,
+                rows)[0])(x, idx, weight, ep.ExpertPairs(*pairs))
 
-    def every_expert(x, weight, *pairs):
-        def one(x, i, w, p):
-            total = 0.0
-            for e in range(held):
-                w_e = jnp.sum(jnp.where(i == e, w, 0.0), -1)
-                wgu = w_gate_up[e].astype(x.dtype)
-                gate = x @ wgu[:, :f] + 2.0 * (x @ p.gate_a[e]) @ p.gate_b[e]
-                up = x @ wgu[:, f:] + 2.0 * (x @ p.up_a[e]) @ p.up_b[e]
-                hidden = jax.nn.silu(gate) * up
-                total = total + w_e[:, None] * (
-                    hidden @ w_down[e].astype(x.dtype)
-                    + 2.0 * (hidden @ p.down_a[e]) @ p.down_b[e])
-            return total
-        return jax.vmap(one)(x, idx, weight, ep.ExpertPairs(*pairs))
+        def every_expert(x, weight, *pairs, idx=idx):
+            def one(x, i, w, p):
+                total = 0.0
+                for e in range(held):
+                    w_e = jnp.sum(jnp.where(i == e, w, 0.0), -1)
+                    wgu = w_gate_up[e].astype(x.dtype)
+                    gate = x @ wgu[:, :f] + 2.0 * (
+                        x @ p.gate_a[e]) @ p.gate_b[e]
+                    up = x @ wgu[:, f:] + 2.0 * (x @ p.up_a[e]) @ p.up_b[e]
+                    hidden = jax.nn.silu(gate) * up
+                    total = total + w_e[:, None] * (
+                        hidden @ w_down[e].astype(x.dtype)
+                        + 2.0 * (hidden @ p.down_a[e]) @ p.down_b[e])
+                return total
+            return jax.vmap(one)(x, idx, weight, ep.ExpertPairs(*pairs))
 
-    compare(
-        f"held_lora_n{n}_d{d}_f{f}_h{held}", grouped, every_expert,
-        jax.random.normal(keys[11], (n_clients, n, d), jnp.bfloat16),
-        x, weight, *pairs, mosaic=False)
-    # rel_err order: y, dx, dweight, the six pairs' gradients
+        compare(
+            name, grouped, every_expert,
+            jax.random.normal(keys[11], (n_clients, n, d), jnp.bfloat16),
+            x, weight, *pairs)
+        # rel_err order: y, dx, dweight, the six pairs' gradients
 
     # Mamba-2's chunked scan at Granite's heads against the recurrence
     from fedml_tpu.ops.ssd import ssd_recurrence, ssd_scan

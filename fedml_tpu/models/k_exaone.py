@@ -27,9 +27,11 @@ This shard of the expert-parallel layer: ``num_experts`` is the router's
 width, ``num_experts_held`` experts from ``first_expert_held`` are here; the
 others' part of the sum is absent (``parallel/expert_parallel.py``). The
 collection ``counters`` keeps, a sparse layer, the running totals
-``expert_tokens [held]``, ``unrouted_tokens``, ``uncomputed_tokens`` and
-``further_passes`` (passes of the held experts' product after the first),
-which the round carries and averages over the cohort like batch statistics.
+``expert_tokens [held]``, ``unrouted_tokens``, ``uncomputed_tokens``,
+``further_passes`` (chunks of the held experts' grouped product after the
+first) and ``grouped_rows`` (the rows of the chunks taken: ``expert_tokens``
+over it is the product's fill), which the round carries and averages over the
+cohort like batch statistics.
 
 Device scopes (``jax.named_scope``, read by the benchmark's reducers):
 ``fed.model.attn.window`` and ``fed.model.attn.full`` (``.core`` around the
@@ -55,7 +57,7 @@ from fedml_tpu.models.qwen3_next import token_ce  # noqa: F401  (the head-scoped
 from fedml_tpu.models.registry import register_model
 from fedml_tpu.ops import lora_linear as ll
 from fedml_tpu.parallel.expert_parallel import (
-    ExpertPairs, held_lora_products, route_sigmoid, slab_rows, sort_held)
+    ExpertPairs, chunk_rows, held_lora_products, route_sigmoid, sort_held)
 
 F32 = jnp.float32
 _NORMAL = nn.initializers.normal(0.02)
@@ -211,10 +213,10 @@ class SparseMoE(_Layer):
                 flat32, w_router, bias, c.num_experts_per_tok,
                 c.routed_scaling_factor, c.norm_topk_prob)
             assigned = sort_held(idx, held, c.first_expert_held)
-        rows = slab_rows(b * t, c.num_experts_per_tok, c.num_experts)
+        rows = chunk_rows(b * t, c.num_experts_per_tok, c.num_experts, held)
         if r:       # pairs that the grouped product computes itself
             for i, o in shapes.values():
-                ll.note(rows[0], i, o, r, False, experts=held)
+                ll.note(rows, i, o, r, False, experts=held)
         with jax.named_scope("fed.model.moe.experts"):
             y, computed, further = held_lora_products(
                 flat, weight, assigned, w_gate_up, w_down, pairs,
@@ -222,7 +224,7 @@ class SparseMoE(_Layer):
         self._count(expert_tokens=assigned.counts,
                     unrouted_tokens=assigned.unrouted,
                     uncomputed_tokens=jnp.sum(assigned.counts) - computed,
-                    further_passes=further)
+                    further_passes=further, grouped_rows=rows * (1 + further))
         with jax.named_scope("fed.model.moe.shared"):
             y = y + GatedMLP(c, self.dtype, c.moe_intermediate_size,
                              name="shared")(flat)

@@ -99,9 +99,10 @@ def note(m: int, k: int, n: int, rank: int, fused: bool,
          experts: int = 0) -> None:
     """Adds a projection with a pair to every open :func:`tally`:
     ``lora_linear`` notes its own; the model whose experts' pairs are
-    computed inside their grouped product
+    computed beside their grouped product
     (``parallel.expert_parallel.held_lora_products``) notes those, unfused,
-    with the number of experts stacked in one leaf."""
+    with the rows of a chunk of that product as ``m`` and the number of
+    experts stacked in one leaf."""
     for calls in _TALLIES:
         calls.append((m, k, n, rank, fused, experts))
 

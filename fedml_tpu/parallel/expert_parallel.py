@@ -22,8 +22,11 @@ in for the absent shards: a token none of whose experts is held gets 0 here.
 ``route_sigmoid`` / ``sort_held`` / ``held_lora_products`` are the same shard
 for FROZEN experts with a low-rank pair a client beside each matrix (the
 federated adapter round): the router scores with a sigmoid and selects on
-score + bias (the DeepSeek-V3 router), and the product reads the experts'
-matrices where they lie, whatever the clients' ``vmap`` batches.
+score + bias (the DeepSeek-V3 router), and the product is GROUPED over the
+assignments sorted by expert (``ops/grouped_matmul.py``: a Mosaic kernel whose
+row tiles read their own expert's matrices where they lie, whatever the
+clients' ``vmap`` batches, and which computes the tiles that hold real rows
+only), in chunks of ``chunk_rows`` rows of the sorted order.
 """
 
 from __future__ import annotations
@@ -35,6 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
+
+from fedml_tpu.ops.grouped_matmul import group_of_rows, grouped_matmul
 
 
 class MoEParams(NamedTuple):
@@ -285,28 +290,27 @@ def held_expert_products(x, routing: HeldRouting, w_gate_up, w_down,
 # One shard's part of a top-k layer of FROZEN experts with low-rank pairs
 # ---------------------------------------------------------------------------
 
-#: rows of an expert's slab over the mean number of assignments a held expert
-#: (``tokens * top_k / experts``): of the first pass's slab, and of a further
-#: pass's. The slab's rows cost products whether filled or not, but a further
-#: pass costs more than its rows (its own gather, weighted sum and ``while``
-#: step: 25 ms for a slab a fifth as tall as one that costs 80, on a v5e),
-#: and how many a step takes depends on its tokens, so the first slab holds
-#: what a step's fullest expert usually draws. Measured there (PERF.md
-#: section 6, PR 34): under a BALANCED router a client-step of Zipf tokens
-#: sends its fullest held expert 3.2 times the mean on average (a silo's
-#: commonest token is a seventh of its tokens and goes to the same 8
-#: experts); at 2.5 a step took 1.4 further passes a layer and a round's time
-#: swung by 12 %.
-SLAB_FACTOR, FURTHER_FACTOR = 4.5, 0.5
+#: rows of a chunk of the grouped product over the mean number of held
+#: assignments a client-step (``tokens * top_k * held / experts``). A chunk's
+#: rows cost a gather, the elementwise passes and the combine whether filled
+#: or not (the products themselves run over the real rows only), and what a
+#: step's held assignments need beyond it costs a further chunk of the same
+#: height. It is the TOTAL that has to fit, not the fullest expert: under a
+#: balanced router a client-step of Zipf tokens sends one held expert 3.2
+#: times the mean, but a layer's held experts together 0.93-1.18 times theirs
+#: (PERF.md section 6, PR 34 and PR 35: at 2 no client-step of the seeds run
+#: took a further chunk, at 1.5 one in ten of one seed's did).
+CHUNK_FACTOR = 2.0
 
 
-def slab_rows(n_tokens: int, top_k: int, n_experts: int) -> tuple:
-    """``(rows of a held expert's slab in the first pass, in a further
-    pass)``: ``SLAB_FACTOR`` and ``FURTHER_FACTOR`` times its mean load, in
-    whole sublane tiles of 8."""
-    mean = n_tokens * top_k / n_experts
-    return tuple(max(8, int(-(-factor * mean // 8)) * 8)
-                 for factor in (SLAB_FACTOR, FURTHER_FACTOR))
+def chunk_rows(n_tokens: int, top_k: int, n_experts: int,
+               n_held: int) -> int:
+    """Rows of a chunk of the held experts' grouped product: ``CHUNK_FACTOR``
+    times the mean number of held assignments, in whole row tiles of the
+    kernel (512; sublane tiles of 8 where a chunk would not fill one)."""
+    rows = CHUNK_FACTOR * n_tokens * top_k * n_held / n_experts
+    tile = 512 if rows >= 512 else 8
+    return max(tile, int(-(-rows // tile)) * tile)
 
 
 def route_sigmoid(x, w_router, bias, top_k: int, scale: float = 1.0,
@@ -326,9 +330,12 @@ def route_sigmoid(x, w_router, bias, top_k: int, scale: float = 1.0,
 
 
 class HeldAssignments(NamedTuple):
-    """The ``N * k`` assignments sorted by held expert."""
+    """The ``N * k`` assignments sorted by held expert: ``order[: sum(counts)]``
+    are the held experts' (expert 0's first), the rows a grouped product
+    takes chunk by chunk; the others follow."""
 
     order: jax.Array     # [N * k] assignment ids, a held expert's together
+    rank: jax.Array      # [N * k] where each assignment is in ``order``
     counts: jax.Array    # [H] int32 assignments of each held expert
     start: jax.Array     # [H] int32 where each expert's begin in ``order``
     unrouted: jax.Array  # [] int32 tokens none of whose experts is held
@@ -343,9 +350,11 @@ def sort_held(idx, n_held: int, first: int) -> HeldAssignments:
     counts = jnp.sum(
         (key[:, None] == jnp.arange(n_held)[None, :]).astype(jnp.int32),
         axis=0)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
     return HeldAssignments(
-        order=jnp.argsort(key, stable=True).astype(jnp.int32), counts=counts,
-        start=jnp.cumsum(counts) - counts,
+        order=order, rank=jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=jnp.int32), unique_indices=True),
+        counts=counts, start=jnp.cumsum(counts) - counts,
         unrouted=jnp.sum(1 - jnp.any(held, axis=-1).astype(jnp.int32)))
 
 
@@ -366,69 +375,112 @@ def _mm(spec, a, b):
                       preferred_element_type=jnp.float32)
 
 
-def _slab_pass(first, x, weight, held: HeldAssignments, w_gate_up, w_down,
-               pairs: ExpertPairs, scale: float, rows: int):
-    """Assignments ``first .. first + rows - 1`` of every held expert:
-    ``[N, d]`` float32. The frozen matrices are operands of expert-batched
-    products as they lie (``[H, d, 2f]``, ``[H, f, d]``): no gather reads
-    them, so nothing copies them and a ``vmap`` over clients (which batches
-    ``x``, the routing and the pairs) leaves them one operand."""
-    n, d = x.shape
-    top_k = weight.shape[-1]
+@jax.custom_vjp
+def _sum_of(rows, token, at):
+    """Combine: token ``n`` gets the float32 sum of its assignments' rows,
+    ``rows[at[n, j]]`` (zero where ``at`` is past the end): at most ``top_k``
+    terms a token, as a gather. Each row is one assignment's, so the
+    transpose is a gather too: row ``r`` gets ``g[token[r]]``."""
+    return jnp.sum(jnp.take(rows, at, axis=0, mode="fill",
+                            fill_value=0).astype(jnp.float32), axis=1)
+
+
+def _sum_fwd(rows, token, at):
+    # an empty array hands the rows' dtype to the backward pass
+    return _sum_of(rows, token, at), (token, jnp.zeros((0,), rows.dtype))
+
+
+def _sum_bwd(saved, g):
+    token, like = saved
+    return (jnp.take(g, token, axis=0, mode="fill",
+                     fill_value=0).astype(like.dtype), None, None)
+
+
+_sum_of.defvjp(_sum_fwd, _sum_bwd)
+
+
+def _chunk_pass(p, x, weight, held: HeldAssignments, w_gate_up, w_down,
+                pairs: ExpertPairs, scale: float, rows: int):
+    """Rows ``p * rows .. (p + 1) * rows - 1`` of the assignments sorted by
+    held expert: ``[N, d]`` float32. The frozen matrices are operands of
+    grouped products as they lie (``[H, d, 2f]``, ``[H, f, d]``;
+    ``ops/grouped_matmul.py``): no gather reads them, so nothing copies them
+    and a ``vmap`` over clients (which batches ``x``, the routing and the
+    pairs) leaves them one operand. Rows past the held assignments' total
+    are zero and are not combined. The combine is a gather by the inverse
+    permutation and so is its transpose (``_sum_of``): a row belongs to one
+    assignment, and in the round a scatter-add of the chunk's weighted rows
+    costs twice that gather of ``N * k`` (measured on a v5e, PERF.md section
+    6, PR 35); the rows' own gather keeps JAX's transpose, a scatter-add in
+    the step's dtype, which is the cheaper there."""
+    n, top_k = weight.shape
     f = w_down.shape[1]
-    rank = first + jnp.arange(rows, dtype=jnp.int32)
-    valid = rank[None, :] < held.counts[:, None]                # [H, rows]
-    at = jnp.clip(held.start[:, None] + rank[None, :], 0, n * top_k - 1)
-    source = held.order[at]
-    token = source // top_k
+    ends = held.start + held.counts
+    lo = p * rows
+    at = lo + jnp.arange(rows, dtype=jnp.int32)
+    valid = at < ends[-1]
+    source = held.order[jnp.minimum(at, n * top_k - 1)]
+    token = jnp.where(valid, source // top_k, n)
     slot_weight = jnp.where(valid, weight.reshape(-1)[source], 0.0)
-    slab = jnp.take(x, token, axis=0)                           # [H, rows, d]
+    # the chunk's row of each assignment, ``rows`` (past the end) if it has
+    # none: not held, or in another chunk
+    row = held.rank - lo
+    row = jnp.where((held.rank < ends[-1]) & (row >= 0) & (row < rows), row,
+                    rows).reshape(n, top_k)
+    # each expert's [start, start + counts) clipped to the chunk
+    sizes = jnp.clip(ends, lo, lo + rows) - jnp.clip(held.start, lo,
+                                                     lo + rows)
+    member = (group_of_rows(sizes, rows)[:, None]
+              == jnp.arange(sizes.shape[0])[None, :])           # [rows, H]
+    slab = jnp.take(x, token, axis=0, mode="fill", fill_value=0)  # [rows, d]
 
     def low(t, a, b):
-        return scale * _mm("hcr,hro->hco",
-                           _mm("hci,hir->hcr", t, a).astype(t.dtype), b)
+        """The pairs of all held experts as two dense products under a
+        block mask (``H r`` columns): a row keeps its own expert's block."""
+        t = _mm("ci,hir->chr", t, a)
+        t = jnp.where(member[:, :, None], t, 0.0).astype(slab.dtype)
+        return scale * _mm("chr,hro->co", t, b)
 
-    gate_up = _mm("hcd,hdf->hcf", slab, w_gate_up)
-    gate = gate_up[..., :f] + low(slab, pairs.gate_a, pairs.gate_b)
-    up = gate_up[..., f:] + low(slab, pairs.up_a, pairs.up_b)
+    gate_up = grouped_matmul(slab, w_gate_up, sizes, name="held_gmm")
+    gate = gate_up[:, :f] + low(slab, pairs.gate_a, pairs.gate_b)
+    up = gate_up[:, f:] + low(slab, pairs.up_a, pairs.up_b)
     hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
-    out = _mm("hcf,hfd->hcd", hidden, w_down) + low(
+    out = grouped_matmul(hidden, w_down, sizes, name="held_gmm") + low(
         hidden, pairs.down_a, pairs.down_b)
     # weighted in float32, handed to the sum in the step's dtype: the sum
     # itself is float32 (at most top_k terms a token)
-    out = (out * slot_weight[..., None]).astype(x.dtype).reshape(-1, d)
-    return jnp.zeros((n, d), jnp.float32).at[token.reshape(-1)].add(out)
+    return _sum_of((out * slot_weight[:, None]).astype(x.dtype), token, row)
 
 
-def _passes(counts, rows: tuple):
-    """Further passes the fullest held expert needs after the first."""
-    return -(-jnp.maximum(jnp.max(counts) - rows[0], 0) // rows[1])
+def _chunks(counts, rows: int):
+    """Chunks the held assignments fill; the first is always computed."""
+    return jnp.maximum(-(-jnp.sum(counts) // rows), 1)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _further_passes(y, x, weight, held, w_gate_up, w_down, pairs, scale,
+def _further_chunks(y, x, weight, held, w_gate_up, w_down, pairs, scale,
                     rows):
-    """``y`` plus the further passes' slabs: a ``while`` whose trip count
-    is the fullest expert's, none at a load under ``SLAB_FACTOR``."""
+    """``y`` plus the chunks after the first: a ``while`` whose trip count
+    is what the held assignments' total needs, none at a total under
+    ``rows``."""
     def body(carry):
         p, acc = carry
-        return p + 1, acc + _slab_pass(
-            rows[0] + p * rows[1], x, weight, held, w_gate_up, w_down, pairs,
-            scale, rows[1])
+        return p + 1, acc + _chunk_pass(p, x, weight, held, w_gate_up,
+                                        w_down, pairs, scale, rows)
 
     return jax.lax.while_loop(
-        lambda carry: carry[0] < _passes(held.counts, rows), body,
-        (jnp.int32(0), y))[1]
+        lambda carry: carry[0] < _chunks(held.counts, rows), body,
+        (jnp.int32(1), y))[1]
 
 
 def _further_fwd(y, x, weight, held, w_gate_up, w_down, pairs, scale, rows):
-    return (_further_passes(y, x, weight, held, w_gate_up, w_down, pairs,
+    return (_further_chunks(y, x, weight, held, w_gate_up, w_down, pairs,
                             scale, rows),
             (x, weight, held, w_gate_up, w_down, pairs))
 
 
 def _further_bwd(scale, rows, saved, dy):
-    """A pass's forward is computed again here and differentiated by
+    """A chunk's forward is computed again here and differentiated by
     ``x``, the weights and the pairs; the frozen matrices get no gradient
     (``None``: none is formed)."""
     x, weight, held, w_gate_up, w_down, pairs = saved
@@ -436,47 +488,48 @@ def _further_bwd(scale, rows, saved, dy):
     def body(carry):
         p, grads = carry
         _, vjp = jax.vjp(
-            lambda x, weight, pairs: _slab_pass(
-                rows[0] + p * rows[1], x, weight, held, w_gate_up, w_down,
-                pairs, scale, rows[1]),
+            lambda x, weight, pairs: _chunk_pass(
+                p, x, weight, held, w_gate_up, w_down, pairs, scale, rows),
             x, weight, pairs)
         return p + 1, jax.tree.map(jnp.add, grads, vjp(dy))
 
     zeros = jax.tree.map(jnp.zeros_like, (x, weight, pairs))
     dx, dweight, dpairs = jax.lax.while_loop(
-        lambda carry: carry[0] < _passes(held.counts, rows), body,
-        (jnp.int32(0), zeros))[1]
+        lambda carry: carry[0] < _chunks(held.counts, rows), body,
+        (jnp.int32(1), zeros))[1]
     return dy, dx, dweight, None, None, None, dpairs
 
 
-_further_passes.defvjp(_further_fwd, _further_bwd)
+_further_chunks.defvjp(_further_fwd, _further_bwd)
 
 
 def held_lora_products(x, weight, held: HeldAssignments, w_gate_up, w_down,
-                       pairs: ExpertPairs, scale: float, rows: tuple):
+                       pairs: ExpertPairs, scale: float, rows: int):
     """The held experts' part of the layer, ``sum_{e in top-k(n), e held}
     w_ne E_e(x_n)`` as float32 ``[N, d]``, every matrix of ``E_e`` the frozen
     one plus ``scale`` times its client's pair: ``x [N, d]`` and
     ``weight [N, k]`` (float32) the client's, ``w_gate_up [H, d, 2f]`` and
     ``w_down [H, f, d]`` frozen, in ``x``'s dtype; ``rows`` from
-    :func:`slab_rows`.
+    :func:`chunk_rows`.
 
-    A batched product over ``[H, rows, d]`` slabs, an expert's assignments in
-    its own slab, so the expert axis is a batch axis of the product and the
-    matrices are read in place (``_slab_pass``). The first pass is plain JAX
-    and differentiated as such: ``dx`` through the frozen matrices and the
-    pairs' gradients; the matrices are no argument of the round's gradient,
-    so no product forms theirs. What does not fit an expert's first slab
-    takes further passes of shorter slabs, as many as the fullest expert
-    needs (``_further_passes``, with its own backward): no ``lax.cond``, no
-    dense arm, no capacity past which an assignment is dropped. Returns
-    ``(y, computed, further)``: ``computed`` the assignments the passes
-    covered, ``min(count, rows of all passes)`` summed over the held experts,
-    which is every one of them; ``further`` the further passes taken."""
-    y = _slab_pass(0, x, weight, held, w_gate_up, w_down, pairs, scale,
-                   rows[0])
-    y = _further_passes(y, x, weight, held, w_gate_up, w_down, pairs, scale,
+    One flat buffer of ``rows`` rows a chunk: the assignments sorted by held
+    expert (``sort_held``), each row multiplied by its own expert's matrices
+    in a product GROUPED over the sorted rows, which computes the tiles that
+    hold real rows only and reads the matrices in place (``_chunk_pass``,
+    ``ops/grouped_matmul.py``); the pairs of all held experts are dense
+    products under a block mask. The first chunk is plain JAX and
+    differentiated as such: ``dx`` through the frozen matrices (the grouped
+    product's own backward, the same kernel on the transposed matrices) and
+    the pairs' gradients; the matrices are no argument of the round's
+    gradient, so no product forms theirs. What the held assignments' total
+    needs beyond the first chunk takes further chunks (``_further_chunks``,
+    a ``while`` with its own backward): no ``lax.cond``, no dense arm, no
+    capacity past which an assignment is dropped. Returns ``(y, computed,
+    further)``: ``computed`` the assignments the chunks covered,
+    ``min(total, rows of all chunks)``, which is every one of them;
+    ``further`` the chunks taken after the first."""
+    y = _chunk_pass(0, x, weight, held, w_gate_up, w_down, pairs, scale, rows)
+    y = _further_chunks(y, x, weight, held, w_gate_up, w_down, pairs, scale,
                         rows)
-    further = _passes(held.counts, rows)
-    covered = rows[0] + further * rows[1]
-    return y, jnp.sum(jnp.minimum(held.counts, covered)), further
+    chunks = _chunks(held.counts, rows)
+    return (y, jnp.minimum(jnp.sum(held.counts), chunks * rows), chunks - 1)

@@ -1,0 +1,236 @@
+"""``ops/grouped_matmul.py``: the grouped kernel (interpreted on the CPU)
+against the plain grouped expression and against a loop over the groups,
+forward and ``dlhs``, with group edges off the tile grid, empty groups, a total
+under the rows (the tail is zero) and transposed matrices; no cotangent for
+the frozen matrices; under ``vmap`` with the matrices unbatched; the plain
+expression wherever the shapes do not take the kernel; and the kernel
+compiled for the v5e at the benchmark cell's shapes (no chip)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from fedml_tpu.ops import grouped_matmul as gm
+from fedml_tpu.ops.grouped_matmul import grouped_matmul, takes_kernel
+
+M, K, N, G = 512, 256, 384, 5
+# tiles of 128 rows: a 512-row buffer is 4 row tiles, 8 visits at most
+SIZES = {
+    "edges_off_the_grid": [100, 0, 157, 30, 60],
+    "all_empty": [0, 0, 0, 0, 0],
+    "one_group_fills_it": [512, 0, 0, 0, 0],
+    "a_row_each": [1, 1, 1, 1, 1],
+    "edges_on_the_grid": [128, 128, 128, 64, 64],
+    "first_groups_empty": [0, 0, 300, 0, 212],
+    "five_in_one_tile": [20, 30, 10, 40, 20],
+}
+TILES = [(128, 128, 128, 128), (256, 256, 128, 128), (512, 128, 384, 128),
+         (512, 256, 128, 256)]
+
+
+def _tiled(lhs, rhs, sizes, tiles, transpose=False):
+    """The product at the tests' own tiles (the public call takes the
+    module's, which hold a whole test buffer in one row tile)."""
+    return gm._grouped_matmul(lhs, rhs, sizes, transpose, tiles,
+                              "grouped_matmul")
+
+
+def _operands(dtype, transpose=False, seed=0):
+    rng = np.random.default_rng(seed)
+    lhs = jnp.asarray(rng.normal(size=(M, K)), dtype)
+    rhs = jnp.asarray(rng.normal(size=(G, N, K) if transpose else (G, K, N))
+                      * 0.1, dtype)
+    return lhs, rhs
+
+
+def _loop(lhs, rhs, sizes, transpose=False):
+    """Group after group, each its rows by its own matrix."""
+    out, at = np.zeros((lhs.shape[0], N), np.float32), 0
+    for g, size in enumerate(sizes):
+        w = np.asarray(rhs[g], np.float32)
+        out[at:at + size] = np.asarray(lhs[at:at + size], np.float32) @ (
+            w.T if transpose else w)
+        at += size
+    return out
+
+
+def _close(got, want, tol):
+    got, want = (np.asarray(v, np.float32) for v in (got, want))
+    assert got.shape == want.shape
+    assert np.abs(got - want).max() <= tol * max(np.abs(want).max(), 1e-6)
+
+
+@pytest.mark.parametrize("tiles", TILES, ids=lambda t: "x".join(map(str, t)))
+@pytest.mark.parametrize("case", list(SIZES))
+@pytest.mark.parametrize("transpose", [False, True])
+def test_kernel_is_the_plain_grouped_expression(case, tiles, transpose):
+    """Forward and ``dlhs`` in float32, every tiling: the kernel, the plain
+    masked ``dot_general`` and the loop over the groups agree; rows past the
+    groups' total are exactly zero, forward and backward."""
+    sizes = jnp.asarray(SIZES[case], jnp.int32)
+    lhs, rhs = _operands(jnp.float32, transpose)
+    assert takes_kernel(M, K, N)
+    cot = jnp.asarray(np.random.default_rng(1).normal(size=(M, N)),
+                      jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        got, vjp = jax.vjp(lambda l: _tiled(l, rhs, sizes, tiles, transpose),
+                           lhs)
+        want, plain_vjp = jax.vjp(
+            lambda l: gm.plain(l, rhs, sizes, transpose), lhs)
+        (dlhs,), (dlhs_plain,) = vjp(cot), plain_vjp(cot)
+    _close(got, want, 1e-5)
+    _close(got, _loop(lhs, rhs, SIZES[case], transpose), 1e-5)
+    _close(dlhs, dlhs_plain, 1e-5)
+    total = sum(SIZES[case])
+    assert not np.asarray(got[total:]).any()
+    assert not np.asarray(dlhs[total:]).any()
+
+
+@pytest.mark.parametrize("case", ["edges_off_the_grid", "first_groups_empty"])
+def test_bfloat16_operands_accumulate_in_float32(case):
+    """bf16 rows and matrices: a float32 result one rounding of the operands
+    away from the float32 product, and a ``dlhs`` in the rows' dtype."""
+    sizes = jnp.asarray(SIZES[case], jnp.int32)
+    lhs, rhs = _operands(jnp.bfloat16)
+    got, vjp = jax.vjp(lambda l: _tiled(l, rhs, sizes, TILES[1]), lhs)
+    assert got.dtype == jnp.float32
+    _close(got, _loop(lhs, rhs, SIZES[case]), 1e-5)
+    (dlhs,) = vjp(jnp.ones_like(got))
+    assert dlhs.dtype == jnp.bfloat16
+    want = jax.grad(lambda l: jnp.sum(gm.plain(l, rhs, sizes)))(lhs)
+    _close(dlhs, want, 1e-2)
+
+
+def _dot_shapes(jaxpr):
+    """``(operand shapes, result shape)`` of every ``dot_general``, nested
+    jaxprs (and kernels' bodies) too."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(([tuple(v.aval.shape) for v in eqn.invars],
+                          tuple(eqn.outvars[0].aval.shape)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(_dot_shapes(sub))
+    return found
+
+
+@pytest.mark.parametrize("shape", [(M, K, N), (48, 16, 24)],
+                         ids=["kernel", "plain"])
+def test_the_frozen_matrices_get_no_cotangent(shape):
+    """The backward holds no product whose result has the matrices' shape
+    (none forms their gradient), kernel and plain expression alike, and
+    differentiating by them gives zeros."""
+    m, k, n = shape
+    rng = np.random.default_rng(2)
+    lhs = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
+    rhs = jnp.asarray(rng.normal(size=(G, k, n)), jnp.float32)
+    sizes = jnp.asarray([m // 8, 0, m // 4, m // 8, m // 16], jnp.int32)
+    assert takes_kernel(m, k, n) == (shape == (M, K, N))
+
+    def loss(lhs, rhs):
+        return jnp.sum(grouped_matmul(lhs, rhs, sizes) ** 2)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(lhs, rhs)
+    dots = _dot_shapes(jaxpr.jaxpr)
+    assert dots
+    for operands, result in dots:
+        assert result != rhs.shape and result != (G, n, k), (operands, result)
+    dlhs, drhs = jax.grad(loss, (0, 1))(lhs, rhs)
+    assert np.asarray(dlhs).any() and not np.asarray(drhs).any()
+
+
+@pytest.mark.parametrize("clients", [1, 3])
+def test_under_vmap_the_frozen_matrices_have_no_client_axis(clients):
+    """Rows and group sizes batched over clients, the matrices not: a batch
+    of one is squeezed, a wider one loops over the clients; each client's
+    result is its own call's, and no value of the batched program has a
+    client axis before the matrices' shape."""
+    rng = np.random.default_rng(3)
+    lhs = jnp.asarray(rng.normal(size=(clients, M, K)), jnp.float32)
+    rhs = jnp.asarray(rng.normal(size=(G, K, N)) * 0.1, jnp.float32)
+    sizes = jnp.asarray([[100, 0, 157, 30, 60], [0, 512, 0, 0, 0],
+                         [7, 9, 0, 300, 1]][:clients], jnp.int32)
+
+    def one(lhs, sizes):
+        return jax.value_and_grad(lambda l: jnp.sum(_tiled(
+            l, rhs, sizes, TILES[0]) ** 2))(lhs)
+
+    with jax.default_matmul_precision("highest"):
+        values, grads = jax.vmap(one)(lhs, sizes)
+        for c in range(clients):
+            value, grad = one(lhs[c], sizes[c])
+            np.testing.assert_allclose(values[c], value, rtol=1e-6)
+            _close(grads[c], grad, 1e-6)
+    text = str(jax.make_jaxpr(jax.vmap(one))(lhs, sizes))
+    assert f"f32[{clients},{G},{K},{N}]" not in text
+    assert "pallas_call" in text
+
+
+@pytest.mark.parametrize("m,k,n,takes", [
+    (8192, 6144, 4096, True),       # the cell's gate and up, a chunk
+    (8192, 2048, 6144, True),       # its down projection
+    (4096, 1024, 1024, True),       # chip_smoke's
+    (128, 128, 128, True),
+    (96, 128, 128, False),          # rows under a sub-tile
+    (128, 32, 128, False),          # a toy depth
+    (128, 128, 24, False),          # a toy width
+])
+def test_takes_kernel_is_a_function_of_the_shapes(m, k, n, takes):
+    assert takes_kernel(m, k, n) is takes
+
+
+def test_small_shapes_keep_the_plain_expression():
+    """Toy widths trace no kernel, and the result is the loop's."""
+    rng = np.random.default_rng(4)
+    lhs = jnp.asarray(rng.normal(size=(48, 16)), jnp.float32)
+    rhs = jnp.asarray(rng.normal(size=(G, 16, 24)), jnp.float32)
+    sizes = [10, 0, 17, 3, 6]
+    fn = lambda l: grouped_matmul(l, rhs, jnp.asarray(sizes, jnp.int32))  # noqa: E731
+    assert "pallas_call" not in str(jax.make_jaxpr(fn)(lhs))
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(fn(lhs))
+    want, at = np.zeros((48, 24), np.float32), 0
+    for g, size in enumerate(sizes):
+        want[at:at + size] = np.asarray(lhs[at:at + size]) @ np.asarray(rhs[g])
+        at += size
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+# --- compiled for the chip, without the chip ---------------------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described v5e device (the TPU's compiler is installed; nothing
+    runs). Made inside the fixture: only the worker that is given this file
+    loads the TPU's library."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("m,k,n,transpose", [
+    (8192, 6144, 4096, False), (8192, 2048, 6144, False),
+    (8192, 4096, 6144, True), (8192, 6144, 2048, True)],
+    ids=["gate_up", "down", "gate_up_t", "down_t"])
+def test_the_kernel_compiles_for_the_v5e_at_the_cells_shapes(
+        monkeypatch, one_chip, m, k, n, transpose):
+    """K-EXAONE's held experts, a chunk of 8,192 rows, forward and ``dlhs``:
+    Mosaic takes the kernel at its own tiles (alignment, VMEM)."""
+    monkeypatch.setattr(gm, "pallas_interpret", lambda: False)
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    rhs = (16, n, k) if transpose else (16, k, n)
+    compiled = jax.jit(lambda l, r, g: grouped_matmul(
+        l, r, g, transpose_rhs=transpose, name="held_gmm")).trace(
+            spec((m, k), jnp.bfloat16), spec(rhs, jnp.bfloat16),
+            spec((16,), jnp.int32)).lower(
+                lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "held_gmm" in text

@@ -232,25 +232,25 @@ def test_a_selection_bias_selects_and_does_not_weigh():
 H, D, F, R, K, FIRST = 4, 16, 8, 2, 3, 2
 
 
-def _held_operands(seed=0, clients=3, n=48):
+def _held_operands(seed=0, clients=3, n=48, d=D, f=F):
     rng = np.random.default_rng(seed)
     mk = lambda *s: jnp.asarray(rng.normal(size=s) * 0.3, jnp.float32)  # noqa: E731
-    pairs = ep.ExpertPairs(mk(clients, H, D, R), mk(clients, H, R, F),
-                           mk(clients, H, D, R), mk(clients, H, R, F),
-                           mk(clients, H, F, R), mk(clients, H, R, D))
-    return (mk(clients, n, D), mk(H, D, 2 * F), mk(H, F, D), pairs,
+    pairs = ep.ExpertPairs(mk(clients, H, d, R), mk(clients, H, R, f),
+                           mk(clients, H, d, R), mk(clients, H, R, f),
+                           mk(clients, H, f, R), mk(clients, H, R, d))
+    return (mk(clients, n, d), mk(H, d, 2 * f), mk(H, f, d), pairs,
             jnp.asarray(rng.integers(0, 12, (clients, n, K))), jnp.abs(
                 mk(clients, n, K)))
 
 
 def _plain(x, idx, weight, w_gate_up, w_down, pairs, scale):
     """Every held expert over every token, masked by the routing."""
-    out = 0.0
+    out, f = 0.0, w_down.shape[1]
     for e in range(H):
         w_e = jnp.sum(jnp.where(idx == FIRST + e, weight, 0.0), -1)
-        gate = x @ w_gate_up[e][:, :F] + scale * (
+        gate = x @ w_gate_up[e][:, :f] + scale * (
             x @ pairs.gate_a[e]) @ pairs.gate_b[e]
-        up = x @ w_gate_up[e][:, F:] + scale * (
+        up = x @ w_gate_up[e][:, f:] + scale * (
             x @ pairs.up_a[e]) @ pairs.up_b[e]
         hidden = jax.nn.silu(gate) * up
         out = out + w_e[:, None] * (hidden @ w_down[e] + scale * (
@@ -265,12 +265,13 @@ def _product(x, idx, weight, w_gate_up, w_down, pairs, rows):
     return y, (computed, held.counts)
 
 
-@pytest.mark.parametrize("rows", [(8, 8), (16, 8), (64, 8)])
+@pytest.mark.parametrize("rows", [16, 40, 96])
 def test_the_held_product_under_vmap_is_the_loop_over_clients(rows):
     """Output and every gradient (``x``, the routing weights, the six pairs)
     of the batched call equal each client's own, which equal the plain
-    every-expert-over-every-token sum; at 8 rows an expert the fullest
-    takes two further passes, and every assignment is still computed."""
+    every-expert-over-every-token sum; at chunks of 16 rows a client takes
+    three or more, at 96 (``chunk_rows``' own) none fills its one, and every
+    assignment is still computed."""
     x, w_gate_up, w_down, pairs, idx, weight = _held_operands()
 
     def loss(x, weight, pairs, idx, fn):
@@ -296,26 +297,27 @@ def test_the_held_product_under_vmap_is_the_loop_over_clients(rows):
             assert _relative(jax.tree.map(lambda a: a[c], grads),
                              want_grads) < 1e-5
     np.testing.assert_array_equal(computed, counts.sum(-1))
-    if rows == (8, 8):
-        assert int(counts.max()) > 2 * 8        # three passes somewhere
+    if rows == 16:
+        assert int(counts.sum(-1).max()) > 2 * 16   # three chunks somewhere
+    assert int(counts.sum(-1).max()) < 96 == ep.chunk_rows(48, K, 12, H)
 
 
 def test_a_held_expert_at_three_times_the_mean_load_is_computed_whole():
     """All of a client's tokens choose held expert 1 (far over three times
-    the mean load and more than the first and a further slab hold): no
-    assignment is left out, whatever ``SLAB_FACTOR`` says."""
+    the mean load, and with the others' more than two chunks hold): no
+    assignment is left out, whatever ``CHUNK_FACTOR`` says."""
     x, w_gate_up, w_down, pairs, idx, weight = _held_operands(seed=3)
     x, pairs, idx, weight = jax.tree.map(lambda a: a[0],
                                          (x, pairs, idx, weight))
     n = x.shape[0]
     idx = idx.at[:, 0].set(FIRST + 1).at[:, 1:].set(
         jnp.where(idx[:, 1:] == FIRST + 1, 11, idx[:, 1:]))
-    # the layout a model of 48 experts would ask for: a first slab of
-    # SLAB_FACTOR times the mean, further slabs of FURTHER_FACTOR times
-    rows = ep.slab_rows(n, K, 48)
+    # the chunk a model of 48 experts would ask for: CHUNK_FACTOR times the
+    # mean number of held assignments
+    rows = ep.chunk_rows(n, K, 48, H)
     y, (computed, counts) = _product(x, idx, weight, w_gate_up, w_down, pairs,
                                      rows)
-    assert int(counts[1]) == n > rows[0] + rows[1]
+    assert int(counts[1]) == n >= 2 * rows < int(counts.sum())
     assert int(counts[1]) >= 3 * n * K / 48
     assert int(computed) == int(counts.sum())
     with jax.default_matmul_precision("highest"):
@@ -324,48 +326,56 @@ def test_a_held_expert_at_three_times_the_mean_load_is_computed_whole():
     np.testing.assert_allclose(y, want, atol=1e-5 * float(jnp.abs(want).max()))
 
 
-def _dots_with(jaxpr, shapes):
-    """``[operand shapes]`` of every ``dot_general`` (nested jaxprs too) one
-    of whose operands has a shape in ``shapes``."""
+def _eqns_with(jaxpr, shapes, name, results=False):
+    """``[shapes]`` of the operands (of the results, with ``results``) of
+    every equation of primitive ``name`` (nested jaxprs too) one of which
+    has a shape in ``shapes``."""
     found = []
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "dot_general":
-            operands = [tuple(v.aval.shape) for v in eqn.invars]
-            if any(s in shapes for s in operands):
-                found.append(operands)
+        if eqn.primitive.name == name:
+            values = [tuple(v.aval.shape)
+                      for v in (eqn.outvars if results else eqn.invars)]
+            if any(s in shapes for s in values):
+                found.append(values)
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            found.extend(_dots_with(sub, shapes))
+            found.extend(_eqns_with(sub, shapes, name, results))
     return found
 
 
-def test_the_batched_call_reads_the_frozen_matrices_in_place():
-    """Under ``vmap`` over clients, forward and backward: every product with
-    an expert matrix takes it as it lies, ``[H, d, 2f]`` / ``[H, f, d]``
-    with no client axis, and nothing gathers, slices or copies it (its only
-    uses are those products)."""
-    x, w_gate_up, w_down, pairs, idx, weight = _held_operands()
+@pytest.mark.parametrize("d,f,rows,user", [
+    (D, F, 24, "dot_general"), (128, 256, 128, "pallas_call")],
+    ids=["plain", "kernel"])
+def test_the_batched_call_reads_the_frozen_matrices_in_place(d, f, rows, user):
+    """Under ``vmap`` over clients, forward and backward: every grouped
+    product with an expert matrix (the plain masked ``dot_general`` at toy
+    widths, the kernel at whole lanes) takes it as it lies, ``[H, d, 2f]`` /
+    ``[H, f, d]`` with no client axis, and nothing gathers, slices or copies
+    it (its only uses are those products); no product's result has a
+    matrix's shape, so none forms its gradient."""
+    x, w_gate_up, w_down, pairs, idx, weight = _held_operands(d=d, f=f)
 
     def step(x, idx, weight, pairs, w_gate_up, w_down):
-        # 24 rows a slab: no activation has an expert matrix's shape
+        # no activation has an expert matrix's shape at these rows a chunk
         return jax.grad(lambda x, p: jnp.sum(_product(
-            x, idx, weight, w_gate_up, w_down, p, (24, 24))[0] ** 2), (0, 1))(
+            x, idx, weight, w_gate_up, w_down, p, rows)[0] ** 2), (0, 1))(
                 x, pairs)
 
     jaxpr = jax.make_jaxpr(jax.vmap(
         step, in_axes=(0, 0, 0, 0, None, None)))(
             x, idx, weight, pairs, w_gate_up, w_down)
     frozen = {tuple(w_gate_up.shape), tuple(w_down.shape)}
-    dots = _dots_with(jaxpr.jaxpr, frozen)
-    # first pass forward and backward, and the further passes' loops
-    assert len(dots) >= 8
+    # first chunk forward and backward, and the further chunks' loops
+    assert len(_eqns_with(jaxpr.jaxpr, frozen, user)) >= 8
+    assert not _eqns_with(jaxpr.jaxpr, frozen, "dot_general", results=True)
     batched = {(x.shape[0],) + s for s in frozen}
     text = str(jaxpr)
     for s in batched:
         assert "f32[" + ",".join(map(str, s)) + "]" not in text
     # the matrices are used by products alone (and handed on into the
-    # custom backward and its loops): nothing gathers, slices or copies them
+    # custom backward, its loops and the batched kernel's loop over clients,
+    # a ``scan`` that takes them whole): nothing gathers, slices or copies them
     assert _users_of(jaxpr.jaxpr, frozen) <= {
-        "dot_general", "while", "custom_vjp_call", "custom_vjp_call_jaxpr",
+        user, "while", "scan", "custom_vjp_call", "custom_vjp_call_jaxpr",
         "pjit", "jit", "closed_call", "convert_element_type"}
 
 
