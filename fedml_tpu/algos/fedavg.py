@@ -945,7 +945,13 @@ class FedAvgAPI(FederatedLoop):
         return reg.snapshot() if reg is not None else {}
 
     # --- what the streamed round dispatched ------------------------------
-    def _count_dispatch(self, groups: int, slots: int, idx, wmask) -> None:
+    def _real_samples(self, idx, wmask) -> np.ndarray:
+        """``[k]`` real samples of the sampled cohort's slots (0 at a pad
+        slot), on the host: what the dispatch spans' ``samples`` and the
+        ``samples_real`` counter are both summed from."""
+        return self._host_counts()[np.asarray(idx)] * np.asarray(wmask)
+
+    def _count_dispatch(self, groups: int, slots: int, samples: int) -> None:
         reg = getattr(self, "_dispatch_registry", None)
         if reg is None:
             from fedml_tpu.obs.registry import MetricsRegistry
@@ -954,8 +960,7 @@ class FedAvgAPI(FederatedLoop):
         reg.counter("rounds_streamed").inc()
         reg.counter("groups_dispatched").inc(groups)
         reg.counter("slots_dispatched").inc(slots)
-        reg.counter("samples_real").inc(int(
-            (self._host_counts()[np.asarray(idx)] * np.asarray(wmask)).sum()))
+        reg.counter("samples_real").inc(samples)
 
     def dispatch_profile(self) -> Dict[str, int]:
         """Running totals of what the streamed rounds put on the device
@@ -1061,6 +1066,7 @@ class FedAvgAPI(FederatedLoop):
         checkpoints and remainder/eval host work read the new state.
         Returns the round's (device) loss."""
         pre, gather = self._fused_round_step()
+        dispatched = {}     # a streamed round: the slots and samples it trains
         with span("fed.round.sample", round=round_idx):
             self.rng, rnd_rng = jax.random.split(self.rng)
             self._last_round_key = rnd_rng
@@ -1078,8 +1084,10 @@ class FedAvgAPI(FederatedLoop):
                     return self._train_round_size_grouped(
                         round_idx, idx, wmask, rnd_rng, extra, group)
                 sub = self._stream_cohort(round_idx, idx)
-                self._count_dispatch(
-                    1, len(idx) * sub.x.shape[1] * sub.x.shape[2], idx, wmask)
+                dispatched = {
+                    "slots": len(idx) * sub.x.shape[1] * sub.x.shape[2],
+                    "samples": int(self._real_samples(idx, wmask).sum())}
+                self._count_dispatch(1, **dispatched)
             else:
                 from fedml_tpu.data.batching import gather_clients
 
@@ -1089,7 +1097,7 @@ class FedAvgAPI(FederatedLoop):
             weights = sub.counts.astype(jnp.float32) * jnp.asarray(wmask)
             operands = (sub.x, sub.y, sub.mask, weights, rnd_rng, *aux)
             step = pre
-        with span("fed.round.dispatch", round=round_idx):
+        with span("fed.round.dispatch", round=round_idx, **dispatched):
             (self.net, extra), loss = step(self.net, extra, *operands)
         self._window_carry_commit(extra)
         self._emit_reduce_obs()
@@ -1188,22 +1196,27 @@ class FedAvgAPI(FederatedLoop):
         whole-cohort round; the padding is what is left out."""
         init, group_step, finish = self._size_group_steps()
         groups = self._stream_cohort(round_idx, idx, group)
+        # What each group's dispatch trains, from the host's own copy of the
+        # plan the groups were gathered by (their ``slots`` are on the
+        # device): the spans' arguments and the registry's counters are the
+        # same numbers.
+        real = self._real_samples(idx, wmask)
+        slots_of = [group * g.steps * self.cfg.batch_size for g in groups]
+        samples_of = [int(real[members].sum()) for members, _ in
+                      self.train_fed.plan_groups(idx, group)]
         with span("fed.round.dispatch", round=round_idx):
             on_device = jnp.asarray(wmask, jnp.float32)
             carry = init(self.net)
         for j, (fed, slots, steps) in enumerate(groups):
             with span("fed.round.dispatch", round=round_idx, group=j,
-                      steps=steps):
+                      steps=steps, slots=slots_of[j], samples=samples_of[j]):
                 carry = group_step(self.net, carry, fed.x, fed.y, fed.mask,
                                    fed.counts, slots, on_device, key)
         with span("fed.round.dispatch", round=round_idx):
             (self.net, extra), loss = finish(self.net, extra, carry, key)
         self._window_carry_commit(extra)
         self._emit_reduce_obs()
-        self._count_dispatch(
-            len(groups),
-            sum(group * g.steps for g in groups) * self.cfg.batch_size,
-            idx, wmask)
+        self._count_dispatch(len(groups), sum(slots_of), sum(samples_of))
         return loss
 
     def train_one_round(self, round_idx: int) -> Dict[str, float]:

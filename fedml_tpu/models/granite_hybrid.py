@@ -28,7 +28,12 @@ Device scopes (``jax.named_scope``, read by the benchmark's reducers):
 ``fed.model.ssm`` (``.conv``, ``.scan``), ``fed.model.attn`` (``.core``),
 ``fed.model.mlp``, ``fed.model.lora`` (every low-rank pair's two products),
 ``fed.model.head`` (embedding, final norm, tied head; ``token_ce`` puts the
-loss there too). Where a projection's shapes take ``ops/lora_linear.py``'s
+loss there too), ``fed.model.norm`` (a layer's input norm, the mixer's
+residual add and the post norm: the residual stream between the branches)
+and ``fed.model.stack`` around the scan over periods (the stacked weights'
+slices and the loop's carry; a layer's operations keep their innermost
+scope), both read by ``benchmark/reduce_booked.py``, which lists no scope.
+Where a projection's shapes take ``ops/lora_linear.py``'s
 kernel (Granite 4.0-H Micro at 1,024 tokens a client: ``input_linear``) the
 forward's frozen product, the pair's second product, the sum, the gate and
 the cast are ONE Mosaic call, booked under the layer it stands in
@@ -205,15 +210,17 @@ class GraniteHybridLayer(_Layer):
         c = self.cfg
         w_in = self.base("input_norm", nn.initializers.ones, (c.hidden_size,))
         w_post = self.base("post_norm", nn.initializers.ones, (c.hidden_size,))
-        h = rms_norm(x, w_in, c.rms_norm_eps).astype(self.dtype)
+        with jax.named_scope("fed.model.norm"):
+            h = rms_norm(x, w_in, c.rms_norm_eps).astype(self.dtype)
         if self.kind == "attention":
             with jax.named_scope("fed.model.attn"):
                 mixed = Attention(c, self.dtype, name="mixer")(h)
         else:
             with jax.named_scope("fed.model.ssm"):
                 mixed = Mamba2Mixer(c, self.dtype, name="mixer")(h)
-        x = x + c.residual_multiplier * mixed
-        h = rms_norm(x, w_post, c.rms_norm_eps).astype(self.dtype)
+        with jax.named_scope("fed.model.norm"):
+            x = x + c.residual_multiplier * mixed
+            h = rms_norm(x, w_post, c.rms_norm_eps).astype(self.dtype)
         with jax.named_scope("fed.model.mlp"):
             return x + c.residual_multiplier * SharedMLP(
                 c, self.dtype, name="mlp")(h)
@@ -311,9 +318,14 @@ class GraniteHybrid(nn.Module):
             x = c.embedding_multiplier * jnp.take(
                 embedding, ids, axis=0).astype(F32)
         periods = c.num_hidden_layers // len(c.period)
-        x, _ = nn.scan(
-            _Period, variable_axes={"params": 0}, split_rngs={"params": True},
-            length=periods)(c, self.dtype, name="periods")(x, None)
+        # the period loop's own work: the stacked weights' slices, its carry
+        # and what of its body names no scope (the residual stream's
+        # gradient sums); a layer's operations keep their innermost scope
+        with jax.named_scope("fed.model.stack"):
+            x, _ = nn.scan(
+                _Period, variable_axes={"params": 0},
+                split_rngs={"params": True},
+                length=periods)(c, self.dtype, name="periods")(x, None)
         with jax.named_scope("fed.model.head"):
             w_norm = self.param("final_norm", nn.initializers.ones,
                                 (c.hidden_size,), c.base_dtype)

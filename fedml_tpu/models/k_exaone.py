@@ -39,7 +39,10 @@ kernel alone), ``fed.model.mlp`` (the dense layer's), ``fed.model.moe``
 (``.route``, ``.experts``, ``.shared``), ``fed.model.lora`` (every pair's two
 products but the held experts', which are grouped by the experts' own
 assignment and are part of ``fed.model.moe.experts``), ``fed.model.head``
-(embedding, final norm, head; ``token_ce`` puts the loss there too).
+(embedding, final norm, head; ``token_ce`` puts the loss there too),
+``fed.model.norm`` (the second branch's output norm and its residual add;
+the first branch's are the attention's; read by
+``benchmark/reduce_booked.py``, which lists no scope).
 """
 
 from __future__ import annotations
@@ -252,7 +255,8 @@ class KExaoneLayer(_Layer):
             with jax.named_scope("fed.model.mlp"):
                 branch = GatedMLP(c, self.dtype, c.intermediate_size,
                                   name="mlp")(x.astype(self.dtype))
-        return x + rms_norm(branch, w_ffn, c.rms_norm_eps)
+        with jax.named_scope("fed.model.norm"):
+            return x + rms_norm(branch, w_ffn, c.rms_norm_eps)
 
 
 @dataclasses.dataclass(frozen=True)

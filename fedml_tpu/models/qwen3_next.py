@@ -28,7 +28,9 @@ Device scopes (``jax.named_scope``, read by ``benchmark/reduce_scopes.py``):
 ``fed.model.gdn`` (``.scan`` around the chunked rule), ``fed.model.attn``
 (``.core`` around the softmax attention), ``fed.model.moe`` (``.route``,
 ``.experts``, ``.shared``), ``fed.model.head`` (embedding, final norm, head;
-``token_ce`` puts the loss there too). Counters, a layer: the ``counters``
+``token_ce`` puts the loss there too), ``fed.model.norm`` (a layer's input
+norm, the one piece of the residual stream outside the mixers' scopes; read
+by ``benchmark/reduce_booked.py``, which lists no scope). Counters, a layer: the ``counters``
 collection's running totals ``expert_tokens [H]``, ``unrouted_tokens``,
 ``uncomputed_tokens`` (held assignments the layer did not compute: dropped
 tokens, 0 or the layer is wrong) and ``dense_arm_calls`` (calls in which the
@@ -273,7 +275,8 @@ class Qwen3NextLayer(nn.Module):
                           (c.hidden_size,))
         w_post = self.param("post_norm", nn.initializers.zeros,
                             (c.hidden_size,))
-        h = rms_norm(x, w_in, c.rms_norm_eps).astype(self.dtype)
+        with jax.named_scope("fed.model.norm"):
+            h = rms_norm(x, w_in, c.rms_norm_eps).astype(self.dtype)
         if self.full_attention:
             with jax.named_scope("fed.model.attn"):
                 x = x + GatedAttention(c, self.dtype, name="mixer")(h)

@@ -252,10 +252,14 @@ def fold_client_groups(local_train, nan_guard, group, params, x, y, mask,
         return fold_group(local_train, nan_guard, params, carry, *operands,
                           unbatched=group == 1)
 
-    (acc, sum_w, sum_loss, sum_lw), losses = jax.lax.scan(
-        body, fold_init(params), tuple(grouped(a) for a in (
-            x, y, mask, weights, loss_weights, rngs)))
-    return acc, sum_w, sum_loss, sum_lw, losses.reshape(n)
+    # The loop's container, its empty carry and what XLA copies around it
+    # get a name of their own; the body's operations keep theirs, which are
+    # the LAST phase and the innermost scope of their path.
+    with jax.named_scope("fed.client_groups"):
+        (acc, sum_w, sum_loss, sum_lw), losses = jax.lax.scan(
+            body, fold_init(params), tuple(grouped(a) for a in (
+                x, y, mask, weights, loss_weights, rngs)))
+        return acc, sum_w, sum_loss, sum_lw, losses.reshape(n)
 
 
 def _grouped_mean(params, acc, sum_w, sum_loss, sum_lw):
@@ -637,7 +641,11 @@ def make_size_group_round(local_train, nan_guard: bool = False,
             new_net, new_extra = server_update(net, avg, extra, key)
         return (new_net, new_extra), loss
 
-    return fold_init, group_step, finish
+    def init(net):
+        with jax.named_scope("fed.client_groups"):
+            return fold_init(net)
+
+    return init, group_step, finish
 
 
 def make_fused_stateful_round_step(round_fn):

@@ -238,17 +238,19 @@ def make_corrected_local_train(apply_fn, local_epochs: int, loss_fn,
                 masked_loss = jax.checkpoint(masked_loss)
             (loss, new_state), grads = jax.value_and_grad(
                 masked_loss, has_aux=True)(net.params)
-            new_params = step_update(net.params, grads, aux)
-            nb = jnp.sum(mb)
-            new_net = tree_select(nb > 0, NetState(new_params, new_state),
-                                  net)
+            with jax.named_scope("fed.step.update"):
+                new_params = step_update(net.params, grads, aux)
+                nb = jnp.sum(mb)
+                new_net = tree_select(
+                    nb > 0, NetState(new_params, new_state), net)
             return (new_net, step_base), (loss, nb)
 
         def epoch(carry, epoch_rng):
             # Same fold_in(·, 0)/(·, 1) forks as make_local_train_fn.
-            reshuffle = make_epoch_shuffle(
-                mask, jax.random.fold_in(epoch_rng, 0))
-            ex, ey, em = reshuffle(x), reshuffle(y), reshuffle(mask)
+            with jax.named_scope("fed.step.shuffle"):
+                reshuffle = make_epoch_shuffle(
+                    mask, jax.random.fold_in(epoch_rng, 0))
+                ex, ey, em = reshuffle(x), reshuffle(y), reshuffle(mask)
             net, _ = carry
             step_base = jax.random.fold_in(epoch_rng, 1)
             carry, (losses, ns) = jax.lax.scan(
@@ -359,22 +361,25 @@ def make_local_train_fn(
             if extra_grad_fn is not None:
                 extra = extra_grad_fn(net.params, global_params)
                 grads = jax.tree.map(jnp.add, grads, extra)
-            updates, new_opt = optimizer.update(grads, opt_state, net.params)
-            new_params = optax.apply_updates(net.params, updates)
-            nb = jnp.sum(mb)
-            nonempty = nb > 0
-            new_net = NetState(new_params, new_state)
-            net = tree_select(nonempty, new_net, net)
-            opt_state = tree_select(nonempty, new_opt, opt_state)
+            with jax.named_scope("fed.step.update"):
+                updates, new_opt = optimizer.update(grads, opt_state,
+                                                    net.params)
+                new_params = optax.apply_updates(net.params, updates)
+                nb = jnp.sum(mb)
+                nonempty = nb > 0
+                new_net = NetState(new_params, new_state)
+                net = tree_select(nonempty, new_net, net)
+                opt_state = tree_select(nonempty, new_opt, opt_state)
             return (net, opt_state, step_base), (loss, nb)
 
         def epoch(carry, epoch_rng):
             if shuffle:
                 # fold_in(·, 0): the shuffle keys and the step streams
                 # must fork from DISJOINT children of the epoch key.
-                reshuffle = make_epoch_shuffle(
-                    mask, jax.random.fold_in(epoch_rng, 0))
-                ex, ey, em = reshuffle(x), reshuffle(y), reshuffle(mask)
+                with jax.named_scope("fed.step.shuffle"):
+                    reshuffle = make_epoch_shuffle(
+                        mask, jax.random.fold_in(epoch_rng, 0))
+                    ex, ey, em = reshuffle(x), reshuffle(y), reshuffle(mask)
             else:
                 ex, ey, em = x, y, mask
             net, opt_state, _ = carry

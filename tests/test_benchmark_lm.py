@@ -8,6 +8,7 @@ nine readers against the manifest. The same of the Granite 4.0-H adapter cell
 import importlib.util
 import json
 import os
+import re
 
 import jax
 import numpy as np
@@ -924,3 +925,40 @@ def test_kexaone_dryrun_sizes_name_every_kind_of_layer(kexaone):
     assert kwargs["mlp_layer_types"] == ["dense"] + ["sparse"] * 4
     assert kwargs["num_experts_held"] < kwargs["num_experts"]
     assert kexaone["dryrun"]["classes"] == kwargs["vocab_size"] == 257
+
+
+# --- PR 36: the residual stream's own work has a scope ------------------------
+
+@pytest.mark.parametrize("name, sites", [
+    # the layer-input norm (the post-mixer norm is the expert layer's)
+    ("qwen3_next_80b_a3b", 1),
+    # the layer-input norm; the mixer's residual add with the post norm
+    ("granite_4_0_h_micro", 2),
+    # the second branch's output norm with its residual add (the first is
+    # the attention's)
+    ("k_exaone_236b_a23b", 1)])
+def test_the_toy_forward_names_the_residual_streams_scope(name, sites):
+    """``fed.model.norm`` in each LM model's lowered forward, at the dryrun
+    sizes: once a site and kind of layer, and no accepted reader lists it."""
+    import importlib
+
+    config = _json("benchmark", "configs", name + ".json")
+    module, _, attr = config["factory"].rpartition(".")
+    model = getattr(importlib.import_module(module), attr)(
+        **config["dryrun"]["factory_kwargs"])
+    ids = jax.ShapeDtypeStruct((1, 32), np.int32)
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), np.zeros((1, 32), np.int32)))
+    text = jax.jit(model.apply).lower(variables, ids).as_text(debug_info=True)
+    named = set(re.findall(r'"([^"]*fed\.model\.norm[^"]*)"', text))
+    assert named, "no op under fed.model.norm"
+    layers = {re.sub(r"fed\.model\.norm.*", "", p) for p in named}
+    assert len(layers) >= sites
+    # the scan over stacked periods has a scope of its own (Granite only)
+    assert ("fed.model.stack" in text) == (name == "granite_4_0_h_micro")
+    for reducer in ("reduce_scopes.py", "reduce_scopes_hybrid.py",
+                    "reduce_scopes_swa_moe.py"):
+        assert not {"fed.model.norm", "fed.model.stack"} & set(
+            _load(reducer).SCOPES)
+    assert _load("reduce_scopes.py").scope_of(
+        ["jit(f)/fed.local_train/layer_0/fed.model.norm/mul"]) == ""

@@ -1,6 +1,8 @@
 """The simulator round's spans (``obs.trace.span``: ``fed.*``) and their
 reduction beside the benchmark (``benchmark/reduce_spans.py`` and the readers
-under ``benchmark/layer_metrics/`` that call it).
+under ``benchmark/layer_metrics/`` that call it); since PR 36 also the device
+scopes of the step and of the group loop, the dispatch spans' ``slots`` /
+``samples``, and ``benchmark/reduce_booked.py`` with its readers.
 
 CPU only: the spans are read back from ``jax.profiler``'s own trace, which
 holds host annotations on any backend; device time by phase and the idle
@@ -8,12 +10,15 @@ partition are checked on a hand-made event list and on two rounds recorded
 on the chip (``benchmark/fixtures/femnist_rounds.spans.json.gz``).
 """
 
+import contextlib
 import glob
 import importlib.util
 import json
 import os
+import re
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -51,7 +56,7 @@ def rs():
     return _load(os.path.join(BENCHMARK, "reduce_spans.py"), "reduce_spans")
 
 
-def _api(placement: str) -> FedAvgAPI:
+def _api(placement: str, **cfg_more) -> FedAvgAPI:
     rng = np.random.default_rng(0)
     clients, per_client = 12, 16
     x = rng.normal(size=(clients * per_client, 6)).astype(np.float32)
@@ -68,7 +73,8 @@ def _api(placement: str) -> FedAvgAPI:
 
             mesh = client_mesh(4)
     cfg = FedConfig(client_num_in_total=clients, client_num_per_round=4,
-                    comm_round=100, epochs=1, batch_size=8, lr=0.1, seed=0)
+                    comm_round=100, epochs=1, batch_size=8, lr=0.1, seed=0,
+                    **cfg_more)
     return FedAvgAPI(LogisticRegression(num_classes=3), fed, None, cfg,
                      mesh=mesh)
 
@@ -451,3 +457,340 @@ def test_readers_on_the_recorded_chip_rounds(rs, monkeypatch):
     idle = sum(want["derived"][n] for n in NEW_READERS
                if n.startswith("idle_ms."))
     assert idle == pytest.approx(got["idle_ns"] / got["rounds"] / 1e6)
+
+
+# --- PR 36: the step's and the group loop's scopes, the booking reader -------
+
+STEP_SCOPES = ("fed.step.update", "fed.step.shuffle")
+GROUP_SCOPE = "fed.client_groups"
+MODEL_SCOPES = ("fed.model.norm", "fed.model.stack")
+BOOKED_READERS = [
+    "device_ms.unbooked.round", "device_ms.loop_self.round",
+    "device_ms.step_update.round", "device_ms.client_groups.round",
+    "device_ms.remat.round", "dispatched_fill_pct", "prefetch_busy_pct"]
+
+
+@pytest.fixture(scope="module")
+def rb():
+    return _load(os.path.join(BENCHMARK, "reduce_booked.py"), "reduce_booked")
+
+
+def _lowered_round(api):
+    """The fused round of a resident toy (gather, train, aggregate: one
+    program), lowered for the operands ``train_one_round`` hands it."""
+    _, gather = api._fused_round_step()
+    idx, wmask = api.sample_round(0)
+    return gather.lower(api.net, api._window_carry_init(), api.train_fed,
+                        jnp.asarray(idx), jnp.asarray(wmask), api.rng)
+
+
+def _op_names(lowered) -> list:
+    """Every instruction's ``op_name`` path in the COMPILED program (the
+    CPU's): what a trace's event metadata carries, transforms and loops
+    spelled out. (The ``add`` inside a ``reduce``'s own computation keeps
+    the innermost fragment only; it is no instruction of the timeline.)"""
+    return [p for p in re.findall(r'op_name="([^"]*)"',
+                                  lowered.compile().as_text())
+            if p.startswith("jit(")]
+
+
+@pytest.mark.parametrize("group", [0, 1])
+def test_the_lowered_round_names_the_steps_and_the_group_loops_scopes(
+        group, rs, rb):
+    lowered = _lowered_round(_api("resident", client_group_size=group))
+    text = lowered.as_text(debug_info=True)
+    for scope in STEP_SCOPES:
+        assert scope in text, scope
+    assert (GROUP_SCOPE in text) == bool(group)
+    paths = _op_names(lowered)
+    updates = [p for p in paths if "fed.step.update" in p]
+    assert updates and all(rs.phase_of([p]) == "fed.local_train"
+                           and rb.names_of(p)[-1] == "fed.step.update"
+                           for p in updates)
+    if not group:
+        return
+    # the loop's own operations end in the new name; its body's keep the
+    # phase they had: fed.local_train / fed.aggregate is still the LAST phase
+    inside = [p for p in paths if GROUP_SCOPE in p]
+    own = [p for p in inside if rb.names_of(p)[-1] == GROUP_SCOPE]
+    assert own and all(rs.phase_of([p]) == "" for p in own)
+    body = [p for p in inside if GROUP_SCOPE + "/while/body/" in p
+            and rs.phase_of([p])]
+    assert {rs.phase_of([p]) for p in body} == {"fed.local_train",
+                                                "fed.aggregate"}
+    assert all(p.index(GROUP_SCOPE) < p.index(rs.phase_of([p]))
+               for p in body)
+    fold = [p for p in body if "fed.client_fold" in p]
+    assert fold and all(rb.names_of(p)[-1] == "fed.client_fold"
+                        for p in fold)
+
+
+@pytest.mark.parametrize("group", [0, 1])
+def test_a_named_scope_is_metadata_the_program_is_the_same(group,
+                                                           monkeypatch):
+    """The round lowered with every ``jax.named_scope`` a no-op is the same
+    program, text for text, once locations are left out."""
+    scoped = _lowered_round(
+        _api("resident", client_group_size=group)).as_text()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = _lowered_round(_api("resident", client_group_size=group))
+    assert "fed.step.update" not in plain.as_text(debug_info=True)
+    assert plain.as_text() == scoped
+
+
+def test_the_pass_strings_of_this_jax(rb):
+    """``reduce_booked.pass_of`` on a ``jax.checkpoint`` + ``jax.grad`` toy
+    inside a scan, as the round's step is: the forward computed again lies
+    under ``rematted_computation`` (INSIDE ``transpose(jvp(...))``), the
+    backward under ``transpose(`` alone, the first forward under neither. A
+    jax that renames them fails here and not in a metric."""
+    def layer(w, x):
+        with jax.named_scope("fed.model.gdn"):
+            return jnp.tanh(x @ w)
+
+    def loss(w, x):
+        f = jax.checkpoint(layer)
+        return jnp.sum(f(w, f(w, x)))
+
+    def local_train(w, xs):
+        def step(w, x):
+            value, grad = jax.value_and_grad(loss)(w, x)
+            with jax.named_scope("fed.step.update"):
+                return w - 0.1 * grad, value
+
+        with jax.named_scope("fed.local_train"):
+            return jax.lax.scan(step, w, xs)
+
+    lowered = jax.jit(local_train).lower(jnp.ones((8, 8)),
+                                         jnp.ones((3, 4, 8)))
+    by_pass = {}
+    for path in _op_names(lowered):
+        by_pass.setdefault(rb.pass_of(path), []).append(path)
+    tanh = {k: [p for p in v if p.endswith("/tanh")]
+            for k, v in by_pass.items()}
+    assert set(by_pass) == {rb.FORWARD, rb.REMAT, rb.BACKWARD}
+    assert tanh[rb.FORWARD] and tanh[rb.REMAT] and not tanh[rb.BACKWARD]
+    assert all("jvp(fed.model.gdn)" in p for p in tanh[rb.FORWARD])
+    assert all("transpose(jvp(" in p and "/checkpoint/rematted_computation/"
+               in p for p in tanh[rb.REMAT])
+    assert all("transpose(jvp(" in p for p in by_pass[rb.BACKWARD])
+    # every pass keeps the scope's name, and the update is forward work
+    assert all("fed.model.gdn" in p for p in tanh[rb.REMAT])
+    assert any("fed.step.update" in p for p in by_pass[rb.FORWARD])
+    assert not any("fed.step.update" in p
+                   for p in by_pass[rb.REMAT] + by_pass[rb.BACKWARD])
+
+
+def _accepted_names(rs):
+    lm = _load(os.path.join(BENCHMARK, "reduce_scopes.py"), "rsc_names")
+    hybrid = _load(os.path.join(BENCHMARK, "reduce_scopes_hybrid.py"),
+                   "rsh_names")
+    swa = _load(os.path.join(BENCHMARK, "reduce_scopes_swa_moe.py"),
+                "rsm_names")
+    return sorted(set(lm.SCOPES) | set(hybrid.SCOPES) | set(swa.SCOPES)
+                  | set(rs.PHASES))
+
+
+NEW_SCOPES = STEP_SCOPES + (GROUP_SCOPE,) + MODEL_SCOPES
+
+
+@pytest.mark.parametrize("new", NEW_SCOPES)
+def test_no_new_name_is_a_prefix_of_an_accepted_one_or_the_reverse(new, rs):
+    """``reduce_scopes._SCOPE`` and ``reduce_spans._PHASE`` match by
+    substring: a ``fed.model.head_norm`` would be read as
+    ``fed.model.head``."""
+    accepted = _accepted_names(rs)
+    assert len(accepted) >= 20
+    for old in accepted:
+        assert not new.startswith(old), (new, old)
+        assert not old.startswith(new), (new, old)
+    assert rs.phase_of([f"jit(f)/{new}/add"]) == ""
+    for other in NEW_SCOPES:
+        assert other == new or not other.startswith(new)
+
+
+def _booked_toy():
+    """One round, window 0..1000. A group loop ``while.1`` (100..700) under
+    ``fed.client_groups`` holds a step loop ``while.2`` (120..620) under a
+    bare ``fed.local_train``; the body: a forward gdn op, a recomputed one,
+    a backward one with no scope, the update, and 100 ns of gaps that are
+    the step loop's own; 100 ns of the group loop are its own. Outside: a
+    copy XLA named nothing, an op whose path names transforms only, the
+    aggregate, and a ``call`` with a nested op."""
+    lt = "jit(step)/fed.client_groups/while/body/fed.local_train/"
+    back = lt + "while/body/closed_call/transpose(jvp(jvp()))/"
+    return [
+        ["host", "bench.round", 0, 900, ""], ["host", "fed.round", 5, 890, ""],
+        ["host", "bench.fence", 900, 100, ""],
+        ["op", "while.1 = (f32[8]) while(f32[8] %a)", 100, 600,
+         "jit(step)/fed.client_groups/while"],
+        ["op", "while.2 = (f32[8]) while(f32[8] %b)", 120, 500,
+         lt + "while"],
+        ["op", "fusion.3", 130, 100,
+         lt + "while/body/closed_call/jvp(fed.model.gdn)/dot_general"],
+        ["op", "fusion.4", 240, 100, back
+         + "checkpoint/rematted_computation/fed.model.gdn/dot_general"],
+        ["op", "fusion.5", 350, 100, back + "checkpoint/mul"],
+        ["op", "add_select_fusion.6", 460, 100,
+         lt + "while/body/fed.step.update/select_n"],
+        ["op", "fusion.7", 640, 40, "jit(step)/fed.client_groups/while/body/"
+         "fed.aggregate/fed.client_fold/add"],
+        ["op", "copy.8", 720, 30, ""],
+        ["op", "copy-done.13", 750, 10, "jit(step)/jit(round_fn)/while:"],
+        ["op", "fusion.9", 760, 20, "jit(step)/jit(_take)/gather"],
+        ["op", "fusion.10", 790, 50, "jit(step)/fed.aggregate/div"],
+        ["op", "call.11 = f32[8] call()", 850, 60,
+         "jit(step)/fed.aggregate/call"],
+        ["op", "fusion.12", 860, 40, "jit(step)/fed.aggregate/mul"],
+        ["span", "fed.round.dispatch", 10, 5,
+         {"round": 3, "group": 0, "steps": 2, "slots": 160, "samples": 116}],
+        ["span", "fed.round.dispatch", 20, 5,
+         {"round": 3, "group": 1, "steps": 4, "slots": 320, "samples": 100}],
+        ["span", "fed.round.dispatch", 30, 5, {"round": 3}],
+        ["span", "fed.round.dispatch", 1200, 5,
+         {"round": 4, "slots": 999, "samples": 999}],    # past the window
+    ]
+
+
+def test_reduce_booked_on_a_hand_made_trace(rb):
+    r = rb.reduce(_booked_toy())
+    assert r["rounds"] == 1 and r["device"]
+    # booked: gdn forward and recomputed, the update, the fold, the two
+    # aggregate ops; unbooked: the bare backward op, the copy, the gather
+    assert r["booked_ns"] == 100 + 100 + 100 + 40 + 50 + 40
+    assert r["unbooked_ns"] == 100 + 30 + 10 + 20
+    assert r["unbooked_ns_by_class"] == {"bare": 100, "empty": 30,
+                                         "loop": 10, "transforms": 20}
+    # a while's gaps land in the container, by its innermost name
+    assert r["container_ns"] == {
+        "fed.aggregate": 20, "fed.client_groups": 600 - 500 - 40,
+        "fed.local_train": 500 - 400}
+    busy = 600 + 30 + 10 + 20 + 50 + 60
+    assert (r["booked_ns"] + r["unbooked_ns"]
+            + sum(r["container_ns"].values())) == busy
+    assert r["pass_ns"] == {"backward": 100, "forward": busy - 200,
+                            "recomputed": 100}
+    assert sum(r["pass_ns"].values()) == busy
+    assert sum(r["innermost_ns"].values()) == busy
+    assert r["innermost_ns"]["fed.step.update"] == 100
+    assert r["innermost_ns"]["fed.client_groups"] == 60
+    assert r["innermost_ns"]["fed.model.gdn"] == 200
+    assert r["innermost_ns"][""] == 60
+    assert r["named"] == ["fed.aggregate", "fed.client_fold",
+                          "fed.client_groups", "fed.local_train",
+                          "fed.model.gdn", "fed.step.update"]
+    assert r["unbooked_ops"][0][:4] == ["bare", "fusion:mul", 100, 1]
+    assert r["dispatch_args"] == {"spans": 3, "round": 9, "group": 1,
+                                  "steps": 6, "slots": 480, "samples": 216}
+    said = rb.table(r)
+    assert "fed.client_groups" in said and "empty" in said
+    # no window, and a CPU rehearsal's trace: host spans, no device op
+    assert rb.reduce([e for e in _booked_toy() if e[0] != "host"]) is None
+    host_only = rb.reduce([e for e in _booked_toy() if e[0] != "op"])
+    assert not host_only["device"] and host_only["dispatch_args"]["slots"] \
+        == 480
+    assert rb.is_container("while.12 = (s32[], f32[8]) while(")
+    assert rb.is_container("conditional.3") and rb.is_container("call.1")
+    assert not rb.is_container("while_fusion.3") \
+        and not rb.is_container("fusion.2") \
+        and not rb.is_container("call-start.1")
+    assert rb.path_of(["a.py:3", "jit(f)/mul", "x"]) == "jit(f)/mul"
+    assert rb.path_of(["a.py:3"]) == ""
+
+
+def test_booked_readers_on_a_reduction(rb, monkeypatch):
+    r = rb.reduce(_booked_toy())
+    spans = {"spans": {"fed.cohort.prefetch": {"total_ns": 600}},
+             "window_ns": 1000, "rounds": 1}
+    want = {"device_ms.unbooked.round": 160e-6,
+            "device_ms.loop_self.round": 180e-6,
+            "device_ms.step_update.round": 100e-6,
+            "device_ms.client_groups.round": 60e-6,
+            "device_ms.remat.round": 100e-6,
+            "dispatched_fill_pct": 100.0 * 216 / 480,
+            "prefetch_busy_pct": 60.0}
+    assert sorted(want) == sorted(BOOKED_READERS)
+    for name, value in want.items():
+        reader = _reader(name)
+        if hasattr(reader, "rb"):
+            monkeypatch.setattr(reader.rb, "traced", lambda: r)
+        else:
+            monkeypatch.setattr(reader.rs, "traced", lambda: spans)
+        assert reader.read({}) == pytest.approx(value), name
+    # the parent of PR 36: no such scope, no such argument -> left out
+    parent = [e if e[0] != "span" else e[:4] + [{"round": 3}]
+              for e in _booked_toy()]
+    parent = [e[:4] + [e[4].replace("fed.step.update/", "")
+                       .replace("fed.client_groups/", "")]
+              if e[0] == "op" else e for e in parent]
+    r0 = rb.reduce(parent)
+    for name in ("device_ms.step_update.round",
+                 "device_ms.client_groups.round", "dispatched_fill_pct"):
+        reader = _reader(name)
+        monkeypatch.setattr(reader.rb, "traced", lambda: r0)
+        assert reader.read({}) is None, name
+    unbooked = _reader("device_ms.unbooked.round")
+    monkeypatch.setattr(unbooked.rb, "traced", lambda: r0)
+    assert unbooked.read({}) == pytest.approx(260e-6)   # the update too
+    # the step's scopes named, none of them an op's innermost: 0, not None
+    fused = rb.reduce([e[:4] + [e[4].replace(
+        "fed.step.update/select_n", "fed.step.shuffle/x/fed.model.gdn/y")]
+        if e[0] == "op" else e for e in _booked_toy()])
+    update = _reader("device_ms.step_update.round")
+    monkeypatch.setattr(update.rb, "traced", lambda: fused)
+    assert update.read({}) == 0.0
+    # a CPU rehearsal: spans and their arguments, never a device number
+    host_only = rb.reduce([e for e in _booked_toy() if e[0] != "op"])
+    for name in BOOKED_READERS[:5]:
+        reader = _reader(name)
+        monkeypatch.setattr(reader.rb, "traced", lambda: host_only)
+        assert reader.read({}) is None, name
+
+
+@pytest.mark.parametrize("name", BOOKED_READERS)
+def test_booked_reader_agrees_with_the_manifest_and_reads_none_without_a_trace(
+        name, tmp_path, monkeypatch):
+    manifest = _manifest()
+    entry, = [m for m in manifest["per_layer"] if m["name"] == name]
+    reader = _reader(name)
+    assert {k: entry[k] for k in ("layer", "unit", "moves")} == reader.META
+    cells = [w["name"] for w in manifest["workloads"] if reader.applies(w)]
+    assert cells == entry.get("workloads",
+                              [w["name"] for w in manifest["workloads"]])
+    rs = reader.rb.rs if hasattr(reader, "rb") else reader.rs
+    monkeypatch.setattr(rs, "TRACE_DIR", str(tmp_path))
+    assert reader.read({"chips": 1, "rounds": 3}) is None
+
+
+@pytest.mark.parametrize("grouped", [False, True])
+def test_the_dispatch_spans_carry_what_the_registry_counts(grouped):
+    """A streamed round of both kinds: the whole cohort in one dispatch, and
+    a size group a dispatch. ``slots`` / ``samples`` on the spans are the
+    host values ``dispatch_profile()``'s counters are incremented by."""
+    if grouped:
+        import test_size_groups
+
+        api = test_size_groups._api()
+    else:
+        api = _api("store")
+    assert bool(api._size_group()) == grouped
+    api.train_one_round(0)
+    _join_prefetch(api)
+    before = api.dispatch_profile()
+    tracer = obs_trace.SpanTracer()
+    with obs_trace.using(tracer):
+        api.train_one_round(1)
+        api.train_one_round(2)
+        _join_prefetch(api)
+    after = api.dispatch_profile()
+    carrying = [e["args"] for e in tracer.events()
+                if e["name"] == "fed.round.dispatch" and "slots" in e["args"]]
+    assert len(carrying) == (after["groups_dispatched"]
+                             - before["groups_dispatched"])
+    assert len(carrying) == (2 * 8 if grouped else 2)
+    for key, counter in (("slots", "slots_dispatched"),
+                         ("samples", "samples_real")):
+        assert all(type(a[key]) is int for a in carrying)
+        assert sum(a[key] for a in carrying) == after[counter] \
+            - before[counter] > 0

@@ -285,11 +285,21 @@ def test_a_prefetched_grouped_cohort_is_a_hit_with_the_same_spans():
 
     dispatches = [e for e in events if e["name"] == "fed.round.dispatch"]
     assert all(e["tid"] == main for e in dispatches)
-    assert [e["args"] for e in dispatches] == (
+    assert [{k: v for k, v in e["args"].items()
+             if k not in ("slots", "samples")} for e in dispatches] == (
         [{"round": 1}]
         + [{"round": 1, "group": j, "steps": s}
            for j, s in enumerate(steps1)]
         + [{"round": 1}])
+    # a group's dispatch says what it trains (PR 36); the init and the
+    # finish train nothing and say nothing
+    assert [e["args"].get("slots") for e in dispatches] == (
+        [None] + [GROUP * s * BATCH for s in steps1] + [None])
+    assert all(isinstance(e["args"]["samples"], int)
+               and 0 < e["args"]["samples"] <= e["args"]["slots"]
+               for e in dispatches[1:-1])
+    assert sum(e["args"]["slots"] for e in dispatches[1:-1]) == slots1
+    assert sum(e["args"]["samples"] for e in dispatches[1:-1]) == real1
 
     after = api.dispatch_profile()
     assert after["rounds_streamed"] - before["rounds_streamed"] == 1
