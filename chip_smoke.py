@@ -104,7 +104,7 @@ class Sizes:
     granite_attn: tuple      # (query heads, d_head, softmax scale)
     ssd_heads: tuple         # (heads, d_head, d_state, chunk)
     lora_linear: tuple       # (rows a client, depth, columns, rank)
-    window_attn: tuple       # (query heads, d_head, window)
+    window_attn: tuple       # (query heads, key-value heads, d_head, window)
     held_experts: tuple      # (tokens, d, f, experts, held, top-k, rank)
     gn_shapes: tuple         # ((height == width, channels), ...)
     gn_batch: int
@@ -123,7 +123,7 @@ REAL = Sizes(
     kernel_t=2048, kernel_heads=((8, 64), (4, 128)), gdn_heads=(16, 32, 128),
     granite_attn=(32, 64, 0.015625), ssd_heads=(64, 64, 128, 256),
     lora_linear=(1024, 2048, 16384, 16),
-    window_attn=(8, 128, 128), held_experts=(2048, 1024, 512, 64, 8, 8, 16),
+    window_attn=(16, 2, 128, 128), held_experts=(2048, 1024, 512, 64, 8, 8, 16),
     gn_shapes=((32, 16), (8, 256)), gn_batch=32,
     serve_seq=128, serve_batch=32, serve_new=16, serve_requests=64,
     chain_dim=4096, chain_s=0.5)
@@ -134,7 +134,7 @@ TOY = Sizes(
     kernel_t=128, kernel_heads=((2, 16), (1, 32)), gdn_heads=(1, 2, 128),
     granite_attn=(2, 16, 0.0625), ssd_heads=(2, 16, 16, 32),
     lora_linear=(256, 128, 65536, 4),
-    window_attn=(2, 16, 24), held_experts=(64, 32, 16, 16, 4, 3, 4),
+    window_attn=(4, 2, 16, 24), held_experts=(64, 32, 16, 16, 4, 3, 4),
     gn_shapes=((8, 16), (4, 32)), gn_batch=4,
     serve_seq=16, serve_batch=4, serve_new=3, serve_requests=8,
     chain_dim=256, chain_s=0.05)
@@ -435,21 +435,26 @@ def phase_kernels(ctx: Ctx, out: dict) -> None:
             q * (scale * d ** 0.5), k, v, causal=True)),
         do, q, k, v)
 
-    # K-EXAONE's window layers: head 128, a window of 128 (the grids hold
-    # each band's blocks only), against the masked plain softmax
-    h, d, window = s.window_attn
+    # K-EXAONE's window layers: head 128, a window of 128, a key-value head
+    # a group of 8 query heads (the grouped band kernels: k and v read with
+    # their own heads), against the masked plain softmax over repeated k, v
+    h, hkv, d, window = s.window_attn
     keys = jax.random.split(jax.random.PRNGKey(h * 1000 + d + window), 4)
-    q, k, v, do = (jax.random.normal(
-        kk, (n_clients, 1, s.kernel_t, h, d), jnp.bfloat16) for kk in keys)
+    q, do = (jax.random.normal(
+        kk, (n_clients, 1, s.kernel_t, h, d), jnp.bfloat16) for kk in keys[:2])
+    k, v = (jax.random.normal(
+        kk, (n_clients, 1, s.kernel_t, hkv, d), jnp.bfloat16)
+        for kk in keys[2:])
 
     def windowed(q, k, v):
+        k, v = (jnp.repeat(a, h // hkv, axis=2) for a in (k, v))
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
         back = jnp.arange(q.shape[1])[:, None] - jnp.arange(q.shape[1])[None]
         scores = jnp.where((back >= 0) & (back < window), scores, -1e30)
         return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v)
 
     compare(
-        f"flash_h{h}_d{d}_window{window}",
+        f"flash_h{h}_kv{hkv}_d{d}_window{window}",
         jax.vmap(partial(flash_attention, causal=True, window=window)),
         jax.vmap(windowed), do, q, k, v)    # rel_err order: o, dq, dk, dv
 

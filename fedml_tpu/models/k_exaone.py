@@ -144,8 +144,12 @@ class Attention(_Layer):
                      c.rms_norm_eps)
         if self.window:
             q, k = rotary(q, c.rope_theta), rotary(k, c.rope_theta)
-        # a key-value head serves hq / hkv queries
-        k, v = (jnp.repeat(a, hq // hkv, axis=2) for a in (k, v))
+        # a key-value head serves hq / hkv queries. The window layers' flash
+        # path hands the band kernel k and v with their hkv heads; the
+        # full-attention layer's streaming kernel reads a head a grid row and
+        # the einsum arm contracts head by head: those two repeat them
+        if not (self.window and c.attention == "flash"):
+            k, v = (jnp.repeat(a, hq // hkv, axis=2) for a in (k, v))
         q, k, v = (a.astype(x.dtype) for a in (q, k, v))
         with jax.named_scope(self.scope_name + ".core"):
             if c.attention == "flash":

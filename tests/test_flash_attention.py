@@ -12,10 +12,15 @@ from fedml_tpu.ops import flash_attention
 from fedml_tpu.parallel.ring_attention import reference_attention
 
 
-def _qkv(b=2, t=64, h=2, d=16, seed=0):
+def _grouped_qkv(t, hq, hkv, d=8, seed=0, lead=(2,)):
+    """q with ``hq`` heads, k and v with ``hkv``, ``lead`` axes before T."""
     rng = np.random.RandomState(seed)
-    mk = lambda: jnp.asarray(rng.randn(b, t, h, d), jnp.float32)
-    return mk(), mk(), mk()
+    mk = lambda h: jnp.asarray(rng.randn(*lead, t, h, d), jnp.float32)  # noqa: E731
+    return mk(hq), mk(hkv), mk(hkv)
+
+
+def _qkv(b=2, t=64, h=2, d=16, seed=0):
+    return _grouped_qkv(t, h, h, d, seed, (b,))
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -84,68 +89,128 @@ def test_transformer_lm_with_flash_attention():
                                rtol=2e-5, atol=2e-5)
 
 
-# --- a sliding window (PR 34) -------------------------------------------------
+# --- a sliding window: the grouped band kernels (PR 37) -----------------------
 
 def _windowed(q, k, v, window):
-    """Masked plain softmax: query ``i`` sees key ``j`` iff ``0 <= i - j <
-    window``."""
-    t = q.shape[1]
+    """Masked plain softmax, query ``i`` sees key ``j`` iff ``0 <= i - j <
+    window``; k and v may have fewer heads, each serving a group of q's."""
+    t, group = q.shape[1], q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
     back = np.arange(t)[:, None] - np.arange(t)[None, :]
     s = jnp.where((back >= 0) & (back < window), s, -1e30)
     return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
 
 
-@pytest.mark.parametrize("t,window,blk_q,blk_k", [
-    (64, 10, 16, 16),       # not a multiple of the block
-    (64, 16, 16, 16),       # exactly a block
-    (128, 24, 32, 16),      # a band of three key blocks
-    (128, 24, 16, 32),      # keys wider than queries
-    (64, 5, 8, 16),
-    (96, 40, 32, 32),       # wider than a block
-    (64, 1, 16, 16),        # a token sees itself only
-])
-def test_windowed_flash_matches_masked_plain_attention(t, window, blk_q,
-                                                       blk_k):
-    """Forward and the three gradients with a window, the grid holding only
-    each band's blocks, against the masked plain softmax."""
-    q, k, v = _qkv(t=t, d=8)
+# t, window, query heads, key-value heads, sub-block (block_k), block (block_q)
+BAND_CASES = {
+    # PR 34's shapes of the old window grids, now the band's
+    "window_no_multiple_of_the_sub_block": (64, 10, 2, 2, 16, 16),
+    "window_exactly_a_sub_block": (64, 16, 2, 2, 16, 16),
+    "two_sub_blocks_a_block": (128, 24, 2, 2, 32, 64),
+    "one_block_no_halo_group_4": (128, 24, 4, 1, 32, 128),
+    "narrow_window_wide_block": (64, 5, 2, 2, 8, 32),
+    "window_under_an_odd_sub_block": (96, 40, 2, 2, 48, 96),
+    "a_token_sees_itself_only": (64, 1, 2, 2, 16, 16),
+    "block_of_one_sub_block_group_2": (64, 16, 4, 2, 16, 16),
+    # the default blocks: the window rounded up to 128, 4 sub-blocks a step
+    "window_128_one_block_group_8": (512, 128, 8, 1, None, None),
+    "window_128_two_blocks_group_4": (1024, 128, 4, 1, None, None),
+    "window_128_three_blocks_of_one_group_1": (384, 128, 1, 1, None, None),
+    "window_under_128_group_8": (512, 100, 8, 2, None, None),
+    "window_no_multiple_of_128_group_4": (1024, 200, 4, 1, None, 256),
+    "window_longer_than_t_over_2": (256, 200, 2, 1, None, None),
+}
 
-    def flash(q, k, v):
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 4e-2)])
+@pytest.mark.parametrize("case", BAND_CASES)
+def test_band_matches_masked_plain_attention(case, dtype, tol):
+    """Forward and the three gradients of the grouped band kernels against
+    the masked plain softmax over repeated k, v: k, v, ``dk``, ``dv`` with
+    their own heads, nothing repeated (bf16: the operands rounded, the
+    reference in float32 on the rounded operands)."""
+    t, window, hq, hkv, sub, blk = BAND_CASES[case]
+    q, k, v = (a.astype(dtype) for a in _grouped_qkv(t, hq, hkv))
+
+    def band(q, k, v):
         return flash_attention(q, k, v, causal=True, window=window,
-                               block_q=blk_q, block_k=blk_k)
+                               block_k=sub, block_q=blk)
 
-    np.testing.assert_allclose(flash(q, k, v), _windowed(q, k, v, window),
-                               rtol=2e-5, atol=2e-5)
-    got = jax.grad(lambda *a: jnp.sum(flash(*a) ** 2), (0, 1, 2))(q, k, v)
+    wide = lambda f: lambda *a: f(*a).astype(jnp.float32)  # noqa: E731
+    exact = [a.astype(jnp.float32) for a in (q, k, v)]
+    np.testing.assert_allclose(wide(band)(q, k, v), _windowed(*exact, window),
+                               rtol=tol, atol=tol)
+    got = jax.grad(lambda *a: jnp.sum(wide(band)(*a) ** 2), (0, 1, 2))(q, k, v)
     want = jax.grad(lambda *a: jnp.sum(_windowed(*a, window) ** 2),
-                    (0, 1, 2))(q, k, v)
+                    (0, 1, 2))(*exact)
+    assert [a.shape for a in got] == [q.shape, k.shape, v.shape]
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a.astype(jnp.float32), b,
+                                   rtol=10 * tol, atol=10 * tol)
+
+
+@pytest.mark.parametrize("hq,hkv", [(8, 1), (4, 1), (4, 2), (2, 2)])
+def test_band_under_vmap_sums_the_group_as_the_repeat_would(hq, hkv):
+    """Under ``jax.vmap`` over a leading client axis, as the round calls it:
+    the grids are ``(clients, B, Hkv, T / block)``, the three calls keep
+    their names, and ``dk``, ``dv`` are the repeated kernel's summed over
+    each key-value head's group."""
+    t, window, d = 128, 16, 8
+    q, k, v = _grouped_qkv(t, hq, hkv, d, lead=(2, 1))
+
+    def band(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_k=16, block_q=32)
+
+    step = jax.vmap(jax.grad(lambda *a: jnp.sum(band(*a) ** 2), (0, 1, 2)))
+    text = str(jax.make_jaxpr(step)(q, k, v))
+    grids = re.findall(r"grid=\((\d+), (\d+), (\d+), (\d+)\)", text)
+    assert grids and all(g == ("2", "1", str(hkv), "4") for g in grids), grids
+    assert {"window_band_fwd", "window_band_dq", "window_band_dkv"} <= set(
+        re.findall(r"window_band_\w+", text))
+    got = step(q, k, v)
+    want = jax.vmap(jax.grad(lambda *a: jnp.sum(_windowed(*a, window) ** 2),
+                             (0, 1, 2)))(q, k, v)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+    repeated = step(q, *(jnp.repeat(a, hq // hkv, axis=3) for a in (k, v)))
+    for a, b in zip(got[1:], repeated[1:]):
+        np.testing.assert_allclose(
+            a, b.reshape(2, 1, t, hkv, hq // hkv, d).sum(4),
+            rtol=1e-5, atol=1e-5)
 
 
-def test_a_windows_grid_holds_its_bands_blocks_only():
-    """The forward and dq grids' innermost axis is the widest band's key
-    blocks, the dkv grid's its query blocks: 2 of 8 at a window of one
-    block, under ``vmap`` over clients too."""
+def test_band_blocks_from_what_the_call_can_see():
+    """Sub-block = the window rounded up to a multiple of 128, block = up to
+    4 sub-blocks that divide T; a sub-block narrower than the window, or a
+    ragged sequence, is refused; grouped k, v under a window no shorter than
+    the sequence are repeated for the causal kernel."""
     import importlib
 
     fa = importlib.import_module("fedml_tpu.ops.flash_attention")
-    assert fa._band_k(128, 16, 16, 16) == 2 == fa._band_q(128, 16, 16, 16)
-    assert fa._band_k(128, 16, 16, 17) == 2 and fa._band_k(128, 16, 16, 18) == 3
-    assert fa._band_k(4096, 256, 256, 128) == 2     # the benchmark's cell
-    assert fa._band_q(4096, 256, 256, 128) == 2
-    q, k, v = (jnp.stack([a, a + 1]) for a in _qkv(t=128, d=8))
-    step = jax.vmap(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
-        q, k, v, causal=True, window=16, block_q=16, block_k=16)), (0, 1, 2)))
-    grids = re.findall(r"grid=\((\d+), (\d+), (\d+), (\d+)\)",
-                       str(jax.make_jaxpr(step)(q, k, v)))
-    assert grids and all(g == ("2", "4", "8", "2") for g in grids), grids
-    got = step(q, k, v)
-    want = jax.vmap(jax.grad(lambda q, k, v: jnp.sum(
-        _windowed(q, k, v, 16)), (0, 1, 2)))(q, k, v)
-    for a, b in zip(got, want):
-        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+    assert fa._band_blocks(4096, 128, None, None) == (128, 512)  # the cell
+    assert fa._band_blocks(4096, 100, None, None) == (128, 512)
+    assert fa._band_blocks(4096, 129, None, None) == (256, 1024)
+    assert fa._band_blocks(640, 128, None, None) == (128, 128)
+    assert fa._band_blocks(768, 128, None, None) == (128, 384)
+    assert fa._band_blocks(24, 6, None, None) == (24, 24)
+    assert fa._band_blocks(64, 10, 16, 32) == (16, 32)
+    with pytest.raises(ValueError, match="sub-blocks"):
+        fa._band_blocks(64, 17, 16, None)
+    with pytest.raises(ValueError, match="sub-blocks"):
+        fa._band_blocks(200, 128, None, None)
+    with pytest.raises(ValueError, match="whole sub-blocks"):
+        fa._band_blocks(128, 16, 16, 48)
+    q, k, v = _grouped_qkv(32, 4, 2)
+    np.testing.assert_allclose(
+        flash_attention(q, k, v, causal=True, window=32, block_q=16,
+                        block_k=16),
+        _windowed(q, k, v, 32), rtol=2e-5, atol=2e-5)
+    with pytest.raises(ValueError, match="heads"):
+        flash_attention(q, k[:, :, :1], v, causal=True, window=8)
+    with pytest.raises(ValueError, match="heads"):
+        flash_attention(*_grouped_qkv(32, 3, 2), causal=True, window=8)
 
 
 def test_no_window_is_the_kernel_as_it_was():
@@ -155,7 +220,10 @@ def test_no_window_is_the_kernel_as_it_was():
     text = lambda **kw: str(jax.make_jaxpr(lambda q, k, v: flash_attention(  # noqa: E731
         q, k, v, causal=True, block_q=16, block_k=16, **kw))(q, k, v))
     assert text() == text(window=None) == text(window=64) == text(window=900)
-    assert text(window=63) != text()
+    assert "window_band" not in text()
+    banded = str(jax.make_jaxpr(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=63))(q, k, v))
+    assert "window_band_fwd" in banded
     with pytest.raises(ValueError, match="causal"):
         flash_attention(q, k, v, window=8)
     with pytest.raises(ValueError, match="window"):

@@ -234,3 +234,26 @@ def test_the_kernel_compiles_for_the_v5e_at_the_cells_shapes(
                 lowering_platforms=("tpu",)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "held_gmm" in text
+
+
+def test_the_band_kernels_compile_for_the_v5e_at_the_cells_shapes(
+        monkeypatch, one_chip):
+    """K-EXAONE's window layers as the round calls them (``ops/
+    flash_attention.py``'s grouped band, PR 37; here because this file holds
+    the described chip): a client's sequence of 4,096 tokens under the
+    ``vmap``, 64 query heads over 8 key-value heads of 128, a window of 128.
+    Mosaic takes the forward, ``dq`` and ``dkv`` kernels (blocks of a whole
+    group, the halo's index maps, VMEM) under their stable names."""
+    import importlib
+
+    fa = importlib.import_module("fedml_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "pallas_interpret", lambda: False)
+    spec = lambda heads: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, 1, 4096, heads, 128), jnp.bfloat16, sharding=one_chip)
+    step = jax.vmap(jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
+        q, k, v, causal=True, window=128).astype(jnp.float32)), (0, 1, 2)))
+    text = jax.jit(step).trace(spec(64), spec(8), spec(8)).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    for name in ("window_band_fwd", "window_band_dq", "window_band_dkv"):
+        assert name in text
+    assert "tpu_custom_call" in text

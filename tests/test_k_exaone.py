@@ -610,3 +610,75 @@ def test_the_drawn_base_follows_the_assumed_laws(reference):
     np.testing.assert_array_equal(
         f32(weights["embed"]),
         f32(reference.init_base(kwargs, 3400000555)["embed"]))
+
+
+# --- which attention program each layer kind lowers to (PR 37) ----------------
+
+def _attention_text(window: int, attention: str) -> str:
+    """One attention layer's lowered text at the tests' widths (4 query heads
+    over 2 key-value heads of 8, 2 sequences of 24 tokens): the forward and
+    the gradient of the adapters and the input."""
+    from fedml_tpu.models.k_exaone import Attention
+
+    cfg = k_exaone(**CFG, base_dtype="float32", attention=attention).cfg
+    layer = Attention(cfg, jnp.float32, window)
+    x = jnp.zeros((2, T, cfg.hidden_size))
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+    return jax.jit(jax.grad(lambda p, x: jnp.sum(layer.apply(p, x)),
+                            (0, 1))).lower(params, x).as_text()
+
+
+def test_the_window_layer_lowers_to_the_band_kernels_on_unrepeated_heads():
+    """The window layer's flash path: k and v are never broadcast to the 4
+    query heads, nothing is transposed to ``[B*H, T, D]`` = ``[8, 24, 8]``,
+    and the three band calls take q ``[2, 24, 32]`` and k, v ``[2, 24, 16]``
+    (``dk``, ``dv`` leave with their 2 heads); the full-attention layer's
+    text, beside it, holds all three."""
+    text, full = _attention_text(6, "flash"), _attention_text(0, "flash")
+    repeat = r"broadcast_in_dim.*tensor<2x24x2x2x8xf32>"
+    heads_first = r"tensor<8x24x8xf32>|tensor<2x4x24x8xf32>"
+    assert re.search(repeat, full) and re.search(heads_first, full)
+    assert not re.search(repeat, text) and not re.search(heads_first, text)
+    for name, results in (
+            ("_band_fwd", r"tensor<2x24x32xf32>, tensor<2x2x2x24xf32>"),
+            ("_band_dq", r"tensor<2x24x32xf32>"),
+            ("_band_dkv", r"tensor<2x24x16xf32>, tensor<2x24x16xf32>")):
+        assert re.search(rf"call @{name}\w*\(.*-> \(?{results}\)?", text), name
+        assert name not in full
+
+
+def test_a_step_calls_the_band_twice_forward_and_once_backward_a_layer(
+        seeded):
+    """The model's four window layers under ``nn.remat``: a training step
+    calls ``window_band_fwd`` twice a layer (the forward and the recomputed
+    one) and ``window_band_dq`` / ``window_band_dkv`` once; the full layer
+    keeps the streaming kernels (the benchmark's cell holds the same 4 layers
+    at 8 sequence-steps a round: 32 x (2 + 1) calls)."""
+    model, base, adapters, ids, labels = seeded
+    text = jax.jit(lambda a: _loss_and_grad(
+        model, base, a, ids, labels)).lower(adapters).as_text()
+    calls = re.findall(r"call @(_band_[a-z]+)", text)
+    assert {n: calls.count(n) for n in set(calls)} == {
+        "_band_fwd": 8, "_band_dq": 4, "_band_dkv": 4}
+    jaxpr = str(jax.make_jaxpr(lambda a: _loss_and_grad(
+        model, base, a, ids, labels))(adapters))
+    assert set(re.findall(r"name=(window_band_\w+)", jaxpr)) == {
+        "window_band_fwd", "window_band_dq", "window_band_dkv"}
+
+
+@pytest.mark.parametrize("window,attention", [
+    (0, "flash"), (0, "dense"), (6, "dense")])
+def test_the_other_attention_arms_lower_to_the_parents_text(window, attention):
+    """The full-attention layer (the streaming kernel over repeated k, v) and
+    the einsum arm of both kinds: ``tests/fixtures/k_exaone_attention_text
+    .json`` holds the SHA-256 of this text as PR 37's parent (5c1f4eb)
+    lowered it, the same function run in a copy of that commit."""
+    import hashlib
+    import json
+
+    with open(os.path.join(ROOT, "tests", "fixtures",
+                           "k_exaone_attention_text.json")) as f:
+        want = json.load(f)["sha256"][f"window_{window}_{attention}"]
+    text = _attention_text(window, attention)
+    assert hashlib.sha256(text.encode()).hexdigest() == want
+    assert "_band_" not in text
