@@ -1,0 +1,24 @@
+"""``device_ms.head.round``'s reading in a stack of single-mixer blocks: self
+time of the first device's operations a traced round under
+``fed.model.head``: embedding, final norm, the vocabulary slice's untied head
+and the token cross-entropy (``reduce_scopes_ssm_moe.py``).
+"""
+
+import os
+import sys
+
+BENCHMARK = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if BENCHMARK not in sys.path:
+    sys.path.insert(0, BENCHMARK)
+import reduce_scopes_ssm_moe as rsm  # noqa: E402  (benchmark/reduce_scopes_ssm_moe.py)
+
+SCOPE = "fed.model.head"
+META = {"layer": "model layers", "unit": "ms", "moves": "rounds_per_s"}
+
+
+def applies(cell: dict) -> bool:
+    return rsm.lists_scope(cell, SCOPE)
+
+
+def read(summary: dict):
+    return rsm.scope_ms(SCOPE)

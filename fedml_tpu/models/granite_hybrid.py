@@ -66,6 +66,17 @@ def rms_norm(x, weight, eps: float):
         * weight.astype(F32)
 
 
+def group_rms_norm(x, weight, eps: float, groups: int = 1):
+    """``w x rsqrt(mean x^2 + eps)`` with the mean taken over each of
+    ``groups`` equal runs of the last axis (Mamba-2's gated norm: a norm a
+    B/C group of heads), float32; :func:`rms_norm` at one group."""
+    if groups == 1:
+        return rms_norm(x, weight, eps)
+    x = x.astype(F32).reshape(x.shape[:-1] + (groups, -1))
+    x = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
+    return x.reshape(x.shape[:-2] + (-1,)) * weight.astype(F32)
+
+
 def _mm(x, w, spec: str = "...d,de->...e"):
     """``x`` times ``w`` cast to ``x``'s dtype, float32 accumulation."""
     return jnp.einsum(spec, x, w.astype(x.dtype), preferred_element_type=F32)
@@ -153,9 +164,10 @@ class Mamba2Mixer(_Layer):
                          b.astype(x.dtype), cc.astype(x.dtype),
                          chunk=c.mamba_chunk_size)
         y = y + skip.astype(F32)[:, None] * xs
-        # gate first, then one norm over all the inner channels
-        y = rms_norm(y.reshape(bsz, t, inner) * jax.nn.silu(z), w_norm,
-                     c.rms_norm_eps)
+        # gate first, then a norm over the inner channels of each B/C group
+        # (Granite's one group: over all of them)
+        y = group_rms_norm(y.reshape(bsz, t, inner) * jax.nn.silu(z), w_norm,
+                           c.rms_norm_eps, g)
         return self.linear("out_proj", y.astype(x.dtype), c.hidden_size)
 
 
@@ -167,8 +179,7 @@ class Attention(_Layer):
     @nn.compact
     def __call__(self, x):
         c = self.cfg
-        hq, hkv = c.num_attention_heads, c.num_key_value_heads
-        hd = c.hidden_size // hq
+        hq, hkv, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
         bsz, t, _ = x.shape
         q = self.linear("q_proj", x, hq * hd).reshape(bsz, t, hq, hd)
         k = self.linear("k_proj", x, hkv * hd).reshape(bsz, t, hkv, hd)
@@ -287,6 +298,11 @@ class GraniteHybridShapes:
         if self.attention not in ("flash", "dense"):
             raise ValueError(f"attention={self.attention!r}: 'flash' or "
                              "'dense'")
+
+    @property
+    def head_dim(self) -> int:
+        """Granite's config has no key for it: the stream over the heads."""
+        return self.hidden_size // self.num_attention_heads
 
     @property
     def period(self) -> Tuple[str, ...]:

@@ -44,6 +44,7 @@ def create_model(name: str, **kwargs):
         import fedml_tpu.models.lr  # noqa: F401
         import fedml_tpu.models.mobilenet  # noqa: F401
         import fedml_tpu.models.mobilenet_v3  # noqa: F401
+        import fedml_tpu.models.nemotron_h  # noqa: F401
         import fedml_tpu.models.qwen3_next  # noqa: F401
         import fedml_tpu.models.resnet  # noqa: F401
         import fedml_tpu.models.resnet_split  # noqa: F401

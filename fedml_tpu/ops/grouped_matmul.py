@@ -17,8 +17,12 @@ they lie.
 Two implementations, chosen from the operands' shapes alone (``takes_kernel``;
 no option anywhere):
 
-* **A Pallas kernel** where rows, depth and width fill whole tiles. Grid
-  ``(n / tn, visits, k / tk)``: a *visit* is one row tile under one group, in
+* **A Pallas kernel** where the rows fill whole sub-tiles and depth and width
+  are a lane tile or more. Grid ``(ceil(n / tn), visits, ceil(k / tk))``: a
+  depth or a width off the tile grid (an expert width of 1,856 is 14.5 lane
+  tiles) ends in a partial tile, whose columns past the width are never
+  written and whose depth past ``k`` is masked to zero in both operands
+  before the last step's product. A *visit* is one row tile under one group, in
   the order of the rows, so a tile whose rows belong to two groups is visited
   once a group under a row mask (the design of
   ``jax.experimental.pallas.ops.tpu.megablox.gmm``). Which group and which row
@@ -70,8 +74,15 @@ _PARAMS = pltpu.CompilerParams(
 
 def takes_kernel(m: int, k: int, n: int) -> bool:
     """Whether the product runs as the Pallas kernel: rows in whole sub-tiles
-    and a depth and width of whole lanes. A pure function of the shapes."""
-    return m % SUB == 0 and k % 128 == 0 and n % 128 == 0
+    and a depth and width of a lane tile or more (whole tiles or not: the
+    last may be partial). A pure function of the shapes."""
+    return m % SUB == 0 and k >= 128 and n >= 128
+
+
+def _tile(size: int, most: int) -> int:
+    """A depth or width tile: ``most``, or the whole of a smaller ``size`` in
+    whole lanes."""
+    return min(most, -(-size // 128) * 128)
 
 
 def _visits(group_sizes, m: int, tm: int):
@@ -98,9 +109,18 @@ def _visits(group_sizes, m: int, tm: int):
 
 
 def _kernel(offsets, group, tile, active, lhs_ref, rhs_ref, out_ref, acc_ref,
-            *, tm, sub, steps, transpose_rhs):
+            *, tm, sub, steps, last, transpose_rhs):
+    """``last``: the columns of depth the last step really has, 0 where the
+    depth fills whole tiles. What a partial tile holds past them is
+    undefined, in both operands: it is zeroed before that step's product."""
     visit, step = pl.program_id(1), pl.program_id(2)
-    contract = (((1,), (1 if transpose_rhs else 0,)), ((), ()))
+    depth_axis = 1 if transpose_rhs else 0
+    contract = (((1,), (depth_axis,)), ((), ()))
+
+    def real_depth(v, axis: int):
+        """``v`` with what lies past the depth's end zeroed (a last step's)."""
+        return jnp.where(jax.lax.broadcasted_iota(jnp.int32, v.shape, axis)
+                         < last, v, jnp.zeros_like(v))
 
     @pl.when(visit < active[0])
     def _visit():
@@ -113,13 +133,18 @@ def _kernel(offsets, group, tile, active, lhs_ref, rhs_ref, out_ref, acc_ref,
 
         for s in range(tm // sub):      # static: sub-tiles of the row tile
             rows = slice(s * sub, (s + 1) * sub)
-
-            @pl.when((row0 + s * sub < hi) & (row0 + (s + 1) * sub > lo))
-            def _multiply(rows=rows):
-                lhs = lhs_ref[rows, :]
-                acc_ref[rows, :] += jax.lax.dot_general(
-                    lhs, rhs_ref[...].astype(lhs.dtype), contract,
-                    preferred_element_type=F32)
+            holds = (row0 + s * sub < hi) & (row0 + (s + 1) * sub > lo)
+            # where the depth ends in a partial tile (static), the last step
+            # is a second body, masked; every other step is the first
+            for masked in ((False, True) if last else (False,)):
+                @pl.when(holds & ((step == steps - 1) == masked) if last
+                         else holds)
+                def _multiply(rows=rows, masked=masked):
+                    clip = real_depth if masked else (lambda v, axis: v)
+                    lhs = clip(lhs_ref[rows, :], 1)
+                    acc_ref[rows, :] += jax.lax.dot_general(
+                        lhs, clip(rhs_ref[...], depth_axis).astype(lhs.dtype),
+                        contract, preferred_element_type=F32)
 
         @pl.when(step == steps - 1)
         def _store():
@@ -143,9 +168,8 @@ def _grouped(lhs, rhs, group_sizes, transpose_rhs: bool, tiles, name: str):
     m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     tm, tk, tn, sub = tiles
-    tm, tk, tn = (_divisor(m, min(m, tm), sub), _divisor(k, min(k, tk), 128),
-                  _divisor(n, min(n, tn), 128))
-    steps = k // tk
+    tm, tk, tn = _divisor(m, min(m, tm), sub), _tile(k, tk), _tile(n, tn)
+    steps = pl.cdiv(k, tk)
     n_visits = m // tm + rhs.shape[0] - 1
 
     def depth(visit, step, active):
@@ -165,10 +189,10 @@ def _grouped(lhs, rhs, group_sizes, transpose_rhs: bool, tiles, name: str):
 
     return pl.pallas_call(
         functools.partial(_kernel, tm=tm, sub=sub, steps=steps,
-                          transpose_rhs=transpose_rhs),
+                          last=k % tk, transpose_rhs=transpose_rhs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(n // tn, n_visits, steps),
+            grid=(pl.cdiv(n, tn), n_visits, steps),
             in_specs=[pl.BlockSpec((tm, tk), lhs_at),
                       pl.BlockSpec((None, tn, tk) if transpose_rhs
                                    else (None, tk, tn), rhs_at)],

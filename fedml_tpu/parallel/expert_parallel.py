@@ -359,8 +359,9 @@ def sort_held(idx, n_held: int, first: int) -> HeldAssignments:
 
 
 class ExpertPairs(NamedTuple):
-    """A low-rank pair beside each of a held expert's three matrices:
-    ``a [H, in, r]``, ``b [H, r, out]``."""
+    """A low-rank pair beside each of a held expert's matrices: ``a [H, in,
+    r]``, ``b [H, r, out]``. A gated expert has three (gate, up, down), a
+    plain one two: its ``gate_a`` and ``gate_b`` are ``None``."""
 
     gate_a: Any
     gate_b: Any
@@ -368,6 +369,18 @@ class ExpertPairs(NamedTuple):
     up_b: Any
     down_a: Any
     down_b: Any
+
+
+#: a held expert's form, which the layer that calls says: ``W_down (silu(
+#: W_gate x) * W_up x)`` with gate and up side by side in one ``[H, d, 2f]``
+#: matrix (three matrices, three pairs), or ``W_down relu(W_up x)^2`` with
+#: ``W_up`` as ``[H, f, d]``, a hidden unit a row as the source's linear maps
+#: store it (two and two). Why that way round: the device lays a matrix whose
+#: last axis is off the lane grid (a width of 1,856) the other way round, and
+#: a kernel that wants it row-major then COPIES it at every call (TPU
+#: compiler, PERF.md section 6, PR 39); ``[H, f, d]`` ends on the stream's
+#: width, which is on the grid in every configuration so far
+FORMS = ("swiglu", "relu2")
 
 
 def _mm(spec, a, b):
@@ -399,11 +412,12 @@ def _sum_bwd(saved, g):
 _sum_of.defvjp(_sum_fwd, _sum_bwd)
 
 
-def _chunk_pass(p, x, weight, held: HeldAssignments, w_gate_up, w_down,
-                pairs: ExpertPairs, scale: float, rows: int):
+def _chunk_pass(p, x, weight, held: HeldAssignments, w_in, w_down,
+                pairs: ExpertPairs, scale: float, rows: int, form: str):
     """Rows ``p * rows .. (p + 1) * rows - 1`` of the assignments sorted by
     held expert: ``[N, d]`` float32. The frozen matrices are operands of
-    grouped products as they lie (``[H, d, 2f]``, ``[H, f, d]``;
+    grouped products as they lie (``w_in [H, d, 2f]`` gate and up, or ``[H, f,
+    d]`` up alone, by ``form``; ``w_down [H, f, d]``;
     ``ops/grouped_matmul.py``): no gather reads them, so nothing copies them
     and a ``vmap`` over clients (which batches ``x``, the routing and the
     pairs) leaves them one operand. Rows past the held assignments' total
@@ -441,10 +455,16 @@ def _chunk_pass(p, x, weight, held: HeldAssignments, w_gate_up, w_down,
         t = jnp.where(member[:, :, None], t, 0.0).astype(slab.dtype)
         return scale * _mm("chr,hro->co", t, b)
 
-    gate_up = grouped_matmul(slab, w_gate_up, sizes, name="held_gmm")
-    gate = gate_up[:, :f] + low(slab, pairs.gate_a, pairs.gate_b)
-    up = gate_up[:, f:] + low(slab, pairs.up_a, pairs.up_b)
-    hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
+    if form == "swiglu":
+        gate_up = grouped_matmul(slab, w_in, sizes, name="held_gmm")
+        gate = gate_up[:, :f] + low(slab, pairs.gate_a, pairs.gate_b)
+        up = gate_up[:, f:] + low(slab, pairs.up_a, pairs.up_b)
+        hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
+    else:
+        up = grouped_matmul(slab, w_in, sizes, transpose_rhs=True,
+                            name="held_gmm") + low(slab, pairs.up_a,
+                                                   pairs.up_b)
+        hidden = jnp.square(jax.nn.relu(up)).astype(x.dtype)
     out = grouped_matmul(hidden, w_down, sizes, name="held_gmm") + low(
         hidden, pairs.down_a, pairs.down_b)
     # weighted in float32, handed to the sum in the step's dtype: the sum
@@ -457,39 +477,39 @@ def _chunks(counts, rows: int):
     return jnp.maximum(-(-jnp.sum(counts) // rows), 1)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _further_chunks(y, x, weight, held, w_gate_up, w_down, pairs, scale,
-                    rows):
+@partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _further_chunks(y, x, weight, held, w_in, w_down, pairs, scale, rows,
+                    form):
     """``y`` plus the chunks after the first: a ``while`` whose trip count
     is what the held assignments' total needs, none at a total under
     ``rows``."""
     def body(carry):
         p, acc = carry
-        return p + 1, acc + _chunk_pass(p, x, weight, held, w_gate_up,
-                                        w_down, pairs, scale, rows)
+        return p + 1, acc + _chunk_pass(p, x, weight, held, w_in, w_down,
+                                        pairs, scale, rows, form)
 
     return jax.lax.while_loop(
         lambda carry: carry[0] < _chunks(held.counts, rows), body,
         (jnp.int32(1), y))[1]
 
 
-def _further_fwd(y, x, weight, held, w_gate_up, w_down, pairs, scale, rows):
-    return (_further_chunks(y, x, weight, held, w_gate_up, w_down, pairs,
-                            scale, rows),
-            (x, weight, held, w_gate_up, w_down, pairs))
+def _further_fwd(y, x, weight, held, w_in, w_down, pairs, scale, rows, form):
+    return (_further_chunks(y, x, weight, held, w_in, w_down, pairs, scale,
+                            rows, form),
+            (x, weight, held, w_in, w_down, pairs))
 
 
-def _further_bwd(scale, rows, saved, dy):
+def _further_bwd(scale, rows, form, saved, dy):
     """A chunk's forward is computed again here and differentiated by
     ``x``, the weights and the pairs; the frozen matrices get no gradient
     (``None``: none is formed)."""
-    x, weight, held, w_gate_up, w_down, pairs = saved
+    x, weight, held, w_in, w_down, pairs = saved
 
     def body(carry):
         p, grads = carry
         _, vjp = jax.vjp(
             lambda x, weight, pairs: _chunk_pass(
-                p, x, weight, held, w_gate_up, w_down, pairs, scale, rows),
+                p, x, weight, held, w_in, w_down, pairs, scale, rows, form),
             x, weight, pairs)
         return p + 1, jax.tree.map(jnp.add, grads, vjp(dy))
 
@@ -503,14 +523,17 @@ def _further_bwd(scale, rows, saved, dy):
 _further_chunks.defvjp(_further_fwd, _further_bwd)
 
 
-def held_lora_products(x, weight, held: HeldAssignments, w_gate_up, w_down,
-                       pairs: ExpertPairs, scale: float, rows: int):
+def held_lora_products(x, weight, held: HeldAssignments, w_in, w_down,
+                       pairs: ExpertPairs, scale: float, rows: int,
+                       form: str = "swiglu"):
     """The held experts' part of the layer, ``sum_{e in top-k(n), e held}
     w_ne E_e(x_n)`` as float32 ``[N, d]``, every matrix of ``E_e`` the frozen
     one plus ``scale`` times its client's pair: ``x [N, d]`` and
-    ``weight [N, k]`` (float32) the client's, ``w_gate_up [H, d, 2f]`` and
-    ``w_down [H, f, d]`` frozen, in ``x``'s dtype; ``rows`` from
-    :func:`chunk_rows`.
+    ``weight [N, k]`` (float32) the client's; ``w_in`` and ``w_down [H, f,
+    d]`` frozen, in ``x``'s dtype; ``form`` (:data:`FORMS`) says what an
+    expert is: ``"swiglu"`` with ``w_in [H, d, 2f]`` (gate and up side by
+    side) and three pairs, or ``"relu2"`` with ``w_in [H, f, d]`` and two;
+    ``rows`` from :func:`chunk_rows`.
 
     One flat buffer of ``rows`` rows a chunk: the assignments sorted by held
     expert (``sort_held``), each row multiplied by its own expert's matrices
@@ -528,8 +551,11 @@ def held_lora_products(x, weight, held: HeldAssignments, w_gate_up, w_down,
     further)``: ``computed`` the assignments the chunks covered,
     ``min(total, rows of all chunks)``, which is every one of them;
     ``further`` the chunks taken after the first."""
-    y = _chunk_pass(0, x, weight, held, w_gate_up, w_down, pairs, scale, rows)
-    y = _further_chunks(y, x, weight, held, w_gate_up, w_down, pairs, scale,
-                        rows)
+    if form not in FORMS:
+        raise ValueError(f"form={form!r}: one of {FORMS}")
+    y = _chunk_pass(0, x, weight, held, w_in, w_down, pairs, scale, rows,
+                    form)
+    y = _further_chunks(y, x, weight, held, w_in, w_down, pairs, scale, rows,
+                        form)
     chunks = _chunks(held.counts, rows)
     return (y, jnp.minimum(jnp.sum(held.counts), chunks * rows), chunks - 1)

@@ -962,3 +962,350 @@ def test_the_toy_forward_names_the_residual_streams_scope(name, sites):
             _load(reducer).SCOPES)
     assert _load("reduce_scopes.py").scope_of(
         ["jit(f)/fed.local_train/layer_0/fed.model.norm/mul"]) == ""
+
+
+# --- PR 39: Nemotron-H in the adapter round -----------------------------------
+
+NEMOTRON, NEMOTRON_CELL = "nemotron_3_nano_30b_a3b", "nemotron3nano_lora_c4_s4k"
+NEMOTRON_READERS = [
+    "device_ms.ssm_groups.round", "device_ms.moe_relu2.round",
+    "device_ms.attn_gqa16.round", "ssm_groups_scan_roofline_pct",
+    "moe_relu2_roofline_pct", "moe_relu2_load_max_over_mean",
+    "moe_relu2_fill_pct", "device_ms.unbooked_ssm_moe.round",
+    "device_ms.remat_ssm_moe.round", "device_ms.lora_ssm_moe.round",
+    "device_ms.head_ssm_moe.round", "adapter_upload_mb_ssm_moe.round",
+    "device_ms.client_groups_ssm_moe.round"]
+
+
+@pytest.fixture(scope="module")
+def nemotron():
+    return _json("benchmark", "configs", NEMOTRON + ".json")
+
+
+@pytest.fixture(scope="module")
+def ssm_moe_mix():
+    return _json("benchmark", "traffic",
+                 "resident_silos_c4_s4k_lora_ssm_moe.json")
+
+
+def test_nemotron_holds_every_published_number_or_names_it_reduced(nemotron):
+    """The catalog row's ``config`` key for key: equal, or one of the three
+    cuts of scale with the published value beside it; every width as
+    published, in the file and in what the factory is handed."""
+    assert nemotron["reduced"] == ["num_hidden_layers", "n_routed_experts",
+                                   "vocab_size"]
+    assert nemotron["published"]["hybrid_override_pattern"].startswith(
+        nemotron["hybrid_override_pattern"])
+    assert {k: nemotron["published"][k] for k in nemotron["reduced"]} == {
+        "num_hidden_layers": 52, "n_routed_experts": 128,
+        "vocab_size": 131072}
+    assert {k: nemotron[k] for k in nemotron["reduced"]} == {
+        "num_hidden_layers": 9, "n_routed_experts": 64, "vocab_size": 16384}
+    assert nemotron["hybrid_override_pattern"] == "MEMEM*EME"
+    kwargs = nemotron["factory_kwargs"]
+    # the router stays 128 wide and top-6; 64 experts from 0 are held
+    assert (kwargs["n_routed_experts"], kwargs["num_experts_held"],
+            kwargs["first_expert_held"], kwargs["num_experts_per_tok"]) == (
+                128, 64, 0, 6)
+    for key, value in {"hidden_size": 2688, "mamba_num_heads": 64,
+                       "mamba_head_dim": 64, "ssm_state_size": 128,
+                       "n_groups": 8, "conv_kernel": 4, "chunk_size": 128,
+                       "num_attention_heads": 32, "num_key_value_heads": 2,
+                       "head_dim": 128, "moe_intermediate_size": 1856,
+                       "moe_shared_expert_intermediate_size": 3712,
+                       "routed_scaling_factor": 2.5}.items():
+        assert nemotron[key] == kwargs[key] == value, key
+    manifest = _json("BENCHMARK.json")
+    listed, = [c for c in manifest["configs"] if c["name"] == NEMOTRON]
+    assert listed["reduced"] == nemotron["reduced"]
+    assert listed["source"] == nemotron["source"]
+    assert set(nemotron["assumed"]) >= {"positions", "gated_norm_group",
+                                        "router_bias", "weight_scale",
+                                        "adapter_b_std",
+                                        "fed_config.client_group_size"}
+    assert "2 chips share each layer" in nemotron["deployment"]
+    from fedml_tpu.algos.config import FedConfig
+
+    assert all(hasattr(FedConfig(), k) for k in nemotron["fed_config"])
+    # the accepted group-loop reader keys on fed_config: the cell's group
+    # size is a key of its own, which its runner hands to the API
+    assert "client_group_size" not in nemotron["fed_config"]
+    assert nemotron["client_group_size_ssm_moe"] in (1, 2)
+    if not os.path.exists(CATALOG):
+        pytest.skip("the model-configs catalog is not on this machine")
+    with open(CATALOG) as f:
+        entry, = [row for row in map(json.loads, f)
+                  if row["source_url"] == nemotron["source"]]
+    for key, value in entry["config"].items():
+        if key in nemotron["reduced"]:
+            assert nemotron["published"][key] == value, key
+        elif key == "hybrid_override_pattern":
+            assert value[:9] == nemotron[key] == kwargs[key]
+        else:
+            assert nemotron[key] == value, key
+            assert kwargs[key] == value, key
+
+
+def test_nemotron_parameters_and_flops_recounted(nemotron, ssm_moe_mix):
+    """The file's counts against the model's own trees (shapes only: no
+    2.9 G parameters are made) and against ``counts/nemotron_h.py``; the
+    frozen FLOPs against the derivation, part by part and by hand."""
+    from fedml_tpu.models.adapter import split_frozen
+    from fedml_tpu.models.nemotron_h import nemotron_h
+
+    kwargs = nemotron["factory_kwargs"]
+    model = nemotron_h(**kwargs)
+    ids = jax.ShapeDtypeStruct((1, 16), np.int32)
+    shapes = jax.eval_shape(
+        lambda i: model.init({"params": jax.random.PRNGKey(0)}, i), ids)
+    base, adapters = split_frozen(shapes["params"])
+
+    def count(tree):
+        return sum(int(np.prod(leaf.shape)) for leaf in jax.tree.leaves(tree))
+
+    # ISSUE 39's count: 4 Mamba-2 blocks, the attention block, 4 expert
+    # blocks of 64 held experts, embedding and head, norms and biases
+    mamba = 2688 * 10304 + 4096 * 2688 + 6144 * 4 + 6144 + 3 * 64 + 4096
+    attn = 2 * 2688 * 4096 + 2 * 2688 * 256
+    expert = 2 * 2688 * 1856
+    sparse = 64 * expert + 2 * expert + 2688 * 128 + 128
+    assert (mamba, attn, expert) == (38742208, 23396352, 9977856)
+    assert {"base": count(base), "adapters": count(adapters)} \
+        == nemotron["parameters"] == {
+            "base": (4 * mamba + attn + 4 * sparse + 2 * 16384 * 2688
+                     + 10 * 2688),
+            "adapters": 39620608}
+    assert nemotron["parameters"]["base"] == 2902003200
+    assert {str(leaf.dtype) for leaf in jax.tree.leaves(base)} == {"bfloat16"}
+    assert set(shapes["counters"]) == {"layer_1", "layer_3", "layer_6",
+                                       "layer_8"}
+    # the held experts' matrices end on the stream's width, on the lane grid
+    assert base["layer_1"]["moe"]["experts_up"].shape == (64, 1856, 2688)
+    assert base["layer_1"]["moe"]["experts_down"].shape == (64, 1856, 2688)
+    counts = _load(nemotron["counts"])
+    assert counts.parameters(kwargs) == nemotron["parameters"]
+    assert (counts.train_flops_per_sequence(nemotron, ssm_moe_mix)
+            == nemotron["train_flops_per_sample"])
+    per_token = counts.forward_flops_per_token(kwargs, 4096)
+    assert {k: round(v) for k, v in per_token.items()} \
+        == nemotron["forward_flops_per_token"]
+    assert per_token["mamba_projections"] == 2 * 4 * (
+        2688 * 10304 + 4096 * 2688)
+    assert per_token["attn_projections"] == 2 * attn
+    assert per_token["shared_experts"] == 2 * 4 * 2 * expert
+    # three held assignments a token at 64 of 128, top-6
+    assert counts.held_per_token(kwargs) == 3
+    assert per_token["held_experts"] == 2 * 4 * 3 * expert
+    assert per_token["head"] == 2 * 2688 * 16384
+    assert per_token["ssm_scan"] == 4 * 64 * (5 * 64 * 128 + 64)
+    assert per_token["attn_core"] == 32 * 4 * 128 * 4097 / 2
+    rank = 2 * 16
+    assert per_token["lora"] == rank * (
+        4 * (2688 + 10304 + 4096 + 2688) + (2 * (2688 + 4096)
+                                            + 2 * (2688 + 256))
+        + 4 * (2 * (2688 + 3712) + 3 * 2 * (2688 + 1856)))
+    frozen, low, moved, matrices = counts.held_experts_forward(kwargs, 4096)
+    assert frozen == 4096 * 3 * 2 * expert and matrices == 64 * expert * 2
+    assert low == 4096 * 3 * 2 * 16 * 2 * (2688 + 1856)
+    assert nemotron["train_flops_per_sample"] == round(4096 * (
+        2 * sum(v for k, v in per_token.items()
+                if k not in ("ssm_scan", "attn_core", "lora"))
+        + 3 * (per_token["ssm_scan"] + per_token["attn_core"]
+               + per_token["lora"])))
+    assert round(sum(per_token.values()) / 1e7) == 90      # 0.90 GFLOP
+    assert counts.steps_per_round(ssm_moe_mix) == 8
+    peaks = _json("benchmark", "peaks.json")["TPU v5 lite"]
+    for kernel in ("ssm_groups_scan", "moe_relu2"):
+        assert counts.roofline_ms_per_round(kernel, nemotron, ssm_moe_mix,
+                                            peaks) > 0
+    with pytest.raises(KeyError):
+        counts.roofline_ms_per_round("attn_window", nemotron, ssm_moe_mix,
+                                     peaks)
+
+
+def test_nemotron_mix_is_the_accepted_adapter_mix_but_for_its_rate(
+        ssm_moe_mix, moe_lora_mix):
+    """``resident_silos_c4_s4k_lora``'s federation and round; only the runner
+    and the learning rate are this cell's own."""
+    for key in ("clients", "counts", "cohort", "batch", "epochs",
+                "sequence_length", "zipf_exponent", "rank_offset_max",
+                "doc_length", "generator", "client_optimizer", "placement",
+                "trace_rounds", "round_base", "round_cycle"):
+        assert ssm_moe_mix[key] == moe_lora_mix[key], key
+    assert ssm_moe_mix["runner"] == "fed_adapter_ssm_moe_lm_round"
+    assert os.path.exists(os.path.join(
+        BENCHMARK, "runners", ssm_moe_mix["runner"] + ".py"))
+
+
+@pytest.mark.parametrize("name", NEMOTRON_READERS)
+def test_nemotron_reader_agrees_with_the_manifest(name, tmp_path,
+                                                  monkeypatch):
+    manifest = _json("BENCHMARK.json")
+    entry, = [m for m in manifest["per_layer"] if m["name"] == name]
+    reader = _load(f"layer_metrics/{name}.py")
+    assert {k: entry[k] for k in ("layer", "unit", "moves")} == reader.META
+    cells = [w["name"] for w in manifest["workloads"] if reader.applies(w)]
+    assert cells == entry["workloads"] == [NEMOTRON_CELL]
+    rsc = reader.rsm.rsc if hasattr(reader, "rsm") else reader.rsc
+    monkeypatch.setattr(rsc.rs, "TRACE_DIR", str(tmp_path))
+    assert reader.read({"chips": 1, "rounds": 3,
+                        "device_kind": "TPU v5 lite"}) is None
+
+
+def test_no_accepted_reader_keys_on_the_nemotron_cells_file(nemotron):
+    """Every accepted reader applies to exactly the cells its list names with
+    the new file in place: the file has no ``scopes``, ``scopes_swa_moe`` or
+    ``scopes_unread`` and keeps ``expert_tokens`` and
+    ``adapter_bytes_folded`` out of ``counters`` (its own
+    ``counters_ssm_moe`` lists both); its own partition's lists are the
+    reducer's."""
+    manifest = _json("BENCHMARK.json")
+    cell, = [w for w in manifest["workloads"] if w["name"] == NEMOTRON_CELL]
+    for metric in manifest["per_layer"]:
+        reader = _load(f"layer_metrics/{metric['name']}.py")
+        listed = NEMOTRON_CELL in metric.get("workloads", [NEMOTRON_CELL])
+        assert reader.applies(cell) == listed, metric["name"]
+    assert not {"scopes", "scopes_swa_moe", "scopes_unread",
+                "counters_swa_moe"} & set(nemotron)
+    assert not {"expert_tokens", "adapter_bytes_folded"} & set(
+        nemotron["counters"])
+    assert {"expert_tokens", "grouped_rows", "adapter_bytes_folded"} <= set(
+        nemotron["counters_ssm_moe"])
+    assert {"fed.model.lora", "fed.model.head"} <= set(
+        nemotron["scopes_ssm_moe"])
+    rsm = _load("reduce_scopes_ssm_moe.py")
+    assert set(nemotron["scopes_ssm_moe"]) | set(
+        nemotron["scopes_ssm_moe_unread"]) == set(rsm.SCOPES) - {
+            "fed.client_fold"}
+
+
+def test_ssm_moe_reducer_books_mixers_norm_and_pairs_apart():
+    """``reduce_scopes_ssm_moe.py``: ``reduce_scopes``' walk with this
+    model's list; the copy is held to the original as the others are."""
+    rsm, rsc = _load("reduce_scopes_ssm_moe.py"), _load("reduce_scopes.py")
+    of = rsm._reducer.scope_of
+    assert of(["jit(f)/fed.local_train/jvp(fed.model.ssm)/"
+               "fed.model.ssm.scan/dot_general"]) == "fed.model.ssm.scan"
+    assert of(["x/transpose(jvp(fed.model.ssm))/fed.model.lora/dot"]) \
+        == "fed.model.lora"
+    assert of(["x/fed.model.moe/fed.model.moe.experts/while/body/dot"]) \
+        == "fed.model.moe.experts"
+    assert of(["x/checkpoint/layer_5/fed.model.attn/fed.model.attn.core/"
+               "pallas_call"]) == "fed.model.attn.core"
+    assert of(["x/checkpoint/layer_5/fed.model.norm/mul"]) == "fed.model.norm"
+    assert rsc.SCOPES[0] == "fed.model.gdn.scan"      # the other is untouched
+    for scope in ("fed.model.head", "fed.client_fold",
+                  "fed.model.moe.route"):
+        path = f"jit(f)/fed.local_train/jvp({scope})/dot_general"
+        assert of([path]) == rsc.scope_of([path]) == scope
+
+
+def test_nemotron_readers_on_a_reduction(nemotron, ssm_moe_mix, monkeypatch):
+    reduction = {"rounds": 4, "device_ns_by_scope": {
+        "": 5e6, "fed.model.ssm": 300e6, "fed.model.ssm.conv": 20e6,
+        "fed.model.ssm.scan": 480e6, "fed.model.attn": 90e6,
+        "fed.model.attn.core": 30e6, "fed.model.moe": 40e6,
+        "fed.model.moe.route": 60e6, "fed.model.moe.experts": 2000e6,
+        "fed.model.moe.shared": 100e6, "fed.model.lora": 40e6,
+        "fed.model.norm": 8e6}}
+    summary = {"device_kind": "TPU v5 lite",
+               "moe_relu2_load_max_over_mean": 1.9,
+               "moe_relu2_fill_pct": 48.5,
+               "adapter_upload_mb_round": 633.9,
+               "counts": {"module": nemotron["counts"], "config": nemotron,
+                          "mix": ssm_moe_mix}}
+    rsm = _load("reduce_scopes_ssm_moe.py")
+    monkeypatch.setattr(rsm._reducer, "traced", lambda: reduction)
+    assert rsm.scope_ms("fed.model.ssm") == pytest.approx(200.0)
+    assert rsm.scope_ms("fed.model.attn") == pytest.approx(30.0)
+    assert rsm.scope_ms("fed.model.moe") == pytest.approx(550.0)
+    # the pairs beside the dense projections are no mixer's; a scope this
+    # trace does not hold reads 0 beside the others
+    assert rsm.scope_ms("fed.model.lora") == pytest.approx(10.0)
+    assert rsm.scope_ms("fed.model.head") == 0
+    counts = _load(nemotron["counts"])
+    peaks = _json("benchmark", "peaks.json")["TPU v5 lite"]
+    for kernel, scope, ms in (
+            ("ssm_groups_scan", "fed.model.ssm.scan", 120.0),
+            ("moe_relu2", "fed.model.moe.experts", 500.0)):
+        least = counts.roofline_ms_per_round(kernel, nemotron, ssm_moe_mix,
+                                             peaks)
+        share = rsm.roofline_pct(summary, kernel, scope)
+        assert share == pytest.approx(100.0 * least / ms) and 0 < share < 100
+    monkeypatch.setattr(rsm._reducer, "traced", lambda: {
+        "rounds": 4, "device_ns_by_scope": {"": 5e6}})
+    assert rsm.scope_ms("fed.model.moe") is None    # a program with no scope
+    for name, key in (("moe_relu2_load_max_over_mean",) * 2,
+                      ("moe_relu2_fill_pct",) * 2,
+                      ("adapter_upload_mb_ssm_moe.round",
+                       "adapter_upload_mb_round")):
+        reader = _load(f"layer_metrics/{name}.py")
+        assert reader.read(summary) == summary[key]
+        assert reader.read({}) is None
+
+
+def test_every_nemotron_limit_lies_between_its_two_readings():
+    """Each limit, the loss's too, at least twice the program's largest
+    reading and at most half the 4-bit control's."""
+    runner = _load("runners/fed_adapter_ssm_moe_lm_round.py")
+    assert set(runner.TOLERANCES) == {
+        "mamba", "attention", "shared_expert", "held_experts", "loss"}
+    for kind, t in runner.TOLERANCES.items():
+        assert t["why"] and set(t) == {"limit", "program", "control", "why"}
+        assert 2 * t["program"] <= t["limit"] <= t["control"] / 2, kind
+    assert runner.kind_of(("layer_0", "mamba", "lora_in_proj_a")) == "mamba"
+    assert runner.kind_of(("layer_5", "attn", "lora_q_proj_a")) == "attention"
+    assert runner.kind_of(("layer_1", "moe", "shared",
+                           "lora_down_proj_a")) == "shared_expert"
+    assert runner.kind_of(("layer_1", "moe",
+                           "lora_experts_up_b")) == "held_experts"
+    with pytest.raises(KeyError):
+        runner.kind_of(("layer_1", "moe", "router"))
+    before = {"layer_1": {"expert_tokens": np.zeros(4),
+                          "grouped_rows": np.float64(0)}}
+    after = {"layer_1": {"expert_tokens": np.asarray([10., 20., 0., 18.]),
+                         "grouped_rows": np.float64(96)}}
+    assert runner.fill_pct(before, after) == pytest.approx(50.0)
+    assert runner.fill_pct(before, before) is None
+
+
+def test_a_pair_left_out_of_one_block_is_read_by_the_kinds_downstream(
+        nemotron, ssm_moe_mix):
+    """``fed_adapter_ssm_moe_lm_round``'s own stand-in at the rehearsal's
+    sizes on the CPU: the reference's round with the first Mamba-2 block's
+    ``out_proj`` pair left out of the forward, in the program's place. Not
+    ``correct``: the pair's own kind over its limit (it is left as it
+    started), and the kinds of the blocks after it too, which see the fault
+    only through the residual stream."""
+    import argparse
+
+    run = _load("run.py")
+    manifest = run.load_manifest()
+    cell = run.by_name(manifest["workloads"], NEMOTRON_CELL, "workload")
+    args = argparse.Namespace(seed=3900000778, seconds=1.0, trace=0,
+                              dryrun_cpu=True)
+    mix = {**ssm_moe_mix, "stand_in": "pair_left_out:layer_0/mamba/out_proj"}
+    ctx = run.Ctx(manifest, cell, nemotron, mix, args, "cpu")
+    runner = ctx.load_module(f"runners/{mix['runner']}.py")
+    result = runner.run(ctx)
+    summary = result["summary"]
+    errors = summary["reference_errors"]
+    over = {k for k, v in errors.items()
+            if v > runner.TOLERANCES[k]["limit"]}
+    assert not result["correct"] and summary["stand_in"] == mix["stand_in"]
+    assert "mamba" in over and over & {"held_experts", "shared_expert",
+                                       "attention"}, errors
+    assert summary["moe_dropped_tokens"] == 0
+    assert summary["adapter_upload_mb_round"] > 0
+
+
+def test_nemotron_dryrun_sizes_name_every_kind_of_block(nemotron):
+    kwargs = nemotron["dryrun"]["factory_kwargs"]
+    assert kwargs["hybrid_override_pattern"] == "MEMEM*EME"
+    assert kwargs["num_hidden_layers"] == 9 and kwargs["hidden_size"] == 64
+    assert kwargs["n_groups"] > 1
+    assert kwargs["num_attention_heads"] * kwargs["head_dim"] \
+        != kwargs["hidden_size"]
+    assert kwargs["moe_intermediate_size"] % 8      # off every grid
+    assert kwargs["num_experts_held"] < kwargs["n_routed_experts"]
+    assert nemotron["dryrun"]["classes"] == kwargs["vocab_size"] == 257

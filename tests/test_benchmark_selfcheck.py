@@ -134,9 +134,39 @@ def test_the_expert_adapter_cell_rehearses_on_the_cpu():
                 or "roofline" in k or k.startswith("mfu")]
 
 
+def test_the_single_mixer_adapter_cell_rehearses_on_the_cpu():
+    """``nemotron3nano_lora_c4_s4k`` as the driver starts it, at the files'
+    dryrun sizes, traced: exit 0, ``correct`` (round 0 against the reference
+    per kind of block, the base unchanged and one operand, no token dropped),
+    both program counters' metrics on the line, every new reader called (a
+    CPU rehearsal gives the device's none), and never a device number."""
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_PLATFORMS"] = "cpu"
+    done = subprocess.run(
+        [sys.executable, os.path.join("benchmark", "run.py"), "--workload",
+         "nemotron3nano_lora_c4_s4k", "--seed", "3900000777", "--seconds",
+         "2", "--trace", "1", "--dryrun-cpu"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=900)
+    assert done.returncode == 0, done.stderr[-4000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert line["dryrun"] is True and line["correct"] is True, \
+        done.stderr[-3000:]
+    assert line["failed"] == 0 and line["attempted"] >= 1
+    assert line["device"]["platform"] == "cpu"
+    assert line["metrics"]["moe_relu2_load_max_over_mean"]["unit"] == "ratio"
+    assert line["metrics"]["moe_relu2_load_max_over_mean"]["value"] >= 1
+    assert 0 < line["metrics"]["moe_relu2_fill_pct"]["value"] <= 100
+    assert line["metrics"]["step_fill_pct"]["value"] == 100.0
+    assert line["metrics"]["adapter_upload_mb_ssm_moe.round"]["value"] > 0
+    assert not [k for k in line["metrics"] if k.startswith("device_")
+                or "roofline" in k or k.startswith("mfu")]
+    # two clients a group: the cell's own key reached the API
+    assert "clients a group 2" in done.stderr
+
+
 @pytest.mark.parametrize("reference", [
     "reference.py", "reference_qwen3_next.py", "reference_granite_hybrid.py",
-    "reference_k_exaone.py"])
+    "reference_k_exaone.py", "reference_nemotron_h.py"])
 def test_reference_imports_nothing_of_the_program(reference):
     """The yardstick is independent of the code under test: by its syntax
     tree, no import of ``fedml_tpu`` (at any depth of the file), and no

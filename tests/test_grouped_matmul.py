@@ -1,7 +1,9 @@
 """``ops/grouped_matmul.py``: the grouped kernel (interpreted on the CPU)
 against the plain grouped expression and against a loop over the groups,
 forward and ``dlhs``, with group edges off the tile grid, empty groups, a total
-under the rows (the tail is zero) and transposed matrices; no cotangent for
+under the rows (the tail is zero) and transposed matrices; a depth and a width
+of 14.5 lane tiles (a partial last tile, masked in depth and clipped in
+width); no cotangent for
 the frozen matrices; under ``vmap`` with the matrices unbatched; the plain
 expression wherever the shapes do not take the kernel; and the kernel
 compiled for the v5e at the benchmark cell's shapes (no chip)."""
@@ -83,6 +85,52 @@ def test_kernel_is_the_plain_grouped_expression(case, tiles, transpose):
     _close(got, _loop(lhs, rhs, SIZES[case], transpose), 1e-5)
     _close(dlhs, dlhs_plain, 1e-5)
     total = sum(SIZES[case])
+    assert not np.asarray(got[total:]).any()
+    assert not np.asarray(dlhs[total:]).any()
+
+
+# 14.5 lane tiles, as Nemotron-H's expert width 1,856 is, at a tenth the size:
+# 1.45 tiles deep and 2.9 wide, and the other way round for the second matrix
+RAGGED = {"deep": (512, 186, 371), "wide": (512, 371, 186)}
+RAGGED_SIZES = {
+    "groups_under_a_sub_tile_and_empty_ones": [3, 0, 100, 0, 27, 5, 0, 64],
+    "edges_off_the_grid": [100, 0, 157, 30, 60, 0, 0, 99],
+    "all_empty": [0] * 8,
+}
+
+
+@pytest.mark.parametrize("tiles", [(512, 256, 2048, 128), (256, 128, 128, 128)],
+                         ids=lambda t: "x".join(map(str, t)))
+@pytest.mark.parametrize("case", list(RAGGED_SIZES))
+@pytest.mark.parametrize("transpose", [False, True])
+@pytest.mark.parametrize("shape", list(RAGGED))
+def test_a_partial_last_lane_tile_is_masked_in_depth_and_clipped_in_width(
+        shape, transpose, case, tiles):
+    """A depth and a width off the lane grid take the kernel: the last depth
+    tile's columns past the depth are undefined in both operands (NaN in the
+    interpreter) and masked to zero before the product; the last width
+    tile's columns past the width are never written. Forward and ``dlhs``
+    (the transposed product, ragged the other way) against the plain
+    expression, at the module's own tiles and at small ones."""
+    m, k, n = RAGGED[shape]
+    assert takes_kernel(m, k, n) and k % 128 and n % 128
+    sizes = jnp.asarray(RAGGED_SIZES[case], jnp.int32)
+    rng = np.random.default_rng(5)
+    lhs = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
+    rhs = jnp.asarray(rng.normal(size=(8, n, k) if transpose else (8, k, n))
+                      * 0.1, jnp.float32)
+    cot = jnp.asarray(rng.normal(size=(m, n)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        got, vjp = jax.vjp(lambda l: _tiled(l, rhs, sizes, tiles, transpose),
+                           lhs)
+        want, plain_vjp = jax.vjp(
+            lambda l: gm.plain(l, rhs, sizes, transpose), lhs)
+        (dlhs,), (dlhs_plain,) = vjp(cot), plain_vjp(cot)
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.isfinite(np.asarray(dlhs)).all()
+    _close(got, want, 1e-5)
+    _close(dlhs, dlhs_plain, 1e-5)
+    total = sum(RAGGED_SIZES[case])
     assert not np.asarray(got[total:]).any()
     assert not np.asarray(dlhs[total:]).any()
 
@@ -172,6 +220,9 @@ def test_under_vmap_the_frozen_matrices_have_no_client_axis(clients):
     (8192, 2048, 6144, True),       # its down projection
     (4096, 1024, 1024, True),       # chip_smoke's
     (128, 128, 128, True),
+    (24576, 2688, 1856, True),      # Nemotron-H's experts: 14.5 lane tiles
+    (24576, 1856, 2688, True),
+    (128, 192, 320, True),          # a partial last tile in depth and width
     (96, 128, 128, False),          # rows under a sub-tile
     (128, 32, 128, False),          # a toy depth
     (128, 128, 24, False),          # a toy width
@@ -215,22 +266,27 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("m,k,n,transpose", [
-    (8192, 6144, 4096, False), (8192, 2048, 6144, False),
-    (8192, 4096, 6144, True), (8192, 6144, 2048, True)],
-    ids=["gate_up", "down", "gate_up_t", "down_t"])
+@pytest.mark.parametrize("groups,m,k,n,transpose", [
+    (16, 8192, 6144, 4096, False), (16, 8192, 2048, 6144, False),
+    (16, 8192, 4096, 6144, True), (16, 8192, 6144, 2048, True),
+    (64, 24576, 2688, 1856, True), (64, 24576, 1856, 2688, False)],
+    ids=["gate_up", "down", "gate_up_t", "down_t", "relu2_up_and_down_t",
+         "relu2_down_and_up_t"])
 def test_the_kernel_compiles_for_the_v5e_at_the_cells_shapes(
-        monkeypatch, one_chip, m, k, n, transpose):
-    """K-EXAONE's held experts, a chunk of 8,192 rows, forward and ``dlhs``:
-    Mosaic takes the kernel at its own tiles (alignment, VMEM)."""
+        monkeypatch, one_chip, groups, m, k, n, transpose):
+    """K-EXAONE's held experts, a chunk of 8,192 rows, forward and ``dlhs``,
+    and Nemotron-H's 64 of width 1,856 (14.5 lane tiles: ``[64, 1856, 2688]``
+    read as it lies, transposed for the up product and ``dlhs`` of the down
+    one, plain for the other two), a chunk of 24,576 rows: Mosaic takes the
+    kernel at its own tiles (alignment, the partial tile's masks, VMEM)."""
     monkeypatch.setattr(gm, "pallas_interpret", lambda: False)
     spec = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dtype, sharding=one_chip)
-    rhs = (16, n, k) if transpose else (16, k, n)
+    rhs = (groups, n, k) if transpose else (groups, k, n)
     compiled = jax.jit(lambda l, r, g: grouped_matmul(
         l, r, g, transpose_rhs=transpose, name="held_gmm")).trace(
             spec((m, k), jnp.bfloat16), spec(rhs, jnp.bfloat16),
-            spec((16,), jnp.int32)).lower(
+            spec((groups,), jnp.int32)).lower(
                 lowering_platforms=("tpu",)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "held_gmm" in text

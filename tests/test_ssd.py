@@ -88,3 +88,35 @@ def test_heads_must_divide_into_the_groups():
     x, dt, a, b, c = _operands(8, groups=1)
     with pytest.raises(ValueError, match="groups"):
         ssd_scan(x, dt, a, jnp.tile(b, (1, 1, 3, 1)), jnp.tile(c, (1, 1, 3, 1)))
+
+
+def test_eight_groups_in_chunks_of_128_equal_the_recurrence():
+    """Nemotron-H's shape of the scan at a small size: 16 heads in 8 B/C
+    groups (two heads a group), chunks of 128 over 300 tokens (two whole
+    chunks and a padded third), forward and the gradients of x, B and C."""
+    k = jax.random.split(jax.random.PRNGKey(8), 5)
+    t, h, g, p, n = 300, 16, 8, 8, 16
+    x = jax.random.normal(k[0], (1, t, h, p))
+    dt = jax.nn.softplus(jax.random.normal(k[1], (1, t, h)) - 2.0)
+    a = -jnp.exp(jax.random.uniform(k[2], (h,), minval=0.0, maxval=2.7))
+    b, c = (jax.random.normal(key, (1, t, g, n)) for key in k[3:])
+
+    def through(fn):
+        return jax.value_and_grad(
+            lambda x, b, c: jnp.sum(jnp.sin(fn(x, dt, a, b, c))),
+            argnums=(0, 1, 2))
+
+    with jax.default_matmul_precision("highest"):
+        got = ssd_scan(x, dt, a, b, c, chunk=128)
+        want = ssd_recurrence(x, dt, a, b, c)
+        (_, grads), (_, wanted) = (through(fn)(x, b, c) for fn in (
+            lambda *v: ssd_scan(*v, chunk=128), ssd_recurrence))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5 * float(
+        jnp.abs(want).max()))
+    # a group's B and C serve its own two heads and no other's
+    other = ssd_recurrence(x, dt, a, jnp.roll(b, 1, axis=2), c)
+    assert float(jnp.abs(other - want).max()) > 1e-2
+    for name, got_g, want_g in zip(("x", "b", "c"), grads, wanted):
+        np.testing.assert_allclose(
+            got_g, want_g, rtol=1e-4, atol=1e-5 * float(jnp.abs(want_g).max()),
+            err_msg=name)
