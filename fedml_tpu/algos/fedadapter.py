@@ -96,6 +96,10 @@ class FedAdapterAPI(FedAvgAPI):
         #: with a pair that ``ops.lora_linear.tally`` saw while a program of
         #: this class was traced (``experts`` > 0: a grouped product's)
         self._lora_traced: dict = {}
+        #: ``{(m, k, n): clients}`` for every grouped product that took its
+        #: kernel while a program of this class was traced: the widest
+        #: client axis its grid had (``ops.grouped_matmul.note``)
+        self._products_traced: dict = {}
         #: Optional PRETRAINED dense params to freeze as the base (the
         #: finetuning story); None = the deterministic fresh init.
         self._base_params = base_params
@@ -125,7 +129,8 @@ class FedAdapterAPI(FedAvgAPI):
                                  base_params=self._base_params)
 
     def _jit(self, fn, donate_argnums=()):
-        return _BaseOperand(self.fns, fn, donate_argnums, self._lora_traced)
+        return _BaseOperand(self.fns, fn, donate_argnums, self._lora_traced,
+                            self._products_traced)
 
     def _lora_sites(self):
         """``(sites, fused)``: the adapter tree's pairs (a stacked leaf is
@@ -186,12 +191,22 @@ class FedAdapterAPI(FedAvgAPI):
         ``experts_held`` (the frozen expert MLPs in the base, all layers),
         ``lora_sites_fused`` (the projections that went through
         ``ops.lora_linear`` when the rounds' programs were traced, and those
-        of them whose shapes take its one-pass kernel)."""
+        of them whose shapes take its one-pass kernel), and
+        ``grouped_products`` / ``grouped_products_client_grid`` (the shapes
+        of grouped products that took ``ops.grouped_matmul``'s kernel when
+        the rounds' programs were traced, and those of them whose kernel had
+        two or more clients on its grid: a ``vmap`` of the clients that
+        became ONE call, not a loop over them)."""
         from fedml_tpu.models.adapter import param_count
 
         sites, fused = self._lora_sites()
-        self._adapter_registry.gauge("lora_sites").set(sites)
-        self._adapter_registry.gauge("lora_sites_fused").set(fused)
+        reg = self._adapter_registry
+        reg.gauge("lora_sites").set(sites)
+        reg.gauge("lora_sites_fused").set(fused)
+        products = self._products_traced.values()
+        reg.gauge("grouped_products").set(len(products))
+        reg.gauge("grouped_products_client_grid").set(
+            sum(clients >= 2 for clients in products))
         a = param_count(self.net.params)
         b = param_count(self.base)
         return {"base_params": b, "total_params": a + b,
@@ -322,21 +337,31 @@ class _BaseOperand:
     bound as its first operand (``AdapterFns.bind``). Called inside another
     such program, it hands on the operand that one was given. A call that
     traces notes in ``traced`` which projections ``ops.lora_linear`` saw
-    (``FedAdapterAPI._lora_sites``)."""
+    (``FedAdapterAPI._lora_sites``), and in ``products`` the grouped
+    products' shapes with the widest client axis of their kernel's grid."""
 
-    def __init__(self, fns, fn, donate_argnums, traced: dict):
+    def __init__(self, fns, fn, donate_argnums, traced: dict,
+                 products: dict):
         self._base = fns.base
         self._traced = traced
+        self._products = products
         self._jitted = jax.jit(
             fns.bind(fn), donate_argnums=tuple(i + 1 for i in donate_argnums))
 
     def __call__(self, *args):
+        from fedml_tpu.ops.grouped_matmul import Traced
         from fedml_tpu.ops.lora_linear import tally
 
         with tally() as sites:
             out = self._jitted(self._base(), *args)
-        for _, k, n, rank, fused, experts in sites:
-            self._traced[(k, n, rank, experts)] = fused
+        for site in sites:
+            if isinstance(site, Traced):
+                shape = site[:3]
+                self._products[shape] = max(site.clients,
+                                            self._products.get(shape, 1))
+            else:
+                _, k, n, rank, fused, experts = site
+                self._traced[(k, n, rank, experts)] = fused
         return out
 
     def lower(self, *args):

@@ -34,9 +34,14 @@ no option anywhere):
   that hold a row of the group are multiplied: tall tiles keep the matrices'
   traffic low (a group's matrix is read once a visit) without paying products
   for the rows of a neighbour. Rows that no visit writes are masked to zero
-  before anything reads them. Under ``vmap`` ``pallas_call`` squeezes a batch
-  of one; a wider batch of the scalar-prefetch operands runs as a loop over
-  the batch, the unbatched ``rhs`` handed in whole.
+  before anything reads them. Under a ``vmap`` of two or more clients that
+  batches the rows and the group sizes and not ``rhs`` (the adapter round's),
+  the kernel has its own batching rule: ONE call with the clients on its
+  grid, their rows read as ``[B m, k]`` where they lie and the result
+  written as ``[B m, n]``, each client's groups its own groups over its own
+  row tiles (``jax.custom_batching.custom_vmap``). A batch of one is
+  squeezed to the unbatched call; a batch of the matrices too runs as
+  ``pallas_call``'s loop over the batch.
 * **The plain expression** everywhere else, toy and dry-run widths included:
   one ``dot_general`` of the rows masked a group, ``[G, m, k] x [G, k, n]``
   contracted over group and depth, which also reads ``rhs`` as it lies (at
@@ -51,13 +56,14 @@ the matrices are frozen, and no product forms their gradient.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from fedml_tpu.ops.lora_linear import _divisor
+from fedml_tpu.ops.lora_linear import _divisor, record
 from fedml_tpu.ops.platform import pallas_interpret
 
 F32 = jnp.float32
@@ -70,6 +76,20 @@ F32 = jnp.float32
 TM, TK, TN, SUB = 512, 256, 2048, 128
 _PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary", "arbitrary"))
+
+
+class Traced(NamedTuple):
+    """A grouped product that takes the kernel, as a trace saw it (host
+    side, trace time; recorded in every open ``ops.lora_linear.tally``): a
+    client's rows ``m``, depth ``k`` and width ``n``, and ``clients``, the
+    width of the client axis on the kernel's grid. A product is recorded
+    with 1 when its call is traced, and again with the clients when a
+    ``vmap`` puts two or more on the grid (``FedAdapterAPI`` keeps a shape's
+    widest)."""
+    m: int
+    k: int
+    n: int
+    clients: int
 
 
 def takes_kernel(m: int, k: int, n: int) -> bool:
@@ -156,21 +176,23 @@ def _kernel(offsets, group, tile, active, lhs_ref, rhs_ref, out_ref, acc_ref,
                                      out_ref[...])
 
 
-@functools.partial(jax.jit, static_argnames=("transpose_rhs", "tiles", "name"))
-def _grouped(lhs, rhs, group_sizes, transpose_rhs: bool, tiles, name: str):
-    """The kernel; rows that no visit wrote are undefined. Jitted, so that a
-    program that calls it many times at the same shapes (a layer's forward,
-    recomputed forward and backward, layer after layer) traces the kernel
-    and lowers it to Mosaic once: with a lowering a call the benchmark
-    cell's round took 4 s longer to trace (PERF.md section 6, PR 35). XLA
-    inlines the calls and names each instance by its own call site's
-    scopes."""
-    m, k = lhs.shape
+def _fit(m: int, k: int, n: int, tiles):
+    """``tiles`` fitted to a client's ``m`` rows, depth and width: a row tile
+    that divides ``m`` in whole sub-tiles, depth and width tiles in whole
+    lanes."""
+    tm, tk, tn, sub = tiles
+    return _divisor(m, min(m, tm), sub), _tile(k, tk), _tile(n, tn), sub
+
+
+def _call(lhs, rhs, visits, transpose_rhs: bool, tiles, name: str,
+          expert=lambda group: group):
+    """The kernel over ``lhs [rows, k]`` at fitted ``tiles``: ``visits`` is
+    ``(offsets, group, tile, active)``, ``expert`` a visit's group's matrix
+    in ``rhs``."""
+    k = lhs.shape[1]
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     tm, tk, tn, sub = tiles
-    tm, tk, tn = _divisor(m, min(m, tm), sub), _tile(k, tk), _tile(n, tn)
     steps = pl.cdiv(k, tk)
-    n_visits = m // tm + rhs.shape[0] - 1
 
     def depth(visit, step, active):
         # a skipped visit asks for the block the last step left in VMEM
@@ -182,7 +204,7 @@ def _grouped(lhs, rhs, group_sizes, transpose_rhs: bool, tiles, name: str):
     def rhs_at(j, v, s, offsets, group, tile, active):
         at = (j, depth(v, s, active)) if transpose_rhs else (
             depth(v, s, active), j)
-        return (group[v],) + at
+        return (expert(group[v]),) + at
 
     def out_at(j, v, s, offsets, group, tile, active):
         return tile[v], j
@@ -192,17 +214,106 @@ def _grouped(lhs, rhs, group_sizes, transpose_rhs: bool, tiles, name: str):
                           last=k % tk, transpose_rhs=transpose_rhs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(pl.cdiv(n, tn), n_visits, steps),
+            grid=(pl.cdiv(n, tn), visits[1].shape[0], steps),
             in_specs=[pl.BlockSpec((tm, tk), lhs_at),
                       pl.BlockSpec((None, tn, tk) if transpose_rhs
                                    else (None, tk, tn), rhs_at)],
             out_specs=pl.BlockSpec((tm, tn), out_at),
             scratch_shapes=[pltpu.VMEM((tm, tn), F32)]),
-        out_shape=jax.ShapeDtypeStruct((m, n), F32),
+        out_shape=jax.ShapeDtypeStruct((lhs.shape[0], n), F32),
         compiler_params=_PARAMS,
         interpret=pallas_interpret(),
         name=name,
-    )(*_visits(group_sizes, m, tm), lhs, rhs)
+    )(*visits, lhs, rhs)
+
+
+@functools.partial(jax.jit, static_argnames=("transpose_rhs", "tiles", "name"))
+def _grouped(lhs, rhs, group_sizes, transpose_rhs: bool, tiles, name: str):
+    """The kernel; rows that no visit wrote are undefined. Jitted, so that a
+    program that calls it many times at the same shapes (a layer's forward,
+    recomputed forward and backward, layer after layer) traces the kernel
+    and lowers it to Mosaic once: with a lowering a call the benchmark
+    cell's round took 4 s longer to trace (PERF.md section 6, PR 35). XLA
+    inlines the calls and names each instance by its own call site's
+    scopes."""
+    m, k = lhs.shape
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    tiles = _fit(m, k, n, tiles)
+    return _call(lhs, rhs, _visits(group_sizes, m, tiles[0]), transpose_rhs,
+                 tiles, name)
+
+
+def _client_visits(group_sizes, m: int, tm: int):
+    """:func:`_visits` of ``B`` clients (``group_sizes [B, G]``) whose ``m``
+    rows each lie end to end: client ``b``'s group ``g`` is group ``b (G + 1)
+    + g`` and its row tile ``t`` is tile ``b m / tm + t``; ``offsets [B (G +
+    1)]`` are each client's moved by ``b m``, so that the last of them, its
+    total, starts a gap group that no visit has (the client's rows past its
+    total); ``group``, ``tile [B V]`` the clients' active visits in turn, the
+    last repeated after them; ``active [1]`` their sum."""
+    clients, n_groups = group_sizes.shape
+    offsets, group, tile, active = jax.vmap(
+        lambda sizes: _visits(sizes, m, tm))(group_sizes)
+    active = active[:, 0]
+    upto = jnp.cumsum(active)
+    visit = jnp.minimum(jnp.arange(group.size), jnp.maximum(upto[-1] - 1, 0))
+    client = jnp.minimum(jnp.searchsorted(upto, visit, side="right"),
+                         clients - 1)
+    at = visit - (upto - active)[client]
+    as_i32 = lambda a: a.astype(jnp.int32)  # noqa: E731
+    return (as_i32(offsets + m * jnp.arange(clients)[:, None]).reshape(-1),
+            as_i32(client * (n_groups + 1) + group[client, at]),
+            as_i32(client * (m // tm) + tile[client, at]),
+            as_i32(upto[-1:]))
+
+
+@functools.partial(jax.jit, static_argnames=("transpose_rhs", "tiles", "name"))
+def _grouped_clients(lhs, rhs, group_sizes, transpose_rhs: bool, tiles,
+                     name: str):
+    """``B`` clients' products as ONE kernel call, the clients on its grid:
+    ``lhs [B, m, k]``, ``group_sizes [B, G]``, ``rhs`` shared -> ``[B, m,
+    n]``. The rows are read as ``[B m, k]`` and the result written as ``[B m,
+    n]`` (leading axes merged: no copy), the clients' groups are ``B (G +
+    1)`` groups over them (:func:`_client_visits`) whose matrix is the group
+    modulo ``G + 1``, and a row tile divides ``m``: none holds two clients'
+    rows."""
+    clients, m, k = lhs.shape
+    n_groups = rhs.shape[0]
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    tiles = _fit(m, k, n, tiles)
+    out = _call(lhs.reshape(clients * m, k), rhs,
+                _client_visits(group_sizes, m, tiles[0]), transpose_rhs,
+                tiles, name, expert=lambda group: group % (n_groups + 1))
+    return out.reshape(clients, m, n)
+
+
+def _batched_by_clients(m: int, k: int, n: int, transpose_rhs: bool, tiles,
+                        name: str):
+    """The kernel at one client's shapes, with the rule that batches it:
+    where a ``vmap`` of two or more clients batches the rows and the group
+    sizes and not the matrices, ONE call with the clients on its grid
+    (:func:`_grouped_clients`); any other batch (one client, or batched
+    matrices) as ``pallas_call``'s own rule batches it: a batch of one
+    squeezed, a wider one a loop over the batch. Each records what it
+    traced (:class:`Traced`)."""
+    call = functools.partial(_grouped, transpose_rhs=transpose_rhs,
+                             tiles=tiles, name=name)
+
+    @jax.custom_batching.custom_vmap
+    def product(lhs, rhs, group_sizes):
+        record(Traced(m, k, n, 1))
+        return call(lhs, rhs, group_sizes)
+
+    @product.def_vmap
+    def _rule(clients, batched, lhs, rhs, group_sizes):
+        if clients > 1 and batched == [True, False, True]:
+            record(Traced(m, k, n, clients))
+            return _grouped_clients(lhs, rhs, group_sizes, transpose_rhs,
+                                    tiles, name), True
+        return jax.vmap(call, in_axes=tuple(0 if b else None for b in batched)
+                        )(lhs, rhs, group_sizes), True
+
+    return product
 
 
 def group_of_rows(group_sizes, m: int):
@@ -227,7 +338,8 @@ def _product(lhs, rhs, group_sizes, transpose_rhs, tiles, name):
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     if not takes_kernel(m, k, n):
         return plain(lhs, rhs, group_sizes, transpose_rhs)
-    out = _grouped(lhs, rhs, group_sizes, transpose_rhs, tiles, name)
+    out = _batched_by_clients(m, k, n, transpose_rhs, tiles, name)(
+        lhs, rhs, group_sizes)
     real = jnp.arange(m) < jnp.sum(group_sizes)
     return jnp.where(real[:, None], out, 0)
 

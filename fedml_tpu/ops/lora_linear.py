@@ -86,7 +86,8 @@ def tally():
     a pair traced while it is open (host side, trace time): what
     ``FedAdapterAPI`` counts its ``lora_sites`` from. ``experts`` is 0 for a
     projection of this file, and the number of stacked experts for a grouped
-    product that computes its pairs itself (:func:`note`)."""
+    product that computes its pairs itself (:func:`note`). A grouped product
+    that takes its kernel adds an ``ops.grouped_matmul.Traced`` besides."""
     calls: list = []
     _TALLIES.append(calls)
     try:
@@ -103,8 +104,13 @@ def note(m: int, k: int, n: int, rank: int, fused: bool,
     (``parallel.expert_parallel.held_lora_products``) notes those, unfused,
     with the rows of a chunk of that product as ``m`` and the number of
     experts stacked in one leaf."""
+    record((m, k, n, rank, fused, experts))
+
+
+def record(item) -> None:
+    """Adds ``item`` to every open :func:`tally`."""
     for calls in _TALLIES:
-        calls.append((m, k, n, rank, fused, experts))
+        calls.append(item)
 
 
 def _divisor(n: int, most: int, unit: int) -> int:

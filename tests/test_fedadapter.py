@@ -126,6 +126,34 @@ def test_adapter_init_is_exact_identity():
     np.testing.assert_array_equal(np.asarray(da), np.asarray(la))
 
 
+def test_the_profile_counts_the_grouped_products_with_clients_on_the_grid():
+    """``adapter_profile()``'s ``grouped_products`` and
+    ``grouped_products_client_grid``: none before a program that holds a
+    grouped product is traced (this model has none); then the shapes of the
+    products that took ``ops.grouped_matmul``'s kernel in the API's own
+    programs, and of those the ones a ``vmap`` of two clients lowered to one
+    call with the clients on its grid (not one client's)."""
+    from fedml_tpu.ops.grouped_matmul import grouped_matmul
+
+    x, y, parts = _token_data()
+    api = _mk(build_federated_arrays(x, y, parts, B))
+    api.train_one_round(0)
+    profile = api.adapter_profile()
+    assert (profile["grouped_products"],
+            profile["grouped_products_client_grid"]) == (0, 0)
+    rng = np.random.default_rng(0)
+    rhs = jnp.asarray(rng.normal(size=(3, 128, 256)), jnp.float32)
+    batched = jax.vmap(lambda l, s: grouped_matmul(l, rhs, s))
+    for clients, rows in ((2, 256), (1, 128)):
+        sizes = jnp.asarray([[100, 0, 28]] * clients, jnp.int32)
+        lhs = jnp.ones((clients, rows, 128), jnp.float32)
+        out = api._jit(batched)(lhs, sizes)
+        assert out.shape == (clients, rows, 256)
+    profile = api.adapter_profile()
+    assert (profile["grouped_products"],
+            profile["grouped_products_client_grid"]) == (2, 1)
+
+
 def test_pretrained_base_params_swap():
     """base_params swaps a dense checkpoint in as the frozen base; at
     the identity adapter init the merged forward equals the dense

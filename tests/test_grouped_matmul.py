@@ -4,9 +4,13 @@ forward and ``dlhs``, with group edges off the tile grid, empty groups, a total
 under the rows (the tail is zero) and transposed matrices; a depth and a width
 of 14.5 lane tiles (a partial last tile, masked in depth and clipped in
 width); no cotangent for
-the frozen matrices; under ``vmap`` with the matrices unbatched; the plain
-expression wherever the shapes do not take the kernel; and the kernel
-compiled for the v5e at the benchmark cell's shapes (no chip)."""
+the frozen matrices; under ``vmap`` with the matrices unbatched (ONE kernel
+call with the clients on its grid, a batch of one squeezed) and the loop it
+falls back to (batched matrices, a nested batch); what the tally notes; the
+plain expression wherever the shapes do not take the kernel; and the kernel
+compiled for the v5e at the benchmark cells' shapes (no chip)."""
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -188,31 +192,150 @@ def test_the_frozen_matrices_get_no_cotangent(shape):
     assert np.asarray(dlhs).any() and not np.asarray(drhs).any()
 
 
-@pytest.mark.parametrize("clients", [1, 3])
-def test_under_vmap_the_frozen_matrices_have_no_client_axis(clients):
-    """Rows and group sizes batched over clients, the matrices not: a batch
-    of one is squeezed, a wider one loops over the clients; each client's
-    result is its own call's, and no value of the batched program has a
-    client axis before the matrices' shape."""
-    rng = np.random.default_rng(3)
-    lhs = jnp.asarray(rng.normal(size=(clients, M, K)), jnp.float32)
-    rhs = jnp.asarray(rng.normal(size=(G, K, N)) * 0.1, jnp.float32)
-    sizes = jnp.asarray([[100, 0, 157, 30, 60], [0, 512, 0, 0, 0],
-                         [7, 9, 0, 300, 1]][:clients], jnp.int32)
+# a client whose total is under the rows with tiles that two groups share,
+# one whose groups are all empty, one with groups under a sub-tile
+CLIENT_SIZES = [[100, 0, 157, 30, 60, 0, 0, 99], [0] * 8,
+                [3, 0, 100, 0, 27, 5, 0, 64]]
+# whole lane tiles, and a depth and a width off the lane grid (a partial last
+# depth tile, masked)
+CLIENT_SHAPES = {"whole_tiles": (M, K, N), "partial_depth": RAGGED["deep"]}
 
-    def one(lhs, sizes):
-        return jax.value_and_grad(lambda l: jnp.sum(_tiled(
-            l, rhs, sizes, TILES[0]) ** 2))(lhs)
 
-    with jax.default_matmul_precision("highest"):
-        values, grads = jax.vmap(one)(lhs, sizes)
-        for c in range(clients):
-            value, grad = one(lhs[c], sizes[c])
-            np.testing.assert_allclose(values[c], value, rtol=1e-6)
-            _close(grads[c], grad, 1e-6)
-    text = str(jax.make_jaxpr(jax.vmap(one))(lhs, sizes))
-    assert f"f32[{clients},{G},{K},{N}]" not in text
-    assert "pallas_call" in text
+def _clients_operands(clients, shape, transpose, seed=3):
+    m, k, n = CLIENT_SHAPES[shape]
+    rng = np.random.default_rng(seed)
+    lhs = jnp.asarray(rng.normal(size=(clients, m, k)), jnp.float32)
+    rhs = jnp.asarray(rng.normal(size=(8, n, k) if transpose else (8, k, n))
+                      * 0.1, jnp.float32)
+    cot = jnp.asarray(rng.normal(size=(clients, m, n)), jnp.float32)
+    return lhs, rhs, jnp.asarray(CLIENT_SIZES[:clients], jnp.int32), cot
+
+
+def _value_and_dlhs(rhs, transpose):
+    """A client's product and its ``dlhs`` for the cotangent ``cot``."""
+    def one(lhs, sizes, cot):
+        out, vjp = jax.vjp(lambda l: _tiled(l, rhs, sizes, TILES[0],
+                                            transpose), lhs)
+        return out, vjp(cot)[0]
+
+    return one
+
+
+def _kernel_calls(jaxpr, within=()):
+    """``(enclosing primitives, pallas_call eqn)`` of every kernel call, and
+    every ``dynamic_update_slice`` (as ``(within, None)``), nested jaxprs
+    too."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append((within, eqn))
+            continue
+        if eqn.primitive.name == "dynamic_update_slice":
+            found.append((within, None))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(_kernel_calls(sub, within + (eqn.primitive.name,)))
+    return found
+
+
+def _signature(eqn):
+    """What a kernel call lowers from: its body, grid, index maps, block
+    shapes and operands."""
+    grid = eqn.params["grid_mapping"]
+    return (str(eqn.params["jaxpr"]), grid.grid,
+            [str(b.index_map_jaxpr) for b in grid.block_mappings],
+            [b.block_shape for b in grid.block_mappings],
+            [v.aval for v in eqn.invars], [v.aval for v in eqn.outvars])
+
+
+@pytest.mark.parametrize("shape", list(CLIENT_SHAPES))
+@pytest.mark.parametrize("transpose", [False, True])
+@pytest.mark.parametrize("clients", [1, 2, 3])
+def test_under_vmap_the_frozen_matrices_have_no_client_axis(
+        clients, transpose, shape):
+    """Rows and group sizes batched over clients, the matrices not: each
+    client's product and ``dlhs`` are its own call's, bit for bit (an empty
+    client, a total under the rows, tiles two groups share, a partial depth
+    tile); two or more clients are ONE kernel call a product with the
+    clients on its grid, in no loop and with no ``dynamic_update_slice``; a
+    batch of one is the unbatched call, squeezed; no value of the batched
+    program has a client axis before the matrices' shape."""
+    lhs, rhs, sizes, cot = _clients_operands(clients, shape, transpose)
+    one = _value_and_dlhs(rhs, transpose)
+    values, grads = jax.vmap(one)(lhs, sizes, cot)
+    for c in range(clients):
+        value, grad = one(lhs[c], sizes[c], cot[c])
+        np.testing.assert_array_equal(values[c], value)
+        np.testing.assert_array_equal(grads[c], grad)
+        assert not np.asarray(values[c][sum(CLIENT_SIZES[c]):]).any()
+    jaxpr = jax.make_jaxpr(jax.vmap(one))(lhs, sizes, cot)
+    calls = _kernel_calls(jaxpr.jaxpr)
+    assert len(calls) == 2                    # the product and its dlhs
+    for within, eqn in calls:
+        assert eqn is not None and not {"while", "scan"} & set(within)
+    if clients == 1:
+        alone = jax.make_jaxpr(one)(lhs[0], sizes[0], cot[0])
+        assert [_signature(e) for _, e in calls] == [
+            _signature(e) for _, e in _kernel_calls(alone.jaxpr)]
+    else:
+        m, k, _ = CLIENT_SHAPES[shape]
+        assert calls[0][1].invars[-2].aval.shape == (clients * m, k)
+    text = str(jaxpr)
+    assert "f32[{},8,{},{}]".format(clients, *rhs.shape[1:]) not in text
+
+
+@pytest.mark.parametrize("batch", ["matrices_batched", "nested"])
+def test_a_batch_the_grid_does_not_take_falls_back_to_the_loop(batch):
+    """Matrices batched with the rows (each client its own), or a ``vmap``
+    inside a ``vmap``: the kernel falls back to ``pallas_call``'s loop over
+    the (outer) batch, and each client's product and ``dlhs`` are still its
+    own call's."""
+    lhs, rhs, sizes, cot = _clients_operands(2, "whole_tiles", False)
+    if batch == "matrices_batched":
+        rhs = jnp.stack([rhs, rhs[::-1]])
+        batched = jax.vmap(lambda r, *a: _value_and_dlhs(r, False)(*a))
+        values, grads = batched(rhs, lhs, sizes, cot)
+        want = [_value_and_dlhs(rhs[c], False)(lhs[c], sizes[c], cot[c])
+                for c in range(2)]
+        jaxpr = jax.make_jaxpr(batched)(rhs, lhs, sizes, cot)
+    else:
+        one = _value_and_dlhs(rhs, False)
+        pair = lambda a: jnp.stack([a, a[::-1]])  # noqa: E731
+        lhs, sizes, cot = pair(lhs), pair(sizes), pair(cot)
+        values, grads = jax.vmap(jax.vmap(one))(lhs, sizes, cot)
+        want = [[one(lhs[o, c], sizes[o, c], cot[o, c]) for c in range(2)]
+                for o in range(2)]
+        values, grads = values.reshape(4, *values.shape[2:]), grads.reshape(
+            4, *grads.shape[2:])
+        want = want[0] + want[1]
+        jaxpr = jax.make_jaxpr(jax.vmap(jax.vmap(one)))(lhs, sizes, cot)
+    for c, (value, grad) in enumerate(want):
+        np.testing.assert_array_equal(values[c], value)
+        np.testing.assert_array_equal(grads[c], grad)
+    assert any(eqn is None for _, eqn in _kernel_calls(jaxpr.jaxpr))
+
+
+def test_the_tally_notes_the_clients_on_the_grid():
+    """Under ``ops.lora_linear.tally`` a product that takes the kernel notes
+    ``Traced(m, k, n, 1)`` when its call is traced, and again with the
+    clients when a ``vmap`` of two or more puts them on its grid (its
+    ``dlhs`` likewise, ``k`` and ``n`` swapped); a ``vmap`` of one client
+    notes no grid, nor does the plain expression anything."""
+    from fedml_tpu.ops import lora_linear as ll
+
+    lhs, rhs, sizes, cot = _clients_operands(2, "whole_tiles", False)
+    one = _value_and_dlhs(rhs, False)
+    for clients in (2, 1):
+        with ll.tally() as calls:
+            jax.make_jaxpr(jax.vmap(one))(lhs[:clients], sizes[:clients],
+                                          cot[:clients])
+        grid = {(M, K, N, clients), (M, N, K, clients)} - {
+            (M, K, N, 1), (M, N, K, 1)}
+        assert set(calls) == {gm.Traced(M, K, N, 1), gm.Traced(M, N, K, 1),
+                              *(gm.Traced(*g) for g in grid)}
+    with ll.tally() as calls:
+        jax.make_jaxpr(jax.vmap(lambda l, s: grouped_matmul(
+            l, rhs[:, :16, :24], s)))(lhs[:, :48, :16], sizes)
+    assert calls == []
 
 
 @pytest.mark.parametrize("m,k,n,takes", [
@@ -266,30 +389,41 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("groups,m,k,n,transpose", [
-    (16, 8192, 6144, 4096, False), (16, 8192, 2048, 6144, False),
-    (16, 8192, 4096, 6144, True), (16, 8192, 6144, 2048, True),
-    (64, 24576, 2688, 1856, True), (64, 24576, 1856, 2688, False)],
+@pytest.mark.parametrize("groups,m,k,n,transpose,clients", [
+    (16, 8192, 6144, 4096, False, 0), (16, 8192, 2048, 6144, False, 0),
+    (16, 8192, 4096, 6144, True, 0), (16, 8192, 6144, 2048, True, 0),
+    (64, 24576, 2688, 1856, True, 0), (64, 24576, 1856, 2688, False, 0),
+    (64, 24576, 2688, 1856, True, 2), (64, 24576, 1856, 2688, False, 2)],
     ids=["gate_up", "down", "gate_up_t", "down_t", "relu2_up_and_down_t",
-         "relu2_down_and_up_t"])
+         "relu2_down_and_up_t", "relu2_up_and_down_t_two_clients",
+         "relu2_down_and_up_t_two_clients"])
 def test_the_kernel_compiles_for_the_v5e_at_the_cells_shapes(
-        monkeypatch, one_chip, groups, m, k, n, transpose):
+        monkeypatch, one_chip, groups, m, k, n, transpose, clients):
     """K-EXAONE's held experts, a chunk of 8,192 rows, forward and ``dlhs``,
     and Nemotron-H's 64 of width 1,856 (14.5 lane tiles: ``[64, 1856, 2688]``
     read as it lies, transposed for the up product and ``dlhs`` of the down
-    one, plain for the other two), a chunk of 24,576 rows: Mosaic takes the
-    kernel at its own tiles (alignment, the partial tile's masks, VMEM)."""
+    one, plain for the other two), a chunk of 24,576 rows, alone and under a
+    ``vmap`` of two clients as the cell's round trains them: Mosaic takes the
+    kernel at its own tiles (alignment, the partial tile's masks, VMEM), and
+    the two clients' result is the kernel's own, no
+    ``dynamic-update-slice`` of a ``[2, 24576, .]`` buffer."""
     monkeypatch.setattr(gm, "pallas_interpret", lambda: False)
     spec = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dtype, sharding=one_chip)
     rhs = (groups, n, k) if transpose else (groups, k, n)
-    compiled = jax.jit(lambda l, r, g: grouped_matmul(
-        l, r, g, transpose_rhs=transpose, name="held_gmm")).trace(
-            spec((m, k), jnp.bfloat16), spec(rhs, jnp.bfloat16),
-            spec((groups,), jnp.int32)).lower(
-                lowering_platforms=("tpu",)).compile()
+    product = lambda l, r, g: grouped_matmul(  # noqa: E731
+        l, r, g, transpose_rhs=transpose, name="held_gmm")
+    batch = (clients,) if clients else ()
+    if clients:
+        product = jax.vmap(product, in_axes=(0, None, 0))
+    compiled = jax.jit(product).trace(
+        spec(batch + (m, k), jnp.bfloat16), spec(rhs, jnp.bfloat16),
+        spec(batch + (groups,), jnp.int32)).lower(
+            lowering_platforms=("tpu",)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "held_gmm" in text
+    assert not re.search(r"f32\[2,24576,\d+\][^\n]*dynamic-update-slice",
+                         text)
 
 
 def test_the_band_kernels_compile_for_the_v5e_at_the_cells_shapes(
