@@ -63,11 +63,11 @@ attention's blocks (``chosen``) and the router's experts (``experts``).
 Device scopes (``jax.named_scope``): ``fed.model.attn.msa`` (the input norm,
 projections, q/k norms, rotary, the indexer, the selection and the core),
 ``.select`` (the indexer's projection, pooling, scores, top-k and the
-visiting lists' sort by block) and ``.core`` (the kernels); ``fed.model.mlp``
-(the dense layer's), ``fed.model.moe`` (``.route``, ``.experts``,
-``.shared``), ``fed.model.norm`` (the FFN branch's input norm and its
-residual add), ``fed.model.lora`` (every pair's two products but the held
-experts'), ``fed.model.head`` (embedding, final norm, head, loss).
+counters) and ``.core`` (the kernels and their visiting lists);
+``fed.model.mlp`` (the dense layer's), ``fed.model.moe`` (``.route``,
+``.experts``, ``.shared``), ``fed.model.norm`` (the FFN branch's input norm
+and its residual add), ``fed.model.lora`` (every pair's two products but
+the held experts'), ``fed.model.head`` (embedding, final norm, head, loss).
 """
 
 from __future__ import annotations
@@ -209,7 +209,7 @@ class MSAttention(_OaiLayer):
                 gemma_norm(exact.reshape(bsz, t, hkv, hd), w_kn,
                            c.rms_norm_eps), c.msa_block_size, c.msa_topk)
             self._count(**bsa.counts(jnp.swapaxes(chosen, 1, 2),
-                                     c.msa_block_size))
+                                     c.msa_block_size, hq // hkv))
             self.sow("intermediates", "chosen", chosen)
         q = partial_rotary(q, c.rope_theta, c.rotary_dim)
         k = partial_rotary(k, c.rope_theta, c.rotary_dim)

@@ -10,32 +10,55 @@ consecutive tokens each) it chose, causally::
               softmax_s(scale q[t, n] . k[s, g]) v[s, g]
 
 ``chosen [B, T, Hkv, S] int32`` lists a token's blocks (``-1`` in a slot that
-holds none); a group's heads share them, as NSA's selection does.
+holds none, a repeat counted once); a group's heads share them, as NSA's
+selection does.
 
-**The kernels.** A query tile is one block of ``block`` tokens. A grid step
-takes a tile's rows of ALL ``G`` heads of a group (``[block, G*D]``, a column
-block of ``q [B, T, Hq*D]`` where the projection left it) and one key block of
-the group (``[block, D]`` of ``k [B, T, Hkv*D]``), and multiplies head by head
-in a static loop: ``block``-row products, whole MXU tiles, with each token's
-row masked to the blocks IT chose. Which key blocks a tile visits is the UNION
-of its tokens' choices (``plan``), ascending and scalar-prefetched into the
-index maps: the grid is static, ``(B, Hkv, T / block, T / block)``, its steps
-past a tile's union skipped with their index maps repeating the last block, so
-nothing is fetched for them. So a tile reads each block of its union once and
-multiplies every token of the tile against it: ``msa_pairs_computed`` over
-``msa_pairs_selected`` (the model's counters) is what sharing the tile costs
-where its tokens chose differently.
+**The kernels.** A query tile is one block of ``block`` tokens; a tile's
+rows of a group are ``[block, G, D]`` of q, o and dq ``[B, T, Hq, D]`` as
+the projection lays them out (a token a leading index, its ``G`` heads on
+sublanes). ``plan`` flattens a group's chosen lists into (token, block)
+assignments and sorts each tile's by block, then token (as
+``held_lora_products`` sorts rows by expert). The blocks a tile visits are
+the UNION of its tokens' choices, ascending; a visit is packed into one
+int32 (the block, how many of the tile's tokens chose it, where they start
+in the tile's sorted list) and the lists are scalar-prefetched; a tile's
+token list is an SMEM block beside them. A visit multiplies only the
+tokens that chose the block, all ``G`` heads of a token stacked as rows:
 
-- forward: grid ``(b, g, tile, slot)``, the softmax streams over the union's
-  blocks (running maximum and sum in VMEM), ``o`` and the row log-sum-exp
-  ``[B, Hkv, G, T]`` (the sublanes a group's heads) leave at the last slot;
-- ``dq``: the same grid and masks, ``delta = rowsum(dO o O)`` made at the
-  first slot and written beside the log-sum-exp;
-- ``dk``, ``dv``: grid ``(b, g, key block, slot)`` over the query tiles whose
-  union holds the key block, the (tile, block) pairs sorted by block (the
-  transpose of the forward's lists, ``plan``): each key block's gradient is
-  summed in VMEM over its tiles and the group's heads. No atomics, no
-  ``[T, T]`` mask.
+- compacted: the tokens in chunks of ``chunk(block, G)`` (8 at 16 heads and
+  blocks of 128: 128 rows, one MXU tile), each chunk's rows gathered by
+  leading index and multiplied against the key block in one product, the
+  padding rows after the list's end masked (and written to a spare row);
+- whole tile: a block that every token of the tile chose (block 0 and the
+  own block always; shared blocks as the indexer learns) is multiplied in
+  contiguous pieces of the tile, nothing gathered. The switch reads the
+  visit's count; nothing is a setting.
+
+Only a visit to the tile's own block takes the causal mask.
+``msa_pairs_computed`` (``counts``) is what the kernels multiply: ``block``
+x ``block`` pairs a whole-tile visit, the padded chunk rows x ``block`` a
+compacted one, so ``msa_pairs_computed`` over ``msa_pairs_selected`` is
+the padding's cost.
+
+- forward, grid ``(b, g, tile)``: the group's keys and values ``[T, D]``
+  stay in VMEM (fetched once a group) and the tile's visits are a loop in
+  the kernel, in ascending order, so a token meets its blocks in order; its
+  running maximum and sum (lanes 0 and 1) and accumulator rows live in
+  VMEM (``[block + 1, G, .]``) and are read and written back by token; ``o``
+  and the row log-sum-exp ``[B, Hkv, T / block, G, block]`` (a tile's heads
+  on sublanes, tokens on lanes) leave at the end;
+- ``dq``: the same grid, keys and rows, ``delta = rowsum(dO o O)`` made
+  first and written beside the log-sum-exp; a row's two picked from its
+  token's lane;
+- ``dk``, ``dv``: grid ``(b, g, key block)`` over the tiles whose union
+  holds the key block (the transposed lists), the group's log-sum-exp and
+  ``delta`` in VMEM; the kernel fetches each visit's q and dO rows and
+  token list itself, ``IN_FLIGHT`` tiles ahead, and sums the key block's
+  gradient in VMEM over its tiles' chosen tokens and the group's heads. No
+  running state, no atomics, no ``[T, T]`` mask.
+
+At 16,384 tokens a group's keys and values are 8 MB of the 16 MiB a call
+may hold; a longer sequence needs the keys in slices.
 
 On the CPU backend the kernels run interpreted (``ops/platform.py``).
 ``plain_block_sparse_attention`` is the same arithmetic as one masked softmax
@@ -56,21 +79,55 @@ from fedml_tpu.ops.platform import pallas_interpret
 
 F32 = jnp.float32
 NEG_INF = -1e30
+#: the lanes of a row of a tile's token list (an SMEM block), and those a
+#: row's running maximum and sum are kept in (lanes 0 and 1)
+LANES = 128
+#: the tiles whose q and dO rows the dk, dv kernel fetches ahead
+IN_FLIGHT = 3
 
-_DIMS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
+
+def chunk(block: int, group: int) -> int:
+    """Tokens a compacted chunk holds: the most that divides ``block`` with
+    their ``group`` heads' rows no more than ``block`` (one MXU tile of rows
+    at blocks of 128)."""
+    return max(c for c in range(1, block + 1)
+               if block % c == 0 and c * group <= max(block, group))
+
+
+def _piece(block: int, group: int) -> int:
+    """Tokens a whole-tile visit takes at a time: four chunks' rows (the
+    tile at small sizes), so that its float32 scores stay a few MXU tiles."""
+    return min(block, 4 * chunk(block, group))
+
+
+def _fields(n: int, block: int):
+    """Bits of a packed visit: the block (or tile) id, then the count."""
+    return max(1, (n - 1).bit_length()), block.bit_length()
+
+
+def _unpack(word, n: int, block: int):
+    """``(id, count, start)`` of a packed visit."""
+    nb, cb = _fields(n, block)
+    return (word & ((1 << nb) - 1), (word >> nb) & ((1 << cb) - 1),
+            word >> (nb + cb))
 
 
 class Plan(NamedTuple):
-    """Which key blocks each query tile visits, and the transpose: ``kv
-    [B, Hkv, n, n]`` the tile's union of chosen blocks ascending, the last
-    repeated past ``kv_count [B, Hkv, n]``; ``q`` and ``q_count`` the tiles
-    whose union holds each key block, likewise. ``n = T / block``."""
+    """What the kernels visit, ``n = T / block``: ``kv [B, Hkv, n, n]`` a
+    tile's union of chosen blocks ascending, the last repeated past
+    ``kv_count [B, Hkv, n]``, each packed with its token count in the tile
+    and where those tokens start in ``tokens``; ``q`` and ``q_count`` the
+    tiles whose union holds each key block, packed with the same count and
+    start; ``tokens [B, Hkv, n, block * S / 128, 128]`` a tile's (token,
+    block) assignments sorted by block, then token, as the token's place in
+    the tile (a tile's list in rows of 128 lanes, as an SMEM block takes
+    it)."""
 
     kv: jax.Array
     kv_count: jax.Array
     q: jax.Array
     q_count: jax.Array
+    tokens: jax.Array
 
 
 def _ascending(mask):
@@ -84,59 +141,82 @@ def _ascending(mask):
     return jnp.take_along_axis(order, last, axis=-1), count
 
 
-def union(chosen, block: int):
-    """``[B, Hkv, T / block, T / block]`` bool: key block ``j`` is chosen by
-    some token of query tile ``i``. ``chosen [B, Hkv, T, S]``."""
-    b, hkv, t, s = chosen.shape
-    n = t // block
-    at = jnp.where(chosen < 0, n, chosen)       # an empty slot: off the end
-    bi = jnp.arange(b)[:, None, None, None]
-    hi = jnp.arange(hkv)[None, :, None, None]
-    ti = (jnp.arange(t) // block)[None, None, :, None]
-    return jnp.zeros((b, hkv, n, n + 1), bool).at[bi, hi, ti, at].set(
-        True)[..., :n]
+def _distinct(chosen, n: int):
+    """``chosen [..., S]`` with an empty slot, a block out of range or a
+    repeat of an earlier slot set to ``n`` (none)."""
+    c = jnp.where((chosen < 0) | (chosen >= n), n, chosen).astype(jnp.int32)
+    s = c.shape[-1]
+    earlier = jnp.arange(s)[None, :] < jnp.arange(s)[:, None]
+    repeat = jnp.any((c[..., :, None] == c[..., None, :]) & earlier, axis=-1)
+    return jnp.where(repeat, n, c)
+
+
+def _per_pair(blocks, block: int, n: int):
+    """``[B, Hkv, n, n]`` int32: tile ``i``'s tokens that chose block ``j``,
+    for ``blocks [B, Hkv, T, S]`` from ``_distinct``."""
+    b, hkv = blocks.shape[:2]
+    tile = blocks.reshape(b, hkv, n, -1)
+    return jnp.sum(tile[..., None] == jnp.arange(n, dtype=jnp.int32),
+                   axis=-2, dtype=jnp.int32)
 
 
 def plan(chosen, block: int) -> Plan:
-    """The forward's and the backward's visiting lists for ``chosen [B, Hkv,
-    T, S]``."""
-    hit = union(chosen, block)
+    """The forward's and the backward's visits for ``chosen [B, Hkv, T,
+    S]``."""
+    b, hkv, t, s = chosen.shape
+    n = t // block
+    blocks = _distinct(chosen, n)
+    per = _per_pair(blocks, block, n)
+    nb, cb = _fields(n, block)
+    if (block * s) >> (31 - nb - cb):
+        raise ValueError(f"{n} blocks of {block} with {s} choices a token do "
+                         f"not pack into an int32 visit")
+    start = jnp.cumsum(per, axis=-1) - per
+    packed = (start << (nb + cb)) | (per << nb)
+    at = jnp.arange(n, dtype=jnp.int32)
+    hit = per > 0
     kv, kv_count = _ascending(hit)
     q, q_count = _ascending(jnp.swapaxes(hit, -1, -2))
-    return Plan(kv, kv_count, q, q_count)
+    place = jnp.arange(t, dtype=jnp.int32) % block
+    keys = jnp.where(blocks < n, blocks * block + place[:, None], n * block)
+    keys = keys.reshape(b, hkv, n, block * s)
+    wide = -(-block * s // LANES) * LANES
+    keys = jnp.pad(keys, ((0, 0),) * 3 + ((0, wide - block * s),),
+                   constant_values=n * block)
+    tokens = (jnp.sort(keys, axis=-1) % block).reshape(
+        b, hkv, n, wide // LANES, LANES)
+    return Plan(jnp.take_along_axis(packed | at, kv, axis=-1), kv_count,
+                jnp.take_along_axis(jnp.swapaxes(packed, -1, -2) | at, q,
+                                    axis=-1), q_count, tokens)
 
 
-def counts(chosen, block: int) -> dict:
+def counts(chosen, block: int, group: int) -> dict:
     """What a call computes, as float32 totals over the batch and the groups
     (a group's heads share its choices, so a pair is a (token, key) pair of a
     group): ``msa_pairs_selected`` the visible pairs of the chosen blocks (a
     whole block before the token's own, the keys up to the token in its own),
-    ``msa_pairs_computed`` the pairs the kernels multiply (every token of a
-    tile against every key of its union's blocks, masked ones included), and
+    ``msa_pairs_computed`` the pairs the kernels multiply (a tile's visit to
+    a block that every token of it chose, the whole tile against the block;
+    any other visit, the chosen tokens padded to whole chunks), and
     ``msa_blocks_fetched`` the key-value blocks the forward reads."""
     t = chosen.shape[2]
+    n = t // block
+    blocks = _distinct(chosen, n)
+    per = _per_pair(blocks, block, n)
+    ch = chunk(block, group)
+    rows = jnp.where(per == block, block, -(-per // ch) * ch)
     own = (jnp.arange(t, dtype=jnp.int32) // block)[None, None, :, None]
-    seen = jnp.where(chosen == own,
+    seen = jnp.where(blocks == own,
                      (jnp.arange(t) % block + 1)[None, None, :, None], block)
-    fetched = jnp.sum(plan(chosen, block).kv_count).astype(F32)
     return {"msa_pairs_selected": jnp.sum(
-                jnp.where(chosen >= 0, seen, 0)).astype(F32),
-            "msa_pairs_computed": fetched * block * block,
-            "msa_blocks_fetched": fetched}
+                jnp.where(blocks < n, seen, 0)).astype(F32),
+            "msa_pairs_computed": jnp.sum(rows).astype(F32) * block,
+            "msa_blocks_fetched": jnp.sum(per > 0).astype(F32)}
 
 
 def _dot(a, b, dims):
     return jax.lax.dot_general(a, b, (dims, ((), ())),
                                preferred_element_type=F32)
-
-
-def _seen(sel, blk, tile, block: int):
-    """``[block, block]`` bool: the tile's tokens (rows) that chose key block
-    ``blk``, and of its keys (columns) those not after the token."""
-    hit = jnp.max(jnp.where(sel == blk, 1, 0), axis=1, keepdims=True) > 0
-    q_pos = tile * block + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
-    k_pos = blk * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
-    return hit & (k_pos <= q_pos)
 
 
 class _Call(NamedTuple):
@@ -155,217 +235,363 @@ def _flat(n: int, hkv: int):
         ((b * hkv + h) * n + i) * n + s)
 
 
-def _fwd_kernel(kv_ref, count_ref, q_ref, k_ref, v_ref, sel_ref, o_ref,
-                lse_ref, acc, m_s, l_s, *, c: _Call, n: int):
-    b, h, i, s = (pl.program_id(a) for a in range(4))
+class _Visit(NamedTuple):
+    """A query ``tile``'s visit to key block ``blk``: the ``count`` tokens of
+    the tile that chose it, from ``start`` of the tile's sorted list
+    ``toks``."""
+
+    tile: jax.Array
+    blk: jax.Array
+    count: jax.Array
+    start: jax.Array
+    toks: object
+
+
+def _rows(ref, rows):
+    """A row block of ``ref [tokens, G, X]``: ``[tokens * G, X]`` for
+    ``rows`` a slice of tokens, or the listed tokens' rows one after the
+    other."""
+    if isinstance(rows, slice):
+        x = ref[rows]
+        return x.reshape(-1, x.shape[-1])
+    return jnp.concatenate([ref[t] for t in rows], axis=0)
+
+
+def _write(ref, rows, value, g: int):
+    """Writes ``value [R, X]`` back where ``_rows`` read it."""
+    if isinstance(rows, slice):
+        ref[rows] = value.reshape(-1, g, value.shape[-1])
+        return
+    for k, t in enumerate(rows):
+        ref[t] = value[k * g:(k + 1) * g]
+
+
+def _column(values, g: int):
+    """``[len(values) * g, 1]`` int32, each scalar repeated ``g`` times."""
+    return jnp.concatenate(
+        [jnp.full((g, 1), x, jnp.int32) for x in values], axis=0)
+
+
+def _pick(a, tok):
+    """``[R, 1]``: row ``r`` of a chunk takes ``a [G, block]`` (a tile's
+    heads on sublanes, tokens on lanes) at head ``r % G`` and token
+    ``tok[r]``."""
+    g, blk = a.shape
+    r = tok.shape[0]
+    tiled = jnp.broadcast_to(a[None], (r // g, g, blk)).reshape(r, blk)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (r, blk), 1)
+    return jnp.sum(jnp.where(lane == tok, tiled, 0.0), axis=1, keepdims=True)
+
+
+def _causal(v: _Visit, tok, block: int):
+    """``[R, block]`` bool: the key is not after the row's token."""
+    k_pos = v.blk * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    return k_pos <= v.tile * block + tok
+
+
+def _each_chunk(v: _Visit, c: _Call, body):
+    """Calls ``body(rd, wr, tok, keep)`` for each chunk of a visit: ``rd``
+    the chunk's tokens as scalars to read rows at, ``wr`` where their
+    running rows are (``block``, the spare row, for padding), ``tok [R, 1]``
+    each row's token and ``keep`` the scores that count (broadcast to ``[R,
+    block]``; None for all). A whole visit is read and written in
+    contiguous pieces of the tile (``rd`` and ``wr`` the same slice). Only a
+    visit to the tile's own block (or a later one) takes the causal mask."""
+    g, ch, blk = c.group, chunk(c.block, c.group), c.block
+    before = v.blk < v.tile
+
+    def whole(causal):
+        part = _piece(blk, g)
+        for first in range(0, blk, part):
+            rows = slice(first, first + part)
+            tok = first + jax.lax.broadcasted_iota(
+                jnp.int32, (part * g, 1), 0) // g
+            body(rows, rows, tok, _causal(v, tok, blk) if causal else None)
+
+    def compacted(causal):
+        last = v.start + v.count - 1
+
+        def one(i, carry):
+            first = v.start + i * ch
+            at = [jnp.minimum(first + k, last) for k in range(ch)]
+            rd = [v.toks[x // LANES, x % LANES] for x in at]
+            left = v.count - i * ch
+            wr = [jnp.where(k < left, rd[k], blk) for k in range(ch)]
+            tok = _column(rd, g)
+            keep = jax.lax.broadcasted_iota(jnp.int32, (ch * g, 1), 0) < (
+                left * g)
+            body(rd, wr, tok, keep & _causal(v, tok, blk) if causal else keep)
+            return carry
+
+        jax.lax.fori_loop(0, (v.count + ch - 1) // ch, one, 0)
+
+    for causal in (False, True):
+        at_own = jnp.logical_not(before) if causal else before
+        pl.when((v.count == blk) & at_own)(lambda causal=causal: whole(causal))
+        pl.when((v.count < blk) & at_own)(
+            lambda causal=causal: compacted(causal))
+
+
+def _masked(sc, keep):
+    return sc if keep is None else jnp.where(keep, sc, NEG_INF)
+
+
+def _each_visit(kv_ref, count_ref, toks_ref, k_ref, v_ref, c: _Call,
+                n: int, body):
+    """Over the tile's union, ascending: ``body(visit, k, v)`` with the key
+    block's rows read from the group's keys and values in VMEM."""
+    b, h, i = (pl.program_id(a) for a in range(3))
     at = _flat(n, c.hkv)
-    d = k_ref.shape[-1]
 
-    @pl.when(s == 0)
-    def _init():
-        acc[...] = jnp.zeros_like(acc)
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
+    def one(s, carry):
+        kid, cnt, start = _unpack(kv_ref[at(b, h, i, s)], n, c.block)
+        rows = pl.ds(pl.multiple_of(kid * c.block, c.block), c.block)
+        body(_Visit(i, kid, cnt, start, toks_ref), k_ref[rows, :],
+             v_ref[rows, :])
+        return carry
 
-    @pl.when(s < count_ref[at(b, h, i)])
-    def _compute():
-        seen = _seen(sel_ref[...], kv_ref[at(b, h, i, s)], i, c.block)
-        k, v = k_ref[...], v_ref[...]
-        for g in range(c.group):
-            cols = slice(g * d, (g + 1) * d)
-            sc = jnp.where(seen, _dot(q_ref[:, cols], k, ((1,), (1,)))
-                           * c.scale, NEG_INF)
-            m_prev = m_s[g]
-            # the first slot is block 0, which every token chooses: the
-            # running maximum is finite from there on, and a row that did
-            # not choose a later block adds exp(NEG_INF - m) = 0 to it
+    jax.lax.fori_loop(0, count_ref[at(b, h, i)], one, 0)
+
+
+def _lanes(rows_of, g: int, blk: int):
+    """``[G, block]``: token ``t``'s ``[G, 1]`` column ``rows_of(t)`` in
+    lane ``t``."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (g, blk), 1)
+    return jax.lax.fori_loop(
+        0, blk, lambda t, out: jnp.where(lane == t, rows_of(t), out),
+        jnp.zeros((g, blk), F32))
+
+
+def _fwd_kernel(kv_ref, count_ref, toks_ref, q_ref, k_ref, v_ref, o_ref,
+                lse_ref, acc, ml_s, *, c: _Call, n: int):
+    blk, g = c.block, c.group
+    acc[...] = jnp.zeros_like(acc)
+    lane = jax.lax.broadcasted_iota(jnp.int32, ml_s.shape, 2)
+    ml_s[...] = jnp.where(lane == 0, NEG_INF, 0.0)
+
+    def visit(vis, k, v):
+        def body(rd, wr, tok, keep):
+            q = _rows(q_ref, rd)
+            sc = _masked(_dot(q, k, ((1,), (1,))) * c.scale, keep)
+            # the first visit is block 0, which every token chooses: the
+            # running maximum is finite from there on, and a masked key adds
+            # exp(NEG_INF - m) = 0; a padding row keeps to the spare row
+            ml = _rows(ml_s, wr)
+            m_prev = ml[:, :1]
             m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
             p = jnp.exp(sc - m_new)
             turn = jnp.exp(m_prev - m_new)
-            m_s[g] = m_new
-            l_s[g] = l_s[g] * turn + jnp.sum(p, axis=1, keepdims=True)
-            acc[:, cols] = acc[:, cols] * turn + _dot(
-                p.astype(v.dtype), v, ((1,), (0,)))
+            l_new = ml[:, 1:2] * turn + jnp.sum(p, axis=1, keepdims=True)
+            at_m = jax.lax.broadcasted_iota(jnp.int32, ml.shape, 1) == 0
+            _write(ml_s, wr, jnp.where(at_m, m_new, l_new), g)
+            _write(acc, wr, _rows(acc, wr) * turn + _dot(
+                p.astype(v.dtype), v, ((1,), (0,))), g)
 
-    @pl.when(s == n - 1)
-    def _finalize():
-        rows = []
-        for g in range(c.group):
-            cols = slice(g * d, (g + 1) * d)
-            l = l_s[g]
-            o_ref[:, cols] = (acc[:, cols] / l).astype(o_ref.dtype)
-            rows.append((m_s[g] + jnp.log(l))[:, 0][None, :])
-        lse_ref[...] = jnp.concatenate(rows, axis=0)
+        _each_chunk(vis, c, body)
+
+    _each_visit(kv_ref, count_ref, toks_ref, k_ref, v_ref, c, n, visit)
+    o_ref[...] = (acc[:blk] / ml_s[:blk][..., 1:2]).astype(o_ref.dtype)
+    lse_ref[...] = _lanes(
+        lambda t: ml_s[t][:, :1] + jnp.log(ml_s[t][:, 1:2]), g, blk)
 
 
-def _dq_kernel(kv_ref, count_ref, q_ref, k_ref, v_ref, sel_ref, do_ref, o_ref,
-               lse_ref, dq_ref, delta_ref, acc, d_s, *, c: _Call, n: int):
-    b, h, i, s = (pl.program_id(a) for a in range(4))
-    at = _flat(n, c.hkv)
-    d = k_ref.shape[-1]
+def _dq_kernel(kv_ref, count_ref, toks_ref, q_ref, k_ref, v_ref, do_ref,
+               o_ref, lse_ref, dq_ref, delta_ref, acc, *, c: _Call, n: int):
+    blk, g = c.block, c.group
+    acc[...] = jnp.zeros_like(acc)
+    delta_ref[...] = _lanes(lambda t: jnp.sum(
+        do_ref[t].astype(F32) * o_ref[t].astype(F32), axis=1,
+        keepdims=True), g, blk)
 
-    @pl.when(s == 0)
-    def _init():
-        acc[...] = jnp.zeros_like(acc)
-        for g in range(c.group):
-            cols = slice(g * d, (g + 1) * d)
-            d_s[g] = jnp.sum(do_ref[:, cols].astype(F32)
-                             * o_ref[:, cols].astype(F32), axis=1,
-                             keepdims=True)
-
-    @pl.when(s < count_ref[at(b, h, i)])
-    def _compute():
-        seen = _seen(sel_ref[...], kv_ref[at(b, h, i, s)], i, c.block)
-        k, v = k_ref[...], v_ref[...]
-        for g in range(c.group):
-            cols = slice(g * d, (g + 1) * d)
-            q, do = q_ref[:, cols], do_ref[:, cols]
-            sc = jnp.where(seen, _dot(q, k, ((1,), (1,))) * c.scale, NEG_INF)
-            p = jnp.exp(sc - lse_ref[g, :][:, None])
+    def visit(vis, k, v):
+        def body(rd, wr, tok, keep):
+            q, do = _rows(q_ref, rd), _rows(do_ref, rd)
+            sc = _masked(_dot(q, k, ((1,), (1,))) * c.scale, keep)
+            p = jnp.exp(sc - _pick(lse_ref[...], tok))
             dp = _dot(do, v, ((1,), (1,)))
-            ds = (p * (dp - d_s[g])).astype(k.dtype)
-            acc[:, cols] += _dot(ds, k, ((1,), (0,)))
+            ds = (p * (dp - _pick(delta_ref[...], tok))).astype(k.dtype)
+            _write(acc, wr, _rows(acc, wr) + _dot(ds, k, ((1,), (0,))), g)
 
-    @pl.when(s == n - 1)
-    def _finalize():
-        dq_ref[...] = (acc[...] * c.scale).astype(dq_ref.dtype)
-        delta_ref[...] = jnp.concatenate(
-            [d_s[g][:, 0][None, :] for g in range(c.group)], axis=0)
+        _each_chunk(vis, c, body)
+
+    _each_visit(kv_ref, count_ref, toks_ref, k_ref, v_ref, c, n, visit)
+    dq_ref[...] = (acc[:blk] * c.scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(tiles_ref, count_ref, q_ref, do_ref, lse_ref, delta_ref,
-                sel_ref, k_ref, v_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                c: _Call, n: int):
-    b, h, j, s = (pl.program_id(a) for a in range(4))
+def _dkv_kernel(tiles_ref, count_ref, q_hbm, do_hbm, toks_hbm, lse_ref,
+                delta_ref, k_ref, v_ref, dk_ref, dv_ref, q_buf, do_buf,
+                toks_buf, sem, dk_acc, dv_acc, *, c: _Call, n: int):
+    b, h, j = (pl.program_id(a) for a in range(3))
     at = _flat(n, c.hkv)
-    d = k_ref.shape[-1]
+    blk, g = c.block, c.group
+    count = count_ref[at(b, h, j)]
 
-    @pl.when(s == 0)
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
+    def copies(s):
+        """The DMAs of visit ``s``'s tile: its q and dO rows of the group
+        and its token list, into buffer ``s % IN_FLIGHT``."""
+        tile = _unpack(tiles_ref[at(b, h, j, s)], n, blk)[0]
+        rows = (b, pl.ds(tile * blk, blk), pl.ds(h * g, g))
+        slot = s % IN_FLIGHT
+        return (pltpu.make_async_copy(q_hbm.at[rows], q_buf.at[slot],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(do_hbm.at[rows], do_buf.at[slot],
+                                      sem.at[1, slot]),
+                pltpu.make_async_copy(toks_hbm.at[b, h, tile],
+                                      toks_buf.at[slot], sem.at[2, slot]))
 
-    @pl.when(s < count_ref[at(b, h, j)])
-    def _compute():
-        seen = _seen(sel_ref[...], j, tiles_ref[at(b, h, j, s)], c.block)
-        k, v = k_ref[...], v_ref[...]
-        for g in range(c.group):
-            cols = slice(g * d, (g + 1) * d)
-            q, do = q_ref[:, cols], do_ref[:, cols]
-            sc = jnp.where(seen, _dot(q, k, ((1,), (1,))) * c.scale, NEG_INF)
-            p = jnp.exp(sc - lse_ref[g, :][:, None])     # [tile, keys]
+    def fetch(s):
+        @pl.when(s < count)
+        def _start():
+            for cp in copies(s):
+                cp.start()
+
+    for s in range(IN_FLIGHT - 1):
+        fetch(s)
+    dk_acc[...] = jnp.zeros_like(dk_acc)
+    dv_acc[...] = jnp.zeros_like(dv_acc)
+    k, v = k_ref[...], v_ref[...]
+
+    def one(s, carry):
+        fetch(s + IN_FLIGHT - 1)
+        for cp in copies(s):
+            cp.wait()
+        slot = s % IN_FLIGHT
+        tile, cnt, start = _unpack(tiles_ref[at(b, h, j, s)], n, blk)
+        q_ref, do_ref = q_buf.at[slot], do_buf.at[slot]
+        lse, delta = lse_ref[tile], delta_ref[tile]
+
+        def body(rd, wr, tok, keep):
+            q, do = _rows(q_ref, rd), _rows(do_ref, rd)
+            sc = _masked(_dot(q, k, ((1,), (1,))) * c.scale, keep)
+            p = jnp.exp(sc - _pick(lse, tok))                  # [rows, keys]
             dv_acc[...] += _dot(p.astype(do.dtype), do, ((0,), (0,)))
             dp = _dot(do, v, ((1,), (1,)))
-            ds = (p * (dp - delta_ref[g, :][:, None])).astype(q.dtype)
+            ds = (p * (dp - _pick(delta, tok))).astype(q.dtype)
             dk_acc[...] += _dot(ds, q, ((0,), (0,)))
 
-    @pl.when(s == n - 1)
-    def _finalize():
-        dk_ref[...] = (dk_acc[...] * c.scale).astype(dk_ref.dtype)
-        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+        _each_chunk(_Visit(tile, j, cnt, start, toks_buf.at[slot]), c, body)
+        return carry
+
+    jax.lax.fori_loop(0, count, one, 0)
+    dk_ref[...] = (dk_acc[...] * c.scale).astype(dk_ref.dtype)
+    dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _specs(c: _Call, n: int, d: int, by_tile: bool):
-    """Block specs on the grid ``(b, h, x, s)``: ``x`` a query tile and ``s``
-    a slot of its union (``by_tile``), or ``x`` a key block and ``s`` a slot
-    of its tiles; the listed block or tile is ``lst[at(b, h, x, s)]``."""
-    at = _flat(n, c.hkv)
-    blk, gd = c.block, c.group * d
-    if by_tile:
-        own = lambda b, h, x, s, lst, cnt: x               # noqa: E731
-        other = lambda b, h, x, s, lst, cnt: lst[at(b, h, x, s)]  # noqa: E731
-    else:
-        own = lambda b, h, x, s, lst, cnt: lst[at(b, h, x, s)]  # noqa: E731
-        other = lambda b, h, x, s, lst, cnt: x             # noqa: E731
+def _tile_specs(c: _Call, t: int, d: int):
+    """Block specs on the grid ``(b, h, tile)``: the tile's rows and token
+    list, and the group's keys and values whole (``[T, D]``, fetched once a
+    group)."""
     return dict(
-        heads=pl.BlockSpec((None, blk, gd), lambda b, h, x, s, lst, cnt: (
-            b, own(b, h, x, s, lst, cnt), h)),
-        head=pl.BlockSpec((None, blk, d), lambda b, h, x, s, lst, cnt: (
-            b, other(b, h, x, s, lst, cnt), h)),
-        rows=pl.BlockSpec((None, None, c.group, blk),
-                          lambda b, h, x, s, lst, cnt: (
-                              b, h, 0, own(b, h, x, s, lst, cnt))),
-        sel=lambda width: pl.BlockSpec(
-            (None, None, blk, width), lambda b, h, x, s, lst, cnt: (
-                b, h, own(b, h, x, s, lst, cnt), 0)))
+        heads=pl.BlockSpec((None, c.block, c.group, d),
+                           lambda b, h, i, lst, cnt: (b, i, h, 0)),
+        keys=pl.BlockSpec((None, t, d), lambda b, h, i, lst, cnt: (b, 0, h),
+                          pipeline_mode=pl.Buffered(1)),
+        rows=pl.BlockSpec((None, None, None, c.group, c.block),
+                          lambda b, h, i, lst, cnt: (b, h, i, 0, 0)),
+        toks=lambda rows: pl.BlockSpec(
+            (None, None, None, rows, LANES),
+            lambda b, h, i, lst, cnt: (b, h, i, 0, 0),
+            memory_space=pltpu.SMEM))
 
 
-def _call(kernel, name, c: _Call, shape, in_specs, out_specs, out_shape,
-          scratch):
-    b, t, _ = shape
-    n = t // c.block
+def _key_specs(c: _Call, n: int, d: int):
+    """Block specs on the grid ``(b, h, key block)``: the key block's rows,
+    the group's row log-sum-exp and ``delta`` whole (``[n, G, block]``,
+    fetched once a group), and q, dO and the token lists where they lie
+    (the kernel fetches a visit's tile itself)."""
+    return dict(
+        head=pl.BlockSpec((None, c.block, d),
+                          lambda b, h, j, lst, cnt: (b, j, h)),
+        rows=pl.BlockSpec((None, None, n, c.group, c.block),
+                          lambda b, h, j, lst, cnt: (b, h, 0, 0, 0),
+                          pipeline_mode=pl.Buffered(1)),
+        anywhere=pl.BlockSpec(memory_space=pl.ANY))
+
+
+def _call(kernel, name, c: _Call, n: int, grid, in_specs, out_specs,
+          out_shape, scratch):
+    semantics = ("parallel",) * 3 + ("arbitrary",) * (len(grid) - 3)
     return pl.pallas_call(
         functools.partial(kernel, c=c, n=n),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(b, c.hkv, n, n), in_specs=in_specs,
+            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
             out_specs=out_specs, scratch_shapes=scratch),
-        out_shape=out_shape, compiler_params=_DIMS,
-        interpret=pallas_interpret(), name=name)
+        out_shape=out_shape, interpret=pallas_interpret(), name=name,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics))
+
+
+def _state(c: _Call, width: int):
+    """A tile's running rows, ``[block + 1, G, width]`` float32: a row a
+    token and head, and a spare token row where a chunk's padding goes (the
+    accumulator, or a row's maximum and sum in lanes 0 and 1)."""
+    return pltpu.VMEM((c.block + 1, c.group, width), F32)
 
 
 @functools.partial(jax.jit, static_argnames="c")
-def _forward(q, k, v, sel, p: Plan, c: _Call):
+def _forward(q, k, v, p: Plan, c: _Call):
     """``(o, lse)``."""
-    b, t, _ = q.shape
-    d = k.shape[-1] // c.hkv
-    spec = _specs(c, t // c.block, d, True)
+    b, t, _, d = q.shape
+    n = t // c.block
+    spec = _tile_specs(c, t, d)
     call = _call(
-        _fwd_kernel, "msa_fwd", c, q.shape,
-        [spec["heads"], spec["head"], spec["head"],
-         spec["sel"](sel.shape[-1])],
+        _fwd_kernel, "msa_fwd", c, n, (b, c.hkv, n),
+        [spec["toks"](p.tokens.shape[-2]), spec["heads"], spec["keys"],
+         spec["keys"]],
         [spec["heads"], spec["rows"]],
         [jax.ShapeDtypeStruct(q.shape, q.dtype),
-         jax.ShapeDtypeStruct((b, c.hkv, c.group, t), F32)],
-        [pltpu.VMEM((c.block, c.group * d), F32),
-         pltpu.VMEM((c.group, c.block, 1), F32),
-         pltpu.VMEM((c.group, c.block, 1), F32)])
-    return call(p.kv.reshape(-1), p.kv_count.reshape(-1), q, k, v, sel)
+         jax.ShapeDtypeStruct((b, c.hkv, n, c.group, c.block), F32)],
+        [_state(c, d), _state(c, LANES)])
+    return call(p.kv.reshape(-1), p.kv_count.reshape(-1), p.tokens, q, k, v)
 
 
 @functools.partial(jax.jit, static_argnames="c")
-def _backward(q, k, v, sel, p: Plan, o, lse, do, c: _Call):
+def _backward(q, k, v, p: Plan, o, lse, do, c: _Call):
     """``(dq, dk, dv)``."""
-    b, t, _ = q.shape
-    d = k.shape[-1] // c.hkv
+    b, t, _, d = q.shape
     n = t // c.block
-    spec = _specs(c, n, d, True)
-    rows = jax.ShapeDtypeStruct((b, c.hkv, c.group, t), F32)
+    spec = _tile_specs(c, t, d)
+    rows = jax.ShapeDtypeStruct((b, c.hkv, n, c.group, c.block), F32)
     dq, delta = _call(
-        _dq_kernel, "msa_dq", c, q.shape,
-        [spec["heads"], spec["head"], spec["head"], spec["sel"](sel.shape[-1]),
-         spec["heads"], spec["heads"], spec["rows"]],
+        _dq_kernel, "msa_dq", c, n, (b, c.hkv, n),
+        [spec["toks"](p.tokens.shape[-2]), spec["heads"], spec["keys"],
+         spec["keys"], spec["heads"], spec["heads"], spec["rows"]],
         [spec["heads"], spec["rows"]],
-        [jax.ShapeDtypeStruct(q.shape, q.dtype), rows],
-        [pltpu.VMEM((c.block, c.group * d), F32),
-         pltpu.VMEM((c.group, c.block, 1), F32)],
-    )(p.kv.reshape(-1), p.kv_count.reshape(-1), q, k, v, sel, do, o, lse)
-    spec = _specs(c, n, d, False)
+        [jax.ShapeDtypeStruct(q.shape, q.dtype), rows], [_state(c, d)],
+    )(p.kv.reshape(-1), p.kv_count.reshape(-1), p.tokens, q, k, v, do, o,
+      lse)
+    spec = _key_specs(c, n, d)
+    width = p.tokens.shape[-2:]
     dk, dv = _call(
-        _dkv_kernel, "msa_dkv", c, q.shape,
-        [spec["heads"], spec["heads"], spec["rows"], spec["rows"],
-         spec["sel"](sel.shape[-1]), spec["head"], spec["head"]],
+        _dkv_kernel, "msa_dkv", c, n, (b, c.hkv, n),
+        [spec["anywhere"], spec["anywhere"], spec["anywhere"], spec["rows"],
+         spec["rows"], spec["head"], spec["head"]],
         [spec["head"], spec["head"]],
         [jax.ShapeDtypeStruct(k.shape, k.dtype),
          jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        [pltpu.VMEM((c.block, d), F32), pltpu.VMEM((c.block, d), F32)],
-    )(p.q.reshape(-1), p.q_count.reshape(-1), q, do, lse, delta, sel, k, v)
+        [pltpu.VMEM((IN_FLIGHT, c.block, c.group, d), q.dtype),
+         pltpu.VMEM((IN_FLIGHT, c.block, c.group, d), do.dtype),
+         pltpu.SMEM((IN_FLIGHT,) + width, jnp.int32),
+         pltpu.SemaphoreType.DMA((3, IN_FLIGHT)),
+         pltpu.VMEM((c.block, d), F32), pltpu.VMEM((c.block, d), F32)],
+    )(p.q.reshape(-1), p.q_count.reshape(-1), q, do, p.tokens, lse, delta,
+      k, v)
     return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _msa(q, k, v, sel, c: _Call):
-    return _forward(q, k, v, sel, plan(sel, c.block), c)[0]
+    return _forward(q, k, v, plan(sel, c.block), c)[0]
 
 
 def _msa_fwd(q, k, v, sel, c):
     p = plan(sel, c.block)
-    o, lse = _forward(q, k, v, sel, p, c)
-    return o, (q, k, v, sel, p, o, lse)
+    o, lse = _forward(q, k, v, p, c)
+    return o, (q, k, v, p, o, lse)
 
 
 def _msa_bwd(c, res, do):
-    q, k, v, sel, p, o, lse = res
-    return (*_backward(q, k, v, sel, p, o, lse, do, c), None)
+    q, k, v, p, o, lse = res
+    return (*_backward(q, k, v, p, o, lse, do, c), None)
 
 
 _msa.defvjp(_msa_fwd, _msa_bwd)
@@ -392,14 +618,13 @@ def block_sparse_attention(q, k, v, chosen, block: int,
     b, t, hq, hkv, d = _checked(q, k, chosen, block)
     scale = float(d ** -0.5 if scale is None else scale)
     # [B, T, H, D] and [B, T, H*D] are tiled differently in HBM: as in
-    # ``flash_attention``'s band, the barrier keeps the reshape one copy of
-    # what the caller made (ops/flash_attention.py, PERF.md section 6)
+    # ``flash_attention``'s band, the barrier keeps the keys' reshape one
+    # copy of what the caller made (ops/flash_attention.py, PERF.md
+    # section 6); q, o and their gradients stay as the caller lays them out
     q, k, v = jax.lax.optimization_barrier((q, k, v))
     sel = jnp.swapaxes(jax.lax.stop_gradient(chosen), 1, 2).astype(jnp.int32)
-    o = _msa(q.reshape(b, t, hq * d), k.reshape(b, t, hkv * d),
-             v.reshape(b, t, hkv * d), sel,
-             _Call(hkv, hq // hkv, block, scale))
-    return o.reshape(b, t, hq, d)
+    return _msa(q, k.reshape(b, t, hkv * d), v.reshape(b, t, hkv * d), sel,
+                _Call(hkv, hq // hkv, block, scale))
 
 
 def visible(chosen, block: int):

@@ -8,7 +8,8 @@ the frozen matrices; under ``vmap`` with the matrices unbatched (ONE kernel
 call with the clients on its grid, a batch of one squeezed) and the loop it
 falls back to (batched matrices, a nested batch); what the tally notes; the
 plain expression wherever the shapes do not take the kernel; and the kernel
-compiled for the v5e at the benchmark cells' shapes (no chip)."""
+compiled for the v5e at the benchmark cells' shapes (no chip), beside the
+attention kernels of two cells that need the same described chip."""
 
 import re
 
@@ -445,5 +446,32 @@ def test_the_band_kernels_compile_for_the_v5e_at_the_cells_shapes(
     text = jax.jit(step).trace(spec(64), spec(8), spec(8)).lower(
         lowering_platforms=("tpu",)).compile().as_text()
     for name in ("window_band_fwd", "window_band_dq", "window_band_dkv"):
+        assert name in text
+    assert "tpu_custom_call" in text
+
+
+def test_the_selected_block_kernels_compile_for_the_v5e_at_the_cells_shapes(
+        monkeypatch, one_chip):
+    """MiniMax-M3's attention as ``minimaxm3_lora_c2_s16k``'s round calls it
+    (``ops/block_sparse_attention.py``; here because this file holds the
+    described chip): a client's 16,384 tokens under the ``vmap``, 64 query
+    heads over 4 key-value heads of 128, 18 chosen blocks of 128 a token.
+    Mosaic takes the forward, ``dq`` and ``dkv`` kernels (the group's keys
+    in VMEM within the default limit, the SMEM token lists, the fetches of
+    the ``dkv`` kernel) under their stable names."""
+    import importlib
+
+    bsa = importlib.import_module("fedml_tpu.ops.block_sparse_attention")
+    monkeypatch.setattr(bsa, "pallas_interpret", lambda: False)
+    spec = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, 1, 16384) + shape, jnp.int32 if shape == (4, 18)
+        else jnp.bfloat16, sharding=one_chip)
+    step = jax.vmap(jax.grad(lambda q, k, v, c: jnp.sum(
+        bsa.block_sparse_attention(q, k, v, c, 128).astype(jnp.float32)),
+        (0, 1, 2)))
+    text = jax.jit(step).trace(
+        spec(64, 128), spec(4, 128), spec(4, 128), spec(4, 18)).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    for name in ("msa_fwd", "msa_dq", "msa_dkv"):
         assert name in text
     assert "tpu_custom_call" in text

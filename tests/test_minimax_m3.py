@@ -210,19 +210,51 @@ def _chosen(seed, batch, n_tokens, groups, block, topk):
     return jnp.asarray(out)
 
 
+def _listed(extra, slots):
+    """Block 0 and the own block for every token of two clients, and
+    ``extra[(client, group)] = {block: tokens}`` in ``slots`` more slots."""
+    out = np.asarray(_chosen(0, 2, T, 2, 8, 0))
+    out = np.concatenate(
+        [out, -np.ones(out.shape[:-1] + (slots,), np.int32)], -1)
+    for (b, g), picks in extra.items():
+        for block, tokens in picks.items():
+            for t in tokens:
+                out[b, t, g, np.flatnonzero(out[b, t, g] < 0)[0]] = block
+    return jnp.asarray(out)
+
+
+#: a key block chosen by one token of a tile (a chunk of one token and three
+#: padding rows, the last token of the sequence among them); a tile whose
+#: blocks are chosen by 5, 7 (a chunk and a part) and all 8 of its tokens
+#: (whole-tile visits beside compacted ones, off the own block)
+LISTED = {
+    "one_token": (1, {(0, 0): {1: [25], 2: [47]}, (0, 1): {3: [33]},
+                      (1, 0): {4: [40]}, (1, 1): {1: [20]}}),
+    "ragged_and_whole": (3, {
+        (0, 0): {1: range(32, 37), 2: range(32, 40), 3: range(33, 40)},
+        (0, 1): {2: range(40, 46), 4: [41, 43, 45]},
+        (1, 0): {1: range(24, 31), 2: [26, 27]},
+        (1, 1): {1: range(40, 48), 3: range(40, 45), 4: [47]}})}
+
+
 @pytest.mark.parametrize("topk, dtype", [
     (6, jnp.float32), (2, jnp.float32), (0, jnp.float32),
-    (2, jnp.bfloat16)])
+    (2, jnp.bfloat16), ("one_token", jnp.float32),
+    ("ragged_and_whole", jnp.float32)])
 def test_the_kernels_are_the_masked_softmax(topk, dtype):
     """Output and the gradients of q, k and v of the interpreted kernels
     equal one softmax over [T, T] masked to the chosen blocks: every block
-    chosen (a top-6 of 6 blocks), two of the blocks between, none but the
-    first and the own; two clients under ``vmap``; and in bf16 (float32
-    accumulation on both sides)."""
+    chosen (a top-6 of 6 blocks: whole-tile visits only), two of the blocks
+    between, none but the first and the own; the lists of ``LISTED``
+    (chunks of 4 tokens: a block of one token, counts that fill no whole
+    chunk, whole and compacted visits in one tile); two clients under
+    ``vmap``; and in bf16 (float32 accumulation on both sides)."""
     rng = np.random.default_rng(1)
     mk = lambda *s: jnp.asarray(rng.normal(size=s), dtype)  # noqa: E731
     q, k, v = mk(2, 1, T, 4, 16), mk(2, 1, T, 2, 16), mk(2, 1, T, 2, 16)
-    chosen = _chosen(2, 2, T, 2, 8, topk)[:, None]
+    chosen = (_listed(*LISTED[topk][::-1]) if topk in LISTED
+              else _chosen(2, 2, T, 2, 8, topk))[:, None]
+    assert bsa.chunk(8, 2) == 4
 
     def loss(fn):
         return lambda q, k, v, c: jnp.sum(jnp.sin(
@@ -248,18 +280,32 @@ def test_the_kernels_are_the_masked_softmax(topk, dtype):
 
 def test_the_counts_of_a_call():
     """``counts``: every visible pair of the chosen blocks (whole blocks
-    before the own, the own up to the token), and the pairs and blocks of
-    the tiles' unions; with every block chosen, the union is every block up
-    to the tile's own, so computed pairs are the causal half's blocks."""
+    before the own, the own up to the token), the pairs the kernels
+    multiply and the blocks of the tiles' unions. With every block chosen
+    the union is every block up to the tile's own and every visit takes the
+    whole tile, so computed pairs are the causal half's blocks; with a few
+    blocks chosen by some tokens of a tile (``few``), a visit multiplies its
+    tokens padded to whole chunks of 4 (8 keys each)."""
     everything = _chosen(0, 1, T, 2, 8, 6)
-    got = bsa.counts(jnp.swapaxes(everything, 1, 2), 8)
+    got = bsa.counts(jnp.swapaxes(everything, 1, 2), 8, 2)
     n = T // 8
     assert float(got["msa_pairs_selected"]) == 2 * T * (T + 1) / 2
     assert float(got["msa_blocks_fetched"]) == 2 * n * (n + 1) / 2
     assert float(got["msa_pairs_computed"]) == 2 * 64 * n * (n + 1) / 2
-    few = bsa.counts(jnp.swapaxes(_chosen(0, 1, T, 2, 8, 0), 1, 2), 8)
-    assert float(few["msa_pairs_selected"]) == 2 * (
+    first_and_own = bsa.counts(
+        jnp.swapaxes(_chosen(0, 1, T, 2, 8, 0), 1, 2), 8, 2)
+    assert float(first_and_own["msa_pairs_selected"]) == 2 * (
         n * 36 + (T - 8) * 8)
+    # group 0: block 1 by token 25 (tile 3), block 2 by tokens 40-44 (tile
+    # 5); group 1: block 3 by every token of tile 4
+    few = _listed({(0, 0): {1: [25], 2: range(40, 45)},
+                   (0, 1): {3: range(32, 40)}}, 1)[:1]
+    got = bsa.counts(jnp.swapaxes(few, 1, 2), 8, 2)
+    whole = 2 * (1 + 2 * (n - 1)) + 1        # first, own, and tile 4's block 3
+    assert float(got["msa_blocks_fetched"]) == whole + 2
+    assert float(got["msa_pairs_selected"]) == 2 * (n * 36 + (T - 8) * 8) + (
+        1 + 5 + 8) * 8
+    assert float(got["msa_pairs_computed"]) == whole * 64 + (4 + 8) * 8
 
 
 # --- the shares of an expert-parallel layer ----------------------------------
